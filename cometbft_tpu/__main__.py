@@ -8,12 +8,13 @@ from .analysis import lockwitness
 
 lockwitness.maybe_install()
 
-# Persistent XLA compile cache (COMETBFT_TPU_COMPILE_CACHE): configured
-# before any kernel compiles so a warm pod restart skips XLA entirely.
-# Imports jax only when the knob is set; no-op otherwise.
+# Persistent XLA compile cache (utils/compilecache: where
+# JAX_COMPILATION_CACHE_DIR says, else the checkout's tests/.jax_cache),
+# configured before any kernel compiles so a restarted node loads its
+# executables instead of recompiling them.
 from .utils import compilecache  # noqa: E402
 
-compilecache.maybe_enable()
+compilecache.enable()
 
 from .cli import main  # noqa: E402
 

@@ -86,7 +86,7 @@ class Kernel:
                     positive budget (kernelcheck fails the manifest
                     otherwise): the old ``comb_build_a_tables`` rode
                     unbudgeted past the PR-6 gate straight into a 2m34s
-                    XLA compile (MULTICHIP_r05); that grandfather clause
+                    XLA compile (on XLA:CPU); that grandfather clause
                     is gone.  Budgets are measured counts plus ~30%
                     headroom — an unrolled-loop blowup fails in
                     milliseconds, an innocuous +1 eqn does not.

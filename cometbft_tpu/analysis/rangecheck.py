@@ -1449,10 +1449,10 @@ def _cached_call(ctx, jaxpr, consts, ins, runner):
     return outs
 
 
-@_rule("pjit")
-def _r_pjit(ctx, frame, eqn, ins):
+@_rule("jit")
+def _r_jit(ctx, frame, eqn, ins):
     closed = eqn.params["jaxpr"]
-    name = eqn.params.get("name") or "pjit"
+    name = eqn.params.get("name") or "jit"
     ctx.path.append(name)
     try:
         return _cached_call(
@@ -1471,9 +1471,17 @@ def _r_shard_map(ctx, frame, eqn, ins):
     p = eqn.params
     jaxpr = p["jaxpr"]  # open jaxpr (no consts) in current jax
     mesh = p["mesh"]
-    in_names = p["in_names"]
-    out_names = p["out_names"]
     sizes = dict(mesh.shape)
+
+    def axes_by_dim(spec) -> dict[int, tuple]:
+        """A PartitionSpec as {dim: (axis, ...)} over its sharded dims."""
+        return {
+            dim: ax if isinstance(ax, tuple) else (ax,)
+            for dim, ax in enumerate(spec) if ax is not None
+        }
+
+    in_names = [axes_by_dim(s) for s in p["in_specs"]]
+    out_names = [axes_by_dim(s) for s in p["out_specs"]]
 
     def shard_in(v, names):
         lo, hi = v.lo, v.hi

@@ -9,11 +9,7 @@ operator-local knobs, exactly like the reference split.
 from __future__ import annotations
 
 import os
-
-try:
-    import tomllib
-except ImportError:  # Python < 3.11: the framework's minimal reader
-    from .utils import minitoml as tomllib
+import tomllib
 from dataclasses import dataclass, field, fields
 
 from .consensus.config import ConsensusConfig

@@ -86,10 +86,7 @@ def hash_from_byte_slices(items: list[bytes], device: bool | None = None) -> byt
     if device is None:
         device = n >= _DEVICE_THRESHOLD
     if device:
-        try:
-            return _root_device(items)
-        except ImportError:
-            pass
+        return _root_device(items)
     return _root_from_leaf_hashes_host([leaf_hash(i) for i in items])
 
 

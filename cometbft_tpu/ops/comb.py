@@ -90,7 +90,7 @@ def build_a_tables(a_enc):
     (``max_eqns``) every kernel now carries — the normalize pass is
     scan-rolled so the jaxpr stays thousands of equations, not the
     ~85k-equation unrolled build whose XLA compile ran 2m34s
-    (MULTICHIP_r05).  Output limbs are FROZEN canonical, bit-identical
+    (on XLA:CPU).  Output limbs are FROZEN canonical, bit-identical
     to :func:`build_a_tables_host` (the compile-free production path).
     """
     pt, valid = E.decompress(a_enc)
@@ -265,7 +265,7 @@ def build_a_tables_host(a_enc: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     field elements), with NO XLA program anywhere.
 
     This is the cold-start fix of ROADMAP item 1: the jitted build's
-    XLA compile ran 2m34s (MULTICHIP_r05) before the scan-rolled
+    XLA compile ran 2m34s (on XLA:CPU) before the scan-rolled
     rework, and even compile-cached it costs a device round trip per
     new shape.  The host build is pure Python/NumPy — a few ms per
     validator — and its output is ``device_put`` with the entry's

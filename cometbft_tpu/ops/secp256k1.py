@@ -282,7 +282,7 @@ for _i in range(NLIMBS):
 # Compile-cost note: like the bls381 kernels, the rolled Montgomery
 # graphs are expensive to compile cold on the CPU backend (one bucket
 # shape ~2 min); the persistent XLA compile cache
-# (COMETBFT_TPU_COMPILE_CACHE, on by default in tests and bench — the
+# (utils/compilecache, on by default in tests and bench — the
 # same mitigation the ed25519 verify kernel already relies on) makes
 # every later process a cache hit, and the power-of-two bucketing
 # keeps the shape set small.

@@ -21,16 +21,8 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.7 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"  # pre-0.7 name for the same switch
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
@@ -38,7 +30,7 @@ def shard_map(f, *, mesh, in_specs, out_specs):
     # mix varying/unvarying per-device types; the collectives below
     # establish replication explicitly, so the static check adds nothing.
     return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{_CHECK_KW: False}
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 from ..ops import ed25519 as E

@@ -1,55 +1,49 @@
-"""JAX persistent compilation cache, behind COMETBFT_TPU_COMPILE_CACHE.
+"""JAX's persistent compilation cache: one rule, in this one place.
 
-The multi-chip cold-start problem (ROADMAP item 1, MULTICHIP_r05) is
-dominated by XLA: the fused Ed25519 kernel compiles in minutes on the
-CPU backend and tens of seconds on TPU, and the sharded comb programs
-re-pay it per (shape, mesh).  With the persistent cache pointed at a
-durable directory, a warm pod restart deserializes the executables
-instead — compile once per image, not once per process.
+Cold compiles are the large part of a cold start here (the fused
+Ed25519 kernel is minutes on the CPU backend; the 10,000-validator comb
+table build and verify programs are the large part of a first run on a
+TPU), so every entry point — ``python -m cometbft_tpu``, ``bench.py``,
+``chip_smoke.py``, the test suite, the profile scripts — calls
+:func:`enable` before its first compile.  The rule:
 
-``maybe_enable()`` is wired into the production entry (``__main__.py``)
-and ``bench.py``.  It is deliberately forgiving: an unusable directory
-or a jax too old for the config keys degrades to "no cache", never a
-startup failure.  The knob must name a DURABLE, per-host directory —
-a corrupt entry (e.g. a process killed mid-write on shared storage)
-can crash jax's cache read path, which is why there is no default dir:
-opting in is an operator decision.
+* ``JAX_COMPILATION_CACHE_DIR`` set: jax reads the variable by itself,
+  and this module sets no directory at all — whoever launched the
+  process placed the cache.
+* unset: ``<checkout>/tests/.jax_cache``, computed from this module's
+  own path.  The path is part of what makes a cache warm (tier-1 fits
+  its time limit only because that directory is), so it is fixed: never
+  a temporary directory, a pid or a time.
 
-Call it before the first compile; flipping the config later in the
-process is a no-op for programs already compiled.
+Nothing else in the repository touches ``jax_compilation_cache_dir``
+(``__graft_entry__._disable_compile_cache`` turns the cache OFF for one
+run; it places nothing).  Call :func:`enable` before the first compile:
+jax latches its cache decision on first use.
 """
 
 from __future__ import annotations
 
 import os
 
-from . import envknobs
-from .log import get_logger
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
-logger = get_logger("compilecache")
+DEFAULT_DIR = os.path.abspath(
+    os.path.join(
+        os.path.dirname(__file__), os.pardir, os.pardir, "tests", ".jax_cache"
+    )
+)
+
+# Harnesses that SIGKILL their children (e2e runner, chaos, soak) point
+# those children here through ENV_VAR: jax writes cache entries
+# non-atomically, and an entry torn by a kill can crash the next reader,
+# which must never happen to the directory tier-1 depends on.
+HARNESS_DIR = DEFAULT_DIR + "_chaos"
 
 
-def maybe_enable(default_dir: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at the knob's directory
-    (or ``default_dir`` when the knob is unset).  Returns the directory
-    on success, None when disabled or unusable."""
-    cache_dir = envknobs.get_str(envknobs.COMPILE_CACHE) or default_dir
-    if not cache_dir:
-        return None
-    cache_dir = os.path.abspath(cache_dir)
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
+def enable() -> str:
+    """Apply the rule above; returns the directory in effect."""
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # every kernel of the verify plane is worth persisting: the
-        # small ones are milliseconds to write, the comb/sharded ones
-        # are the minutes this cache exists to kill
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:  # noqa: BLE001 - the cache is an optimization only
-        logger.warning("persistent compile cache unusable at %s: %s",
-                       cache_dir, e)
-        return None
-    logger.info("persistent compile cache enabled at %s", cache_dir)
-    return cache_dir
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return jax.config.jax_compilation_cache_dir

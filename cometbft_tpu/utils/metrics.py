@@ -567,8 +567,9 @@ class Hub:
         self.verify_phase_seconds = r.histogram(
             "verify_phase_seconds",
             "Per-phase VerifyCommit pipeline latency (label phase="
-            "assembly|h2d_dispatch|staging_wait|device_wait; first call "
-            "at a new shape carries the XLA compile in h2d_dispatch)",
+            "assembly|h2d_dispatch|staging_wait|device_wait; the uncached "
+            "kernel's first call at a new bucket carries its XLA compile "
+            "in h2d_dispatch, the comb program compiles before staging)",
             buckets=(
                 0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 2.5,
             ),

@@ -195,7 +195,9 @@ class ServiceBatchVerifier:
             return payload
         # bounded wait: a live-but-stuck scheduler (accepted the submit,
         # never resolved the ticket) must not park a consensus or
-        # blocksync caller forever.  On expiry: stall forensics, then the
+        # blocksync caller forever.  (A first-shape compile of the
+        # batch's program is work and is not charged, up to a bound:
+        # Ticket.compiling.)  On expiry: stall forensics, then the
         # host fallback — first-wins ticket settlement discards the
         # service's late answer if it ever comes.
         timeout = collect_timeout_s()

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
-"""Diff two bench rounds (BENCH_*.json) and flag regressions.
+"""Diff two bench rounds and flag regressions.
 
-    python scripts/bench_compare.py BENCH_r01.json BENCH_r02.json
+    python scripts/bench_compare.py old.json new.json
     python scripts/bench_compare.py --threshold 0.05 --json old.json new.json
 
 Each input is a driver round wrapper (``{"n", "cmd", "rc", "tail",

@@ -40,21 +40,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main(argv: list[str] | None = None) -> int:
     # Persistent XLA compile cache for every node the scenarios spawn
-    # (children inherit the env; `python -m cometbft_tpu` calls
-    # utils/compilecache.maybe_enable at startup): repeated chaos runs
-    # stop paying the kernel recompiles.  setdefault — an operator's
-    # COMETBFT_TPU_COMPILE_CACHE always wins.  The dir is chaos-private
-    # (not tests/.jax_cache): these scenarios kill -9 nodes mid-flight,
-    # and a write torn by a kill must never be able to corrupt the
-    # tier-1 suite's shared cache (a corrupt entry can crash jax's
+    # (children inherit the env, and jax reads JAX_COMPILATION_CACHE_DIR
+    # by itself): repeated chaos runs stop paying the kernel recompiles.
+    # setdefault — an operator's own placement always wins.  The dir is
+    # harness-private (not tests/.jax_cache): these scenarios kill -9
+    # nodes mid-flight, and a write torn by a kill must never be able to
+    # corrupt the tier-1 suite's cache (a corrupt entry can crash jax's
     # cache read path).
-    os.environ.setdefault(
-        "COMETBFT_TPU_COMPILE_CACHE",
-        os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tests", ".jax_cache_chaos",
-        ),
-    )
+    from cometbft_tpu.utils import compilecache
+
+    os.environ.setdefault(compilecache.ENV_VAR, compilecache.HARNESS_DIR)
     from cometbft_tpu.e2e import scenarios as sc
 
     p = argparse.ArgumentParser(description=__doc__)

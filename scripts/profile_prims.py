@@ -1,5 +1,5 @@
 """Slope-based micro-benchmarks: vary inner iteration count and diff, so
-fixed dispatch/tunnel overhead cancels out."""
+fixed dispatch overhead cancels out."""
 import os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
@@ -8,10 +8,9 @@ import jax.numpy as jnp
 from jax import lax
 from functools import partial
 
-cache_dir = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tests", ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", cache_dir)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from cometbft_tpu.utils import compilecache
+
+compilecache.enable()
 
 from cometbft_tpu.ops import field as F
 

@@ -21,7 +21,7 @@ story of the GLV + hashing-residency PR):
   glv+fused  — GLV + on-device hashing (the default production shape)
 
 Each config compiles its own program variant (~minutes cold on the CPU
-backend; warm COMETBFT_TPU_COMPILE_CACHE removes it), so the default
+backend; a warm compile cache removes it), so the default
 sweep is opt-down via SECPPROF_CONFIGS.
 
 Env: SECPPROF_N (rows, default 512), SECPPROF_ITERS (timed reps, 5),

@@ -5,10 +5,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 import jax
-cache_dir = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tests", ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", cache_dir)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from cometbft_tpu.utils import compilecache
+
+compilecache.enable()
 import jax.numpy as jnp
 
 from cometbft_tpu.crypto import ed25519 as host

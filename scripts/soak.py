@@ -41,16 +41,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main(argv: list[str] | None = None) -> int:
     # CPU determinism + warm compile cache for any real-plane run and
     # for the chaos subprocess's nodes (same reasoning as chaos.py:
-    # setdefault so an operator's environment always wins; chaos-private
-    # cache dir so a kill -9-torn write can't corrupt tier-1's cache)
+    # setdefault so an operator's environment always wins; harness-
+    # private cache dir so a kill -9-torn write can't corrupt tier-1's)
+    from cometbft_tpu.utils import compilecache
+
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault(
-        "COMETBFT_TPU_COMPILE_CACHE",
-        os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tests", ".jax_cache_chaos",
-        ),
-    )
+    os.environ.setdefault(compilecache.ENV_VAR, compilecache.HARNESS_DIR)
     from cometbft_tpu.e2e.soak import SoakConfig, run_soak
 
     p = argparse.ArgumentParser(description=__doc__)

@@ -1,10 +1,10 @@
 """scripts/bench_compare.py — the round-over-round perf diff.
 
-Proven against the CHECKED-IN driver rounds: r01/r02 are valid
-(783.101 ms @ 0.35x vs 845.655 ms @ 0.33x, a +7.99% headline
-regression), r03 crashed (rc=1, no JSON), r04/r05 are degraded
-backend-unavailable rounds (value null + "error") — the three
-exclusion shapes the comparator must refuse to treat as numbers."""
+Proven against rounds in the driver's wrapper shape, written here: two
+valid rounds (100.0 ms @ 2.75x vs 107.99 ms @ 2.55x, a +7.99% headline
+regression), one that crashed (rc=1, no JSON), and one that failed with
+a structured line (value null + "error") — the exclusion shapes the
+comparator must refuse to treat as numbers."""
 
 import importlib.util
 import json
@@ -17,9 +17,30 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPT = os.path.join(REPO, "scripts", "bench_compare.py")
 
+_METRIC = "verify_commit_p50_10k_ms"
+_ROUNDS = {
+    "a": {"rc": 0, "parsed": {"metric": _METRIC, "value": 100.0,
+                              "unit": "ms", "vs_baseline": 2.75}},
+    "b": {"rc": 0, "parsed": {"metric": _METRIC, "value": 107.99,
+                              "unit": "ms", "vs_baseline": 2.55}},
+    "crashed": {"rc": 1, "parsed": None},
+    "failed": {"rc": 0, "parsed": {
+        "metric": _METRIC, "value": None, "unit": "ms", "phases": {},
+        "error": "backend-unavailable: probe exited 1"}},
+}
 
-def _round(n: int) -> str:
-    return os.path.join(REPO, f"BENCH_r0{n}.json")
+
+@pytest.fixture
+def rounds(tmp_path):
+    """name -> path of a driver round wrapper."""
+    paths = {}
+    for name, doc in _ROUNDS.items():
+        path = tmp_path / f"round_{name}.json"
+        path.write_text(json.dumps(
+            {"n": 1, "cmd": "python bench.py", "tail": "", **doc}
+        ))
+        paths[name] = str(path)
+    return paths
 
 
 def _load_mod():
@@ -36,48 +57,49 @@ def _run(*args: str) -> subprocess.CompletedProcess:
     )
 
 
-# ----------------------------------------------------- checked-in rounds
+# ------------------------------------------------------- wrapped rounds
 
 
-def test_r01_vs_r02_within_default_threshold():
+def test_a_vs_b_within_default_threshold(rounds):
     """+7.99% sits under the default 10% gate: reported, not fatal."""
-    r = _run(_round(1), _round(2), "--json")
+    r = _run(rounds["a"], rounds["b"], "--json")
     assert r.returncode == 0, r.stderr
     rep = json.loads(r.stdout)
     assert rep["headline"]["delta_pct"] == pytest.approx(7.99, abs=0.01)
-    assert rep["vs_baseline"]["delta"] == pytest.approx(-0.02)
+    assert rep["vs_baseline"]["delta"] == pytest.approx(-0.2)
     assert rep["regressions"] == []
 
 
-def test_r01_vs_r02_trips_tighter_threshold():
-    r = _run("--threshold", "0.05", _round(1), _round(2))
+def test_a_vs_b_trips_tighter_threshold(rounds):
+    r = _run("--threshold", "0.05", rounds["a"], rounds["b"])
     assert r.returncode == 1
     assert "REGRESSION" in r.stderr and "+8.0%" in r.stderr
     # the improvement direction never trips: new faster than old
-    assert _run("--threshold", "0.05", _round(2), _round(1)).returncode == 0
+    assert _run(
+        "--threshold", "0.05", rounds["b"], rounds["a"]
+    ).returncode == 0
 
 
-@pytest.mark.parametrize("n,why", [
-    (3, "rc=1"),              # driver bench crashed, no JSON at all
-    (4, "backend-unavailable"),  # degraded: value null + error
-    (5, "backend-unavailable"),
+@pytest.mark.parametrize("name,why", [
+    ("crashed", "rc=1"),              # bench crashed, no JSON at all
+    ("failed", "backend-unavailable"),  # value null + error
 ])
-def test_degraded_and_wedge_rounds_excluded(n, why):
-    r = _run(_round(1), _round(n))
+def test_failed_rounds_excluded(rounds, name, why):
+    r = _run(rounds["a"], rounds[name])
     assert r.returncode == 2
     assert "excluded" in r.stderr and why in r.stderr
-    # symmetric: a degraded BASELINE is just as unusable
-    assert _run(_round(n), _round(1)).returncode == 2
+    # symmetric: a failed BASELINE is just as unusable
+    assert _run(rounds[name], rounds["a"]).returncode == 2
 
 
-def test_unreadable_and_mismatched_inputs_exit_2(tmp_path):
-    r = _run(_round(1), str(tmp_path / "missing.json"))
+def test_unreadable_and_mismatched_inputs_exit_2(rounds, tmp_path):
+    r = _run(rounds["a"], str(tmp_path / "missing.json"))
     assert r.returncode == 2
     other = tmp_path / "other_metric.json"
     other.write_text(json.dumps(
         {"metric": "something_else_ms", "value": 10.0}
     ))
-    r = _run(_round(1), str(other))
+    r = _run(rounds["a"], str(other))
     assert r.returncode == 2 and "metric mismatch" in r.stderr
 
 

@@ -429,6 +429,56 @@ def test_host_worker_time_exempt_from_trip_deadline(svc):
     s._untrack_inflight(batch)
 
 
+def test_compile_wait_exempt_from_trip_deadline_up_to_a_bound(svc, monkeypatch):
+    """A device-tracked batch waiting for its program to compile (a comb
+    batch's first payload width compiles on the staging thread, minutes
+    on a cold cache) is off the deadline clock; the clock starts anew
+    when the program is there, so a hang AFTER the compile still trips;
+    and a compile past COMPILE_BOUND_S counts as the hang it is."""
+    from cometbft_tpu.verifysvc import service as service_mod
+
+    class CompilingBV(FakeDeviceBV):
+        on_compile = None  # the service sets it per dispatched batch
+        release = threading.Event()
+
+        def submit(self):
+            self.on_compile(True)  # program missing: waits from here
+            CompilingBV.told = self.on_compile
+            return ("dev", list(self._items))
+
+        def collect(self, ticket):
+            CompilingBV.release.wait(WAIT)
+            return _host_verdicts(ticket[1])
+
+    s = svc(batch_deadline_s=0.2, failover_tick_s=0.05)
+    s._make_verifier = lambda mode: (
+        CompilingBV() if s.backend_mode == MODE_TPU
+        else _HostBatchVerifier(mode)
+    )
+    items = _sigs(3, b"cold", tamper=(1,))
+    ticket = s.submit(items, Klass.CONSENSUS)
+    time.sleep(0.8)  # four deadlines "compiling"
+    assert s.backend_mode == MODE_TPU and not ticket.done()
+    assert s.stats()["in_flight"][0]["device_age_s"] is None
+    CompilingBV.told(False)  # compiled; the dispatch is made only now
+    assert (s.stats()["in_flight"][0]["device_age_s"] or 0.0) < 0.2
+    assert ticket.collect(WAIT) == _host_verdicts(items)  # the trip's re-verify
+    assert s.backend_mode == MODE_CPU_FALLBACK
+    CompilingBV.release.set()
+
+    # a compile that never returns is a hang once the bound is past
+    monkeypatch.setattr(service_mod, "COMPILE_BOUND_S", 0.3)
+    s2 = svc(batch_deadline_s=0.2, failover_tick_s=0.05)
+    CompilingBV.release = threading.Event()
+    s2._make_verifier = lambda mode: (
+        CompilingBV() if s2.backend_mode == MODE_TPU
+        else _HostBatchVerifier(mode)
+    )
+    assert s2.submit(items, Klass.CONSENSUS).collect(WAIT) == _host_verdicts(items)
+    assert s2.backend_mode == MODE_CPU_FALLBACK
+    CompilingBV.release.set()
+
+
 def test_service_restarts_after_stop(svc):
     """stop() then a later submit restarts the service; the stale stop
     signal must not leave the failover watchdog busy-spinning."""
@@ -549,7 +599,7 @@ def test_make_verifier_bypasses_comb_in_cpu_mode(svc):
 def test_resolve_mode_bypasses_comb_bind_when_tripped(monkeypatch):
     """A tripped global service makes resolve_mode return MODE_PLAIN
     without ever touching the comb cache — a table build is device work
-    and would hang with the wedged tunnel."""
+    and would hang with the wedged device."""
     from cometbft_tpu.verifysvc import service as service_mod
 
     s = VerifyService(probe_fn=lambda _t: _probe(False))

@@ -79,9 +79,12 @@ def test_spec_normalization():
     assert shardcheck.declared_spec_map(("sig",)) == {"0": "sig"}
     assert shardcheck.declared_spec_map((None, None, "sig")) == {"2": "sig"}
     assert shardcheck.declared_spec_map(()) == {}
-    assert shardcheck.traced_names_map({0: ("sig",)}) == {"0": "sig"}
-    assert shardcheck.traced_names_map({}) == {}
-    assert shardcheck.traced_names_map({1: ("a", "b")}) == {"1": "a+b"}
+    # a traced shard_map's PartitionSpec entries take the same road
+    from jax.sharding import PartitionSpec as P
+
+    assert shardcheck.declared_spec_map(P("sig")) == {"0": "sig"}
+    assert shardcheck.declared_spec_map(P()) == {}
+    assert shardcheck.declared_spec_map(P(None, ("a", "b"))) == {"1": "a+b"}
     assert shardcheck._fmt_spec({}) == "replicated"
     assert "0:sig" in shardcheck._fmt_spec({"0": "sig"})
 
@@ -90,7 +93,7 @@ def test_collective_prim_matcher():
     for name in ("psum", "all_gather", "all_to_all", "ppermute",
                  "sharding_constraint", "all_gather_invariant"):
         assert shardcheck.is_collective(name), name
-    for name in ("add", "scan", "shard_map", "pjit", "convert_element_type"):
+    for name in ("add", "scan", "shard_map", "jit", "convert_element_type"):
         assert not shardcheck.is_collective(name), name
 
 
@@ -516,31 +519,45 @@ def test_bench_reports_shardcheck(tmp_path):
     assert "elapsed_s" in rep
 
 
-# ------------------------------------------------- compile-cache knob
+# ------------------------------------------------- compile-cache rule
 
 
-def test_compile_cache_knob(tmp_path, monkeypatch):
+def test_compile_cache_rule(tmp_path, monkeypatch):
+    """utils/compilecache: with JAX_COMPILATION_CACHE_DIR set the helper
+    sets no directory at all (jax reads the variable by itself); unset,
+    it sets the in-checkout default, and nothing else."""
     import jax
 
     from cometbft_tpu.utils import compilecache
 
-    old_dir = jax.config.jax_compilation_cache_dir
-    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    old_sz = jax.config.jax_persistent_cache_min_entry_size_bytes
-    try:
-        monkeypatch.delenv("COMETBFT_TPU_COMPILE_CACHE", raising=False)
-        assert compilecache.maybe_enable() is None  # knob unset: no-op
-        target = str(tmp_path / "xla_cache")
-        monkeypatch.setenv("COMETBFT_TPU_COMPILE_CACHE", target)
-        got = compilecache.maybe_enable()
-        assert got == os.path.abspath(target) and os.path.isdir(target)
-        assert jax.config.jax_compilation_cache_dir == got
-        # default_dir is only a fallback; the knob wins
-        assert compilecache.maybe_enable(default_dir="/nonexistent") == got
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", old_sz)
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path / "placed"))
+    compilecache.enable()
+    assert calls == []
+    monkeypatch.delenv(compilecache.ENV_VAR)
+    compilecache.enable()
+    assert [c[1] for c in calls] == [compilecache.DEFAULT_DIR]
+    assert compilecache.DEFAULT_DIR == os.path.join(REPO, "tests", ".jax_cache")
+
+
+def test_compile_cache_default_is_the_same_path_from_any_process(tmp_path):
+    """The directory is part of what makes a cache warm: computed from
+    the module's own path, never from the cwd, a pid or the time."""
+    code = (
+        f"import sys; sys.path.insert(0, {REPO!r}); "
+        "from cometbft_tpu.utils import compilecache as c; "
+        "print(c.DEFAULT_DIR)"
+    )
+    seen = set()
+    for cwd in (REPO, str(tmp_path)):
+        proc = subprocess.run(
+            [sys.executable, "-c", code],
+            capture_output=True, text=True, timeout=60, cwd=cwd,
+        )
+        assert proc.returncode == 0, proc.stderr
+        seen.add(proc.stdout.strip())
+    assert seen == {os.path.join(REPO, "tests", ".jax_cache")}
 
 
 # ------------------------------------------------------- the slow gate

@@ -2,8 +2,8 @@
 adaptive batch formation, backpressure, blame-order preservation, and the
 mempool CheckTx client.
 
-All tests are CPU-only and fast: batches stay below the link-aware
-device threshold (models/verifier._device_batch_min), so the underlying
+All tests are CPU-only and fast: batches stay below the device
+threshold (models/verifier._device_batch_min), so the underlying
 verifiers host-route and no XLA program compiles — the scheduler logic
 under test is identical either way.
 """
@@ -221,6 +221,125 @@ def test_fifo_blame_order_across_classes(svc):
     assert (not ok_bg) and per_bg == [True, False, True]
     assert (not ok_cs) and per_cs == [True, True, True, False, True]
     assert ok_bs and per_bs == [True, True]
+
+
+def test_collect_clock_stops_for_a_compile_only(svc, monkeypatch):
+    """The client's collect() bound is for a stuck scheduler.  A batch
+    waiting for its program to compile — on a chip with a cold cache a
+    first-shape compile outlasts the bound — is work: the clock stands
+    still for it, runs again when it ends, and runs anyway once the
+    compile has outlasted COMPILE_BOUND_S."""
+    from cometbft_tpu.verifysvc import service as service_mod
+    from cometbft_tpu.verifysvc.service import Ticket
+
+    t = Ticket(1)
+    with pytest.raises(TimeoutError):
+        t.collect(0.1)  # no compile: the bound holds
+    t._resolve((True, [True]))
+    assert t.collect(0) == (True, [True])  # settled: no wait to bound
+
+    t = Ticket(1)
+    t.compiling_since = time.monotonic()
+    threading.Timer(0.5, t._resolve, args=((True, [True]),)).start()
+    assert t.collect(0.1) == (True, [True])  # outlasted the bound 5x
+
+    t = Ticket(1)
+    t.compiling_since = time.monotonic()
+    threading.Timer(0.3, setattr, args=(t, "compiling_since", None)).start()
+    t0 = time.monotonic()
+    with pytest.raises(TimeoutError):
+        t.collect(0.2)
+    assert 0.3 <= time.monotonic() - t0 < 5.0
+
+    monkeypatch.setattr(service_mod, "COMPILE_BOUND_S", 0.3)
+    t = Ticket(1)
+    t.compiling_since = time.monotonic()  # a compile that never returns
+    t0 = time.monotonic()
+    with pytest.raises(TimeoutError):
+        t.collect(0.2)
+    assert 0.3 <= time.monotonic() - t0 < 5.0
+
+    # and the service raises the flag for as long as the verifier says
+    s = svc(deadlines_ms={k: 0 for k in Klass})
+    seen = {}
+
+    class CompilingBV:
+        _entry = None  # offloaded to the host worker
+        on_compile = None
+
+        def add(self, pub, msg, sig):
+            pass
+
+        def submit(self):
+            seen["assembling"] = seen["ticket"].compiling()
+            self.on_compile(True)
+            seen["compiling"] = seen["ticket"].compiling()
+            self.on_compile(False)
+            seen["dispatching"] = seen["ticket"].compiling()
+            return ("sync", (True, [True]))
+
+        def collect(self, ticket):
+            return ticket[1]
+
+    s._make_verifier = lambda mode: CompilingBV()
+    gate = threading.Event()
+    real_dispatch = s._dispatch
+    s._dispatch = lambda *a: (gate.wait(WAIT), real_dispatch(*a))
+    seen["ticket"] = s.submit(_sigs(1), Klass.CONSENSUS)
+    gate.set()
+    assert seen["ticket"].collect(WAIT) == (True, [True])
+    assert seen == {
+        "ticket": seen["ticket"], "assembling": False, "compiling": True,
+        "dispatching": False,
+    }
+
+
+def test_hung_host_work_still_answers_from_host(svc, monkeypatch):
+    """Only the compile is off the collect clock.  A submit() that hangs
+    on the host worker outside it — the uncached path's transfers and
+    dispatch run there, and an accelerator can hang instead of erroring
+    — must not park the caller: the bound expires and the caller gets
+    host verdicts in its own add() order."""
+    from cometbft_tpu.verifysvc import service as service_mod
+
+    monkeypatch.setenv("COMETBFT_TPU_VERIFYSVC_COLLECT_TIMEOUT_MS", "300")
+    s = svc(deadlines_ms={k: 0 for k in Klass})
+    release = threading.Event()
+
+    class HangsAfterCompileBV:
+        _entry = None  # offloaded to the host worker
+        on_compile = None
+
+        def add(self, *item):
+            pass
+
+        def submit(self):
+            self.on_compile(True)
+            time.sleep(0.5)  # a compile longer than the bound: not charged
+            self.on_compile(False)
+            release.wait(WAIT)  # the transfer hangs "forever"
+            return ("sync", (True, [True] * 3))
+
+        def collect(self, ticket):
+            return ticket[1]
+
+    s._make_verifier = lambda mode: HangsAfterCompileBV()
+    before = mhub().verify_svc_collect_timeout.value(**{"class": "consensus"})
+    items = _sigs(3, b"hang", tamper=(1,))
+    bv = ServiceBatchVerifier(Klass.CONSENSUS, service=s, tenant="hang-t")
+    for pub, msg, sig in items:
+        bv.add(pub, msg, sig)
+    t0 = time.monotonic()
+    ok, per = bv.verify()
+    waited = time.monotonic() - t0
+    assert 0.5 <= waited < 5.0  # the compile and then the bound, not forever
+    assert (not ok) and per == [True, False, True]  # host verdicts, own order
+    assert (
+        mhub().verify_svc_collect_timeout.value(**{"class": "consensus"})
+        == before + 1
+    )
+    release.set()  # unpark the host worker so teardown joins cleanly
+    service_mod._reset_stall_gate()
 
 
 def test_host_queue_respects_class_priority(svc):
