@@ -1,0 +1,28 @@
+"""The arithmetic of the end-to-end metrics: percentiles by linear
+interpolation between the two closest ranks, and how many samples lie
+beyond one (a percentile is reported only with ten or more beyond it:
+choosing-metrics section 1)."""
+
+from __future__ import annotations
+
+import math
+
+
+def percentile(values, q: float) -> float:
+    """The q-th percentile (0..100) of ``values``; raises on none."""
+    xs = sorted(values)
+    if not xs:
+        raise ValueError("no samples")
+    pos = (len(xs) - 1) * q / 100.0
+    lo = math.floor(pos)
+    hi = min(lo + 1, len(xs) - 1)
+    return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
+
+
+def median(values) -> float:
+    return percentile(values, 50.0)
+
+
+def samples_beyond(n: int, q: float) -> int:
+    """How many of n samples lie beyond the q-th percentile."""
+    return int(math.floor(n * (100.0 - q) / 100.0 + 1e-9))

@@ -1,0 +1,203 @@
+"""The benchmark's command and its one-cell run, on the CPU: as a
+command it needs a TPU and must fail here with nothing on stdout; its
+legs run at a tiny width through ``harness.run_cell``, the internal
+entry that skips only the TPU check.  Eight signatures take the
+program's host route, which gives right verdicts, and the run must
+still say ``correct: false``: it reads the route, not only the verdicts
+(the negative test tests/test_chip_smoke.py has for the smoke)."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+import time
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from benchmarks import checks, harness, reference, spec  # noqa: E402
+from benchmarks.drivers import commit_serial  # noqa: E402
+
+BENCH = spec.load_benchmark()
+ARGS = ["--workload", "commit-175-serial", "--seed", "3", "--seconds", "1",
+        "--trace", "0"]
+
+
+def test_command_fails_for_want_of_a_tpu():
+    r = subprocess.run(
+        [sys.executable, *BENCH["command"][1:], *ARGS], capture_output=True,
+        text=True, timeout=120, cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert r.stdout == ""
+
+
+def test_command_fails_where_only_the_benchmark_is(tmp_path):
+    """BENCHMARK.json and the files under ``paths`` alone, without the
+    program: non-zero, no result."""
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path)
+    for p in BENCH["paths"]:
+        shutil.copytree(
+            os.path.join(REPO, p), tmp_path / p,
+            ignore=shutil.ignore_patterns("__pycache__", ".trace"),
+        )
+    r = subprocess.run(
+        [sys.executable, *BENCH["command"][1:], *ARGS], capture_output=True,
+        text=True, timeout=120, cwd=tmp_path,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=""),
+    )
+    assert r.returncode != 0
+    assert r.stdout == ""
+
+
+@pytest.mark.parametrize("kw", [
+    dict(height=1, round_=0, seconds=1_700_000_123, nanos=0),
+    dict(height=8, round_=0, seconds=1_700_000_999, nanos=0),
+    dict(height=1 << 40, round_=3, seconds=1, nanos=999_999_999),
+    dict(height=0, round_=0, seconds=0, nanos=0),
+])
+def test_reference_sign_bytes_equal_the_programs(kw):
+    """The reference encodes CanonicalVote by hand; the program's
+    encoder must give the same bytes, or every commit the benchmark
+    signs would be refused."""
+    from cometbft_tpu.wire.canonical import (
+        PRECOMMIT_TYPE, CanonicalBlockID, CanonicalPartSetHeader, Timestamp,
+        vote_sign_bytes,
+    )
+
+    block_hash, parts_hash = bytes(range(32)), bytes(range(32, 64))
+    want = vote_sign_bytes(
+        "bench-valset-175", PRECOMMIT_TYPE, kw["height"], kw["round_"],
+        CanonicalBlockID(
+            hash=block_hash,
+            part_set_header=CanonicalPartSetHeader(total=1, hash=parts_hash),
+        ),
+        Timestamp(seconds=kw["seconds"], nanos=kw["nanos"]),
+    )
+    got = reference.precommit_sign_bytes(
+        "bench-valset-175", kw["height"], kw["round_"], block_hash, 1,
+        parts_hash, kw["seconds"], kw["nanos"],
+    )
+    assert got == want
+
+
+def test_reference_verdicts():
+    key = reference.private_key(1, b"t", 0)
+    pub, sig = reference.public_bytes(key), key.sign(b"msg")
+    assert reference.verify(pub, b"msg", sig)
+    assert not reference.verify(pub, b"msg2", sig)
+    assert not reference.verify(pub, b"msg", sig[:-1] + bytes([sig[-1] ^ 1]))
+    assert not reference.verify(b"\x00" * 31, b"msg", sig)
+
+
+@pytest.fixture
+def fresh(monkeypatch):
+    """A fresh metrics hub, a fresh global verify service and an empty
+    span ring around a run_cell."""
+    from cometbft_tpu.utils import metrics, tracing
+    from cometbft_tpu.verifysvc import service as svc_mod
+
+    monkeypatch.setattr(metrics, "_HUB", metrics.Hub())
+    svc_mod.reset_global_service()
+    was_on = tracing.enabled()
+    yield
+    svc_mod.reset_global_service()
+    tracing.set_enabled(was_on)
+    tracing.reset()
+
+
+def tiny_cell(width: int, pool: int = 3):
+    cell = spec.resolve("commit-175-serial")
+    cell.config = dict(cell.config, validators=width)
+    cell.traffic = dict(cell.traffic, pool=pool, warm_verdicts=2)
+    return cell
+
+
+def run_tiny(cell, seconds=0.4, seed=(1 << 31) + 77):
+    import jax
+
+    return harness.run_cell(
+        cell, seed, seconds, False, time.monotonic(), jax.devices())  # (result, facts)
+
+
+def test_host_routed_run_counts_rightly_and_is_not_correct(fresh):
+    result, facts = run_tiny(tiny_cell(8))
+    assert result["correct"] is False
+    assert any("verify.host_route span(s)" in p for p in facts["problems"])
+    assert result["failed"] == 0 and result["attempted"] > 3
+    assert facts["samples"] == {"request_s": result["attempted"]}  # one each
+    assert set(result) == {"correct", "attempted", "failed", "device", "metrics"}
+    assert set(result["metrics"]) == {"verdict_p50_ms", "verdict_p90_ms", "setup_s"}
+    for name, m in result["metrics"].items():
+        assert m["value"] > 0 and m["unit"] == ("s" if name == "setup_s" else "ms")
+    assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    json.dumps(result)
+
+
+def test_a_verdict_that_raises_is_a_failed_request(fresh, monkeypatch):
+    """One commit of the pool of three is tampered after set-up: every
+    third verdict raises, is counted, and the traffic goes on."""
+    setup = commit_serial.setup
+
+    def setup_then_tamper(cell, seed, log):
+        state = setup(cell, seed, log)
+        sc = state.pool[2]  # the two warm verdicts take pool[0] and pool[1]
+        sc.commit, _ = checks.tampered(sc.commit, 8)
+        return state
+
+    monkeypatch.setattr(commit_serial, "setup", setup_then_tamper)
+    result, _ = run_tiny(tiny_cell(8))
+    assert result["correct"] is False
+    assert result["attempted"] >= 3
+    assert result["failed"] in (result["attempted"] // 3,
+                                (result["attempted"] + 1) // 3)
+
+
+def test_a_failed_check_of_setup_prints_no_result(fresh, monkeypatch, capsys):
+    import types
+
+    import jax
+
+    def setup(cell, seed, log):
+        raise checks.CheckFailure("verdicts differ from the reference")
+
+    monkeypatch.setattr(commit_serial, "setup", setup)
+    dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda: [dev])
+    assert harness.main(ARGS, time.monotonic()) == 1
+    assert capsys.readouterr().out == ""
+
+
+def test_same_seed_same_inputs_and_lengths_never_depend_on_it():
+    from benchmarks import data
+
+    cell = tiny_cell(5)
+    a = data.make_commits(data.make_valset(cell.config, 11), 2)
+    b = data.make_commits(data.make_valset(cell.config, 11), 2)
+    c = data.make_commits(data.make_valset(cell.config, (1 << 31) + 5), 2)
+    sigs = lambda pool: [s.signature for sc in pool for s in sc.commit.signatures]
+    assert sigs(a) == sigs(b) and sigs(a) != sigs(c)
+    assert [len(m) for sc in a for m in sc.sign_bytes] == [
+        len(m) for sc in c for m in sc.sign_bytes]
+
+
+@pytest.mark.slow  # compiles the uncached, table-build and comb programs
+@pytest.mark.parametrize("width,comb", [(8, False), (10, True)])
+def test_legs_hold_over_the_device_programs(fresh, monkeypatch, width, comb):
+    """The same legs with the thresholds lowered (here, never in the
+    benchmark) so that the routes of 175 and of 10,000 validators are
+    taken: the uncached program, and tables bound in set-up and then
+    the comb program."""
+    monkeypatch.setenv("COMETBFT_TPU_DEVICE_BATCH_MIN", "1")
+    if comb:
+        monkeypatch.setenv("COMETBFT_TPU_COMB_MIN", "8")
+        monkeypatch.setenv("COMETBFT_TPU_COMB_ASYNC_MIN", "8")
+        monkeypatch.setenv("COMETBFT_TPU_COMB_HOST_BUILD_MAX", "0")
+    result, facts = run_tiny(tiny_cell(width), seconds=2.0)
+    assert facts["problems"] == []
+    assert result["correct"] is True
+    assert result["failed"] == 0 and result["attempted"] >= 1
