@@ -118,6 +118,10 @@ class Trace:
     primitives: dict[str, int]
     findings: list[Finding] = field(default_factory=list)
     eqns: int = 0  # total jaxpr equations, nested bodies included
+    # jax.named_scope names met on the equations' name stacks: the
+    # phases a profile is read by (docs/observability.md).  Outside the
+    # fingerprint: a scope adds no primitive and moves no digest.
+    scopes: frozenset[str] = frozenset()
 
     def fingerprint(self) -> dict:
         payload = {
@@ -235,11 +239,17 @@ def trace_kernel(kernel: manifest.Kernel) -> Trace:
 
     prims: dict[str, int] = {}
     total_eqns = 0
+    scopes: set[str] = set()
     for jaxpr in _walk_jaxprs(closed.jaxpr):
         for eqn in jaxpr.eqns:
             total_eqns += 1
             name = eqn.primitive.name
             prims[name] = prims.get(name, 0) + 1
+
+            scopes.update(
+                el.name for el in eqn.source_info.name_stack.stack
+                if type(el).__name__ == "Scope"
+            )
 
             if name in _FORBIDDEN_PRIMS or any(
                 s in name for s in _FORBIDDEN_PRIM_SUBSTRINGS
@@ -295,7 +305,8 @@ def trace_kernel(kernel: manifest.Kernel) -> Trace:
             "loop with lax.scan / precompute host-side) or raise the "
             "budget with justification"
         )
-    return Trace(kernel, signature, prims, findings, total_eqns)
+    return Trace(kernel, signature, prims, findings, total_eqns,
+                 frozenset(scopes))
 
 
 # -------------------------------------------------------------- drift gate

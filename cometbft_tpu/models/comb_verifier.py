@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import hashlib
 import threading
-import time as _time
 from collections import OrderedDict
 
 import numpy as np
@@ -344,29 +343,23 @@ def _build_tables(pub_arr: np.ndarray):
     from ..utils import envknobs
 
     lim = envknobs.get_int(envknobs.COMB_HOST_BUILD_MAX)
-    t0 = _time.perf_counter()
     if 0 < pub_arr.shape[0] <= lim:
-        with tracing.span(
-            "verify.table_build", {"backend": "host"} if tracing.enabled() else None
+        with tracing.phase(
+            "verify.table_build", "table_build_host",
+            labels={"backend": "host"},
         ):
-            out = comb.build_a_tables_host(pub_arr)
-        _mhub().verify_phase_seconds.observe(
-            _time.perf_counter() - t0, phase="table_build_host"
-        )
-        return out
+            return comb.build_a_tables_host(pub_arr)
     import jax.numpy as jnp
 
-    with tracing.span(
-        "verify.table_build", {"backend": "device"} if tracing.enabled() else None
+    with tracing.phase(
+        "verify.table_build", "table_build_device",
+        labels={"backend": "device"},
     ):
         out = comb.build_a_tables_jit(jnp.asarray(pub_arr))
         # the jit dispatch is async: wait for the arithmetic so the
         # phase is the COMPLETED build (the host counterpart measures
         # completed work; comparing the two is this split's purpose)
         out[0].block_until_ready()
-    _mhub().verify_phase_seconds.observe(
-        _time.perf_counter() - t0, phase="table_build_device"
-    )
     return out
 
 
@@ -612,13 +605,10 @@ class CombBatchVerifier:
         # same rule as the uncached kernel: a small batch (few signers
         # of a large cached set) finishes sooner on the host even though
         # the tables are warm
-        from .verifier import CpuEd25519BatchVerifier, _device_batch_min
+        from .verifier import _device_batch_min, host_route
 
         if n < _device_batch_min():
-            cpu = CpuEd25519BatchVerifier()
-            cpu._items = self._items
-            with tracing.span("verify.host_route"):
-                return ("sync", cpu.verify())
+            return ("sync", host_route(self._items, "comb"))
 
         idx = np.asarray(self._rows, dtype=np.int64)
         # real snapshot for the staging thread: a verifier is one batch
@@ -648,8 +638,6 @@ class CombBatchVerifier:
         m.verify_submit_queue_depth.add(1)
 
         def stage():
-            import time
-
             import jax.numpy as jnp
 
             timings = {}
@@ -660,28 +648,28 @@ class CombBatchVerifier:
                 finally:
                     if waiting:
                         on_compile(False)
-                t0 = time.perf_counter()
                 # One TIGHT (V, 68 + maxm) row: R | s | mlen(3B LE) | live |
                 # msg.  The call ships only irreducible bytes in ONE
                 # transfer: no SHA padding (rebuilt on device,
                 # ops/sha2.ram_blocks_from_parts), no pubkeys (device-resident
                 # in the cache entry), no zero blocks.  The slab is recycled
                 # host memory — steady state allocates nothing.
-                with tracing.span("verify.slab_fill"):
+                with tracing.phase(
+                    "verify.slab_fill", "assembly", timings, "assembly_ms"
+                ):
                     slab = entry.acquire_slab(width)
                     payload = _fill_payload(slab, items, idx)
-                t1 = time.perf_counter()
-                with tracing.span("verify.h2d_dispatch"):
+                with tracing.phase(
+                    "verify.h2d_dispatch", "h2d_dispatch", timings,
+                    "h2d_dispatch_ms",
+                ):
                     out = fn(
                         entry.tables, entry.valid, entry.pubs,
                         jnp.asarray(payload),
                     )
-                t2 = time.perf_counter()
-                timings["assembly_ms"] = (t1 - t0) * 1e3
-                timings["h2d_dispatch_ms"] = (t2 - t1) * 1e3
-                m.verify_phase_seconds.observe(t1 - t0, phase="assembly")
-                m.verify_phase_seconds.observe(t2 - t1, phase="h2d_dispatch")
-                m.verify_staging_busy.inc(t2 - t0)
+                m.verify_staging_busy.inc(
+                    (timings["assembly_ms"] + timings["h2d_dispatch_ms"]) / 1e3
+                )
                 return out, slab, timings
             except BaseException:
                 # a failed fill/dispatch must not leak the pooled slab —
@@ -715,19 +703,20 @@ class CombBatchVerifier:
         if kind == "sync":
             return payload
         fut, idx = payload
-        import time as _time
-
         # Two distinct waits, measured separately: fut.result() blocks
         # until the STAGING thread finishes (queue + slab fill + H2D +
         # dispatch — in the submit-then-collect-immediately pattern this
         # covers the whole staging pass, which must not be billed to the
         # device), then np.asarray blocks until the KERNEL's result lands.
-        t0 = _time.perf_counter()
-        with tracing.span("verify.staging_wait"):
+        waits: dict[str, float] = {}
+        with tracing.phase(
+            "verify.staging_wait", "staging_wait", waits, "staging_wait_ms"
+        ):
             out, slab, timings = fut.result()
-        t1 = _time.perf_counter()
         try:
-            with tracing.span("verify.device_wait"):
+            with tracing.phase(
+                "verify.device_wait", "device_wait", waits, "device_wait_ms"
+            ):
                 host = np.asarray(out)  # the one blocking device fetch
         except BaseException:
             # async dispatch errors surface at this fetch (a lost
@@ -735,15 +724,10 @@ class CombBatchVerifier:
             slab.retire()
             self._entry.release_slab(slab)
             raise
-        t2 = _time.perf_counter()
-        timings["staging_wait_ms"] = (t1 - t0) * 1e3
-        timings["device_wait_ms"] = (t2 - t1) * 1e3
-        m = _mhub()
-        m.verify_phase_seconds.observe(t1 - t0, phase="staging_wait")
-        m.verify_phase_seconds.observe(t2 - t1, phase="device_wait")
         # the kernel has consumed the staged payload; recycle the slab
         self._entry.release_slab(slab)
         self.last_timings.update(timings)
+        self.last_timings.update(waits)
         with tracing.span("verify.blame_unpack"):
             all_ok = bool(host[-1])
             picked = (
@@ -753,26 +737,24 @@ class CombBatchVerifier:
             return all_ok, picked.tolist()
 
     def verify(self) -> tuple[bool, list[bool]]:
-        import time
-
         self.last_timings = {}
-        t0 = time.perf_counter()
-        with tracing.span("verify.submit"):
+        outer: dict[str, float] = {}
+        with tracing.phase("verify.submit", None, outer, "submit_ms"):
             ticket = self.submit()
-        t1 = time.perf_counter()
-        result = self.collect(ticket)
-        t2 = time.perf_counter()
+        # no span of its own: collect()'s waits and the blame unpack are
+        # the spans of these lines
+        with tracing.phase(None, None, outer, "kernel_ms"):
+            result = self.collect(ticket)
         if ticket[0] == "sync":
             # host-routed (small batch / fallback): all work happened
             # inside submit(); labeling it assembly_ms would corrupt the
             # phase breakdowns the measurement scripts record
-            self.last_timings = {"host_ms": (t1 - t0) * 1e3}
+            self.last_timings = {"host_ms": outer["submit_ms"]}
         else:
             # collect() merged the staging thread's assembly_ms /
             # h2d_dispatch_ms into last_timings already; kernel_ms is the
             # caller-visible wait (device execution minus what overlapped)
-            self.last_timings["submit_ms"] = (t1 - t0) * 1e3
-            self.last_timings["kernel_ms"] = (t2 - t1) * 1e3
+            self.last_timings.update(outer)
         return result
 
     def _program(self, width: int):
@@ -820,17 +802,20 @@ def _device_verify(tables, valid, pubs, payload):
     default while fingerprinting, so goldens always describe the tree
     path).
     """
+    import jax
     import jax.numpy as jnp
 
     from ..ops import comb, sha2
 
     bt = comb.get_b_tables()
-    r, s, blocks, active, live = sha2.parse_verify_payload(payload, pubs)
+    with jax.named_scope("payload_parse"):
+        r, s, blocks, active, live = sha2.parse_verify_payload(payload, pubs)
     k_digest = sha2.sha512_blocks(blocks, active)
     ok = comb.verify_cached(tables, valid, r, s, k_digest, bt)
-    bits = jnp.packbits(ok & live)
-    all_ok = jnp.all(ok | ~live).astype(jnp.uint8)
-    return jnp.concatenate([bits, all_ok[None]])
+    with jax.named_scope("pack_result"):
+        bits = jnp.packbits(ok & live)
+        all_ok = jnp.all(ok | ~live).astype(jnp.uint8)
+        return jnp.concatenate([bits, all_ok[None]])
 
 
 def _bucket_mlen(mlen: int) -> int:

@@ -103,6 +103,18 @@ def _device_batch_min() -> int:
     return envknobs.get_int(envknobs.DEVICE_BATCH_MIN)
 
 
+def host_route(items, lane: str) -> tuple[bool, list[bool]]:
+    """A batch under DEVICE_BATCH_MIN, verified on the host by a device
+    verifier (``lane``: "uncached" or "comb").  Counted and spanned: a
+    healthy chip's cells must read zero here, and /metrics says so
+    without the span ring."""
+    _metrics_hub().verify_host_route.inc(lane=lane, reason="below_batch_min")
+    cpu = CpuEd25519BatchVerifier()
+    cpu._items = items
+    with tracing.span("verify.host_route"):
+        return cpu.verify()
+
+
 class TpuEd25519BatchVerifier:
     """Batched ZIP-215 verification on the default JAX device.
 
@@ -164,10 +176,7 @@ class TpuEd25519BatchVerifier:
         # The hot configs (150-val light blocks, 10k-val commits) always
         # take the device path.
         if n < _device_batch_min():
-            cpu = CpuEd25519BatchVerifier()
-            cpu._items = self._items
-            with tracing.span("verify.host_route"):
-                return ("sync", cpu.verify())
+            return ("sync", host_route(self._items, "uncached"))
         return ("dev", (self._submit_device(n), n))
 
     def collect(self, ticket) -> tuple[bool, list[bool]]:
@@ -175,25 +184,16 @@ class TpuEd25519BatchVerifier:
         if kind == "sync":
             return payload
         out, n = payload
-        import time as _time
-
-        t0 = _time.perf_counter()
-        with tracing.span("verify.device_wait"):
+        with tracing.phase("verify.device_wait", "device_wait"):
             ok = np.asarray(out)[:n]  # blocks until the device result lands
-        _metrics_hub().verify_phase_seconds.observe(
-            _time.perf_counter() - t0, phase="device_wait"
-        )
         res = [bool(x) for x in ok]
         return all(res), res
 
     def _submit_device(self, n: int):
-        import time as _time
-
         import jax.numpy as jnp
         from ..ops import sha2
 
-        t0 = _time.perf_counter()
-        with tracing.span("verify.uncached_assemble"):
+        with tracing.phase("verify.uncached_assemble", "assembly"):
             bucket = _next_bucket(n)
             a = np.zeros((bucket, 32), dtype=np.uint8)
             r = np.zeros((bucket, 32), dtype=np.uint8)
@@ -209,16 +209,9 @@ class TpuEd25519BatchVerifier:
                 a[i], r[i], s[i] = a[0], r[0], s[0]
                 hashed.append(hashed[0])
             blocks, active = sha2.pad_messages_sha512(hashed)
-        t_asm = _time.perf_counter()
         arrays = (a, r, s, blocks, active)
         fn = self._program(arrays)  # a new bucket shape compiles here
-        t1 = _time.perf_counter()
         # device dispatch is asynchronous: the returned array is a future
-        with tracing.span("verify.h2d_dispatch"):
+        with tracing.phase("verify.h2d_dispatch", "h2d_dispatch"):
             out = fn(*(jnp.asarray(x) for x in arrays))
-        m = _metrics_hub()
-        m.verify_phase_seconds.observe(t_asm - t0, phase="assembly")
-        m.verify_phase_seconds.observe(
-            _time.perf_counter() - t1, phase="h2d_dispatch"
-        )
         return out

@@ -71,6 +71,7 @@ _D2_C = F.to_limbs(ref.D2)[:, None]  # (22, 1) broadcastable constant
 # --------------------------------------------------- A-table construction
 
 
+@jax.named_scope("table_build")  # set-up's program, by name in a profile
 def build_a_tables(a_enc):
     """(V, 32) uint8 compressed pubkeys ->
        (tables (64, 9, 3, 22, V) int32 affine-Niels, valid (V,) bool).
@@ -504,24 +505,32 @@ def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None
     caught by the sharded census (analysis/shardcheck,
     docs/sharding_contracts.md).
     """
-    k_limbs = scalar.reduce_mod_l(scalar.bytes_to_limbs(k_digest, scalar.NL_X))
-    # signed radix-16 digits in [-8, 7]: |d| selects the entry, the sign
-    # flips the Niels point ((y+x, y-x, 2dxy) -> (y-x, y+x, -2dxy))
-    k_dig = scalar.signed_digits_radix16(k_limbs, NPOS_A)  # (64, V)
-    s_ok = scalar.s_lt_l(s_bytes)
-    # s as 22 x 12-bit digits, LSB first: exactly its base-2^12 limbs
-    s_dig = scalar.bytes_to_limbs(s_bytes, NPOS_B)  # (22, V)
+    # the scope names are the uncached program's (ops/ed25519): one
+    # reading of a profile serves both
+    with jax.named_scope("scalar_prep"):
+        k_limbs = scalar.reduce_mod_l(
+            scalar.bytes_to_limbs(k_digest, scalar.NL_X)
+        )
+        # signed radix-16 digits in [-8, 7]: |d| selects the entry, the
+        # sign flips the Niels point ((y+x, y-x, 2dxy) -> (y-x, y+x, -2dxy))
+        k_dig = scalar.signed_digits_radix16(k_limbs, NPOS_A)  # (64, V)
+        s_ok = scalar.s_lt_l(s_bytes)
+        # s as 22 x 12-bit digits, LSB first: exactly its base-2^12 limbs
+        s_dig = scalar.bytes_to_limbs(s_bytes, NPOS_B)  # (22, V)
 
-    r_pt, r_valid = E.decompress(r_enc)
+    with jax.named_scope("decompress"):
+        r_pt, r_valid = E.decompress(r_enc)
 
     if tree is None:
         tree = tree_enabled()
     acc_fn = _accumulate_tree if tree else _accumulate_sequential
-    acc = acc_fn(tables, k_dig, s_dig, b_tables, r_pt)
+    with jax.named_scope("scalar_mul"):
+        acc = acc_fn(tables, k_dig, s_dig, b_tables, r_pt)
 
     # ---- clear cofactor, check identity
-    acc = E.double(E.double(E.double(acc)))
-    return E.is_identity(acc) & a_valid & r_valid & s_ok
+    with jax.named_scope("final_check"):
+        acc = E.double(E.double(E.double(acc)))
+        return E.is_identity(acc) & a_valid & r_valid & s_ok
 
 
 def _accumulate_sequential(tables, k_dig, s_dig, b_tables, r_pt):
@@ -547,7 +556,8 @@ def _accumulate_sequential(tables, k_dig, s_dig, b_tables, r_pt):
         t2d = F.select(neg, -sel[2], sel[2])
         return E.add_niels(acc, E.Niels(yplusx, yminusx, t2d))
 
-    acc = lax.fori_loop(0, NPOS_A, a_body, E.identity((V,)))
+    with jax.named_scope("comb_lookup_a"):
+        acc = lax.fori_loop(0, NPOS_A, a_body, E.identity((V,)))
 
     # ---- B part: acc += B_TAB[i][:, s_i], 22 adds, MXU one-hot matmul
     ents_b = jnp.arange(NENT_B, dtype=jnp.int32)[:, None]
@@ -565,7 +575,8 @@ def _accumulate_sequential(tables, k_dig, s_dig, b_tables, r_pt):
             acc, E.Niels(sel[0:22], sel[22:44], sel[44:66])
         )
 
-    acc = lax.fori_loop(0, NPOS_B, b_body, acc)
+    with jax.named_scope("comb_lookup_b"):
+        acc = lax.fori_loop(0, NPOS_B, b_body, acc)
     return E.add(acc, E.neg(r_pt))
 
 
@@ -584,19 +595,20 @@ def _accumulate_tree(tables, k_dig, s_dig, b_tables, r_pt):
     a 12x shorter dependency chain, a clear win on a latency-bound chip.
     """
     # ---- A part: all 64 sign-adjusted selections in one shot
-    neg_d = k_dig < 0
-    absd = jnp.abs(k_dig)
-    ents_a = jnp.arange(NENT_A, dtype=jnp.int32)[None, :, None]
-    onehot_a = (ents_a == absd[:, None, :]).astype(jnp.int32)  # (64, 9, V)
-    sel = jnp.sum(
-        tables * onehot_a[:, :, None, None, :], axis=1
-    )  # (64, 3, 22, V)
-    na = E.Niels(
-        F.select(neg_d, sel[:, 1], sel[:, 0]),
-        F.select(neg_d, sel[:, 0], sel[:, 1]),
-        F.select(neg_d, -sel[:, 2], sel[:, 2]),
-    )
-    pa = E.niels_to_extended(na)  # coords (64, 22, V)
+    with jax.named_scope("comb_lookup_a"):
+        neg_d = k_dig < 0
+        absd = jnp.abs(k_dig)
+        ents_a = jnp.arange(NENT_A, dtype=jnp.int32)[None, :, None]
+        onehot_a = (ents_a == absd[:, None, :]).astype(jnp.int32)  # (64, 9, V)
+        sel = jnp.sum(
+            tables * onehot_a[:, :, None, None, :], axis=1
+        )  # (64, 3, 22, V)
+        na = E.Niels(
+            F.select(neg_d, sel[:, 1], sel[:, 0]),
+            F.select(neg_d, sel[:, 0], sel[:, 1]),
+            F.select(neg_d, -sel[:, 2], sel[:, 2]),
+        )
+        pa = E.niels_to_extended(na)  # coords (64, 22, V)
 
     # ---- B part: 22 independent one-hot MXU matmuls (no add chain);
     # unrolled so each keeps the (4096, V) onehot transient of the
@@ -604,26 +616,28 @@ def _accumulate_tree(tables, k_dig, s_dig, b_tables, r_pt):
     # f32 one-hot for the MXU path: int32 -> float32 -> int32 is exact
     # for the 12-bit Niels limbs (both conversions are in the manifest's
     # justified ALLOWED_CONVERSIONS set; HIGHEST forbids bf16 passes)
-    ents_b = jnp.arange(NENT_B, dtype=jnp.int32)[:, None]
-    sels = []
-    for i in range(NPOS_B):
-        onehot = (ents_b == s_dig[i][None, :]).astype(jnp.float32)
-        sels.append(
-            jnp.matmul(
-                b_tables[i], onehot, precision=lax.Precision.HIGHEST
-            ).astype(jnp.int32)
-        )  # (66, V)
-    selb = jnp.stack(sels)  # (22, 66, V)
-    pb = E.niels_to_extended(
-        E.Niels(selb[:, 0:22], selb[:, 22:44], selb[:, 44:66])
-    )
+    with jax.named_scope("comb_lookup_b"):
+        ents_b = jnp.arange(NENT_B, dtype=jnp.int32)[:, None]
+        sels = []
+        for i in range(NPOS_B):
+            onehot = (ents_b == s_dig[i][None, :]).astype(jnp.float32)
+            sels.append(
+                jnp.matmul(
+                    b_tables[i], onehot, precision=lax.Precision.HIGHEST
+                ).astype(jnp.int32)
+            )  # (66, V)
+        selb = jnp.stack(sels)  # (22, 66, V)
+        pb = E.niels_to_extended(
+            E.Niels(selb[:, 0:22], selb[:, 22:44], selb[:, 44:66])
+        )
 
     # ---- fold A partials + B partials + (-R) in one tree
-    nr = E.neg(r_pt)
-    stack = E.Point(
-        jnp.concatenate([pa.x, pb.x, nr.x[None]], axis=0),
-        jnp.concatenate([pa.y, pb.y, nr.y[None]], axis=0),
-        jnp.concatenate([pa.z, pb.z, nr.z[None]], axis=0),
-        jnp.concatenate([pa.t, pb.t, nr.t[None]], axis=0),
-    )
-    return E.tree_reduce_points(stack)
+    with jax.named_scope("tree_reduce"):
+        nr = E.neg(r_pt)
+        stack = E.Point(
+            jnp.concatenate([pa.x, pb.x, nr.x[None]], axis=0),
+            jnp.concatenate([pa.y, pb.y, nr.y[None]], axis=0),
+            jnp.concatenate([pa.z, pb.z, nr.z[None]], axis=0),
+            jnp.concatenate([pa.t, pb.t, nr.t[None]], axis=0),
+        )
+        return E.tree_reduce_points(stack)

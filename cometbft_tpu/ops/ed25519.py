@@ -40,6 +40,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -352,11 +353,15 @@ def verify_prepared(a_enc, r_enc, s_windows, k_windows, s_ok):
     sharing one doubling chain; the per-signature (-A) window table is
     built once (1 dbl + 13 adds).  The step loop is a lax.fori_loop so the
     compiled graph is one window body regardless of scalar length.
+
+    The ``jax.named_scope`` names are the phases a profile is read by
+    (docs/observability.md); ops/comb.verify_cached uses the same ones.
     """
-    a_pt, a_valid = decompress(a_enc)
-    r_pt, r_valid = decompress(r_enc)
-    neg_a = neg(a_pt)
-    table = build_var_table(neg_a)  # windows of -A
+    with jax.named_scope("decompress"):
+        a_pt, a_valid = decompress(a_enc)
+        r_pt, r_valid = decompress(r_enc)
+    with jax.named_scope("var_table"):
+        table = build_var_table(neg(a_pt))  # windows of -A
 
     def step(i, acc):
         acc = double(double(double(double(acc))))
@@ -371,10 +376,12 @@ def verify_prepared(a_enc, r_enc, s_windows, k_windows, s_ok):
         return lax.dynamic_index_in_dim(s_windows, i, axis=-2, keepdims=False)
 
     batch = a_enc.shape[:-1]
-    acc = lax.fori_loop(0, 64, step, identity(batch))
-    acc = add(acc, neg(r_pt))
-    acc = double(double(double(acc)))
-    return is_identity(acc) & a_valid & r_valid & s_ok
+    with jax.named_scope("scalar_mul"):
+        acc = lax.fori_loop(0, 64, step, identity(batch))
+    with jax.named_scope("final_check"):
+        acc = add(acc, neg(r_pt))
+        acc = double(double(double(acc)))
+        return is_identity(acc) & a_valid & r_valid & s_ok
 
 
 def verify_batch(a_enc, r_enc, s_bytes, msg_blocks, msg_active):
@@ -403,8 +410,11 @@ def verify_batch(a_enc, r_enc, s_bytes, msg_blocks, msg_active):
 
     # RFC 8032 interprets the 64-byte digest as a little-endian integer.
     k_digest = sha2.sha512_blocks(msg_blocks, msg_active)  # (N, 64)
-    k_limbs = scalar.reduce_mod_l(scalar.bytes_to_limbs(k_digest, scalar.NL_X))
-    k_windows = scalar.limbs_to_windows(k_limbs)  # (64, N)
-    s_windows = scalar.bytes_to_windows(s_bytes)  # (64, N)
-    s_ok = scalar.s_lt_l(s_bytes)  # (N,)
+    with jax.named_scope("scalar_prep"):
+        k_limbs = scalar.reduce_mod_l(
+            scalar.bytes_to_limbs(k_digest, scalar.NL_X)
+        )
+        k_windows = scalar.limbs_to_windows(k_limbs)  # (64, N)
+        s_windows = scalar.bytes_to_windows(s_bytes)  # (64, N)
+        s_ok = scalar.s_lt_l(s_bytes)  # (N,)
     return verify_prepared(a_enc, r_enc, s_windows, k_windows, s_ok)

@@ -25,6 +25,7 @@ Reference workloads served by these kernels:
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -186,6 +187,7 @@ def _add64_many(*pairs):
     return h, l
 
 
+@jax.named_scope("sha512")  # the phase's name in a profile, both verify programs
 def sha512_blocks(blocks, active_blocks=None):
     """(..., nblocks, 128) uint8 padded message -> (..., 64) uint8 digest.
 

@@ -27,6 +27,7 @@ from dataclasses import dataclass
 from fractions import Fraction
 
 from ..crypto import batch as crypto_batch
+from ..utils import tracing
 from .block import BlockID, Commit, CommitSig
 from .validators import ValidatorSet
 
@@ -321,22 +322,27 @@ def _verify_commit_batch(
     bv = crypto_batch.create_batch_verifier(
         proposer.pub_key.type, pubkeys=vals.pub_keys_bytes(), klass=klass
     )
-    batch_sig_idxs, sign_bytes_at = _assemble_commit_batch(
-        bv, chain_id, vals, commit, voting_power_needed, ignore_sig,
-        count_sig, count_all_signatures, lookup_by_index, cache,
-    )
+    with tracing.span("commit.assemble"):
+        batch_sig_idxs, sign_bytes_at = _assemble_commit_batch(
+            bv, chain_id, vals, commit, voting_power_needed, ignore_sig,
+            count_sig, count_all_signatures, lookup_by_index, cache,
+        )
     if not batch_sig_idxs:
         return  # everything came from the cache
 
-    if VERIFY_LATENCY_OBSERVER is not None:
-        import time as _time
+    with tracing.span("commit.verify"):
+        if VERIFY_LATENCY_OBSERVER is not None:
+            import time as _time
 
-        _t0 = _time.perf_counter()
-        ok, valid_sigs = bv.verify()
-        VERIFY_LATENCY_OBSERVER(_time.perf_counter() - _t0)
-    else:
-        ok, valid_sigs = bv.verify()
-    _judge_batch_result(ok, valid_sigs, commit, batch_sig_idxs, sign_bytes_at, cache)
+            _t0 = _time.perf_counter()
+            ok, valid_sigs = bv.verify()
+            VERIFY_LATENCY_OBSERVER(_time.perf_counter() - _t0)
+        else:
+            ok, valid_sigs = bv.verify()
+    with tracing.span("commit.judge"):
+        _judge_batch_result(
+            ok, valid_sigs, commit, batch_sig_idxs, sign_bytes_at, cache
+        )
 
 
 class PendingCommitVerification:
@@ -359,11 +365,13 @@ class PendingCommitVerification:
     def collect(self) -> None:
         if self._bv is None:
             return  # everything came from the signature cache
-        ok, valid_sigs = self._bv.collect(self._ticket)
-        _judge_batch_result(
-            ok, valid_sigs, self._commit, self._idxs, self._sign_bytes_at,
-            self._cache,
-        )
+        with tracing.span("commit.verify"):
+            ok, valid_sigs = self._bv.collect(self._ticket)
+        with tracing.span("commit.judge"):
+            _judge_batch_result(
+                ok, valid_sigs, self._commit, self._idxs,
+                self._sign_bytes_at, self._cache,
+            )
 
 
 def submit_verify_commit_light(
@@ -398,18 +406,21 @@ def submit_verify_commit_light(
     if not hasattr(bv, "submit"):
         return None  # host verifier: no async seam, caller runs sync
     voting_power_needed = vals.total_voting_power() * 2 // 3
-    batch_sig_idxs, sign_bytes_at = _assemble_commit_batch(
-        bv, chain_id, vals, commit, voting_power_needed,
-        ignore_sig=lambda cs: not cs.for_block(),
-        count_sig=lambda cs: True,
-        count_all_signatures=count_all_signatures,
-        lookup_by_index=True,
-        cache=cache,
-    )
+    with tracing.span("commit.assemble"):
+        batch_sig_idxs, sign_bytes_at = _assemble_commit_batch(
+            bv, chain_id, vals, commit, voting_power_needed,
+            ignore_sig=lambda cs: not cs.for_block(),
+            count_sig=lambda cs: True,
+            count_all_signatures=count_all_signatures,
+            lookup_by_index=True,
+            cache=cache,
+        )
     if not batch_sig_idxs:
         return PendingCommitVerification(None, None, commit, [], sign_bytes_at, cache)
+    with tracing.span("commit.verify"):
+        ticket = bv.submit()
     return PendingCommitVerification(
-        bv, bv.submit(), commit, batch_sig_idxs, sign_bytes_at, cache
+        bv, ticket, commit, batch_sig_idxs, sign_bytes_at, cache
     )
 
 

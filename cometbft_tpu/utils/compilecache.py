@@ -15,6 +15,17 @@ TPU), so every entry point — ``python -m cometbft_tpu``, ``bench.py``,
   its time limit only because that directory is), so it is fixed: never
   a temporary directory, a pid or a time.
 
+The key holds the program's metadata
+(``jax_compilation_cache_include_metadata_in_key``): jax 0.9 strips
+``jax.named_scope`` names and source locations before it hashes a
+program, so an executable cached before a scope was named is served to
+the program that names it, WITHOUT the names, and a profile of it reads
+as the old program (seen on the CPU backend and on the v5e, PR 25: the
+per-kernel metrics of benchmarks/ read nothing from such a cache).  The
+price: a key now moves with the line numbers and the checkout's path of
+the files a kernel is traced from, so an edit above a kernel, or another
+checkout, compiles again where it used to hit.
+
 Nothing else in the repository touches ``jax_compilation_cache_dir``
 (``__graft_entry__._disable_compile_cache`` turns the cache OFF for one
 run; it places nothing).  Call :func:`enable` before the first compile:
@@ -46,4 +57,5 @@ def enable() -> str:
 
     if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir

@@ -397,6 +397,12 @@ class Hub:
             "Cumulative busy time of the comb staging thread (ratio to "
             "wall clock = staging-thread occupancy)",
         )
+        self.verify_host_route = r.counter(
+            "verify_host_route_total",
+            "Batches a device verifier answered on the host (label "
+            "lane=uncached|comb, reason=below_batch_min: narrower than "
+            "COMETBFT_TPU_DEVICE_BATCH_MIN)",
+        )
         self.comb_table_cache = r.counter(
             "verify_comb_table_cache_total",
             "Valset comb-table cache lookups (label result=hit|miss|"

@@ -16,6 +16,19 @@ allocation, no clock read — so the hot paths stay instrumented in
 production builds.  Enabled, a span is two perf_counter_ns reads plus a
 tuple append; the ring bounds total memory however long the run.
 
+On the profiler's clock: a recording span also enters a
+``jax.profiler.TraceAnnotation`` of its name (name only; instants are
+not mirrored), so while ``jax.profiler`` runs every program span lands
+on ``/host:CPU`` of the profile, on the line of the thread that ran it
+and on the same clock as the device's ``XLA Ops``.  JAX is imported
+when tracing is turned on, never at this module's import, and a process
+without JAX keeps the ring alone.
+
+One timing convention: :func:`phase` takes ONE pair of clock reads and
+feeds the ``verify_phase_seconds`` histogram (always), the ring and the
+annotation (tracing on) and the caller's ``timings`` dict from it.  The
+verifiers time their phases through it and in no other way.
+
 Enable with COMETBFT_TPU_TRACE=1 (drain via export_chrome_trace / the
 API) or COMETBFT_TPU_TRACE=/path/to/out.trace.json to also auto-export
 at interpreter exit.  COMETBFT_TPU_TRACE_RING sizes the ring (events,
@@ -41,6 +54,7 @@ import time
 import weakref
 
 from . import envknobs
+from .metrics import hub as _metrics_hub
 
 _OFF_VALUES = ("", "0", "false", "off", "no")
 _ON_VALUES = ("1", "true", "on", "yes")
@@ -51,6 +65,12 @@ _CHUNK = 64
 _DEFAULT_RING = 65536
 
 _ENABLED = False
+# jax.profiler.TraceAnnotation once tracing is on and JAX can be
+# imported; None in a process without JAX.  _UNRESOLVED until the first
+# look: COMETBFT_TPU_TRACE turns tracing on at import, and JAX must not
+# be imported from here then.
+_UNRESOLVED = object()
+_ANNOTATION = _UNRESOLVED
 _CTX_ENABLED = True  # COMETBFT_TPU_TRACE_CTX — span-context propagation
 _EXPORT_PATH: str | None = None
 
@@ -83,7 +103,22 @@ def set_enabled(on: bool, ring_capacity: int | None = None) -> None:
         with _ring_mtx:
             _ring_cap = max(1, int(ring_capacity))
             del _ring[: max(0, len(_ring) - _ring_cap)]
+    if on:
+        _annotation()  # the JAX import, if any, is paid here and in no span
     _ENABLED = bool(on)
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, or None without JAX; looked up
+    once, when tracing is first on."""
+    global _ANNOTATION
+    if _ANNOTATION is _UNRESOLVED:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # noqa: BLE001 - no JAX, or one that cannot load
+            TraceAnnotation = None
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 def reset() -> None:
@@ -271,21 +306,48 @@ def _emit(ph: str, name: str, ts_ns: int, dur_ns: int, labels) -> None:
 
 
 class _Span:
-    """One 'X' (complete) trace event, recorded at __exit__."""
+    """One timed block: an 'X' (complete) trace event recorded at
+    __exit__ and the profiler annotation of the same name around it
+    (``record``), and for :func:`phase` the histogram and the caller's
+    ``timings``, all from the one pair of clock reads."""
 
-    __slots__ = ("_name", "_labels", "_t0")
+    __slots__ = ("_name", "_labels", "_record", "_hist", "_timings", "_key",
+                 "_t0", "_ann")
 
-    def __init__(self, name: str, labels: dict | None):
+    def __init__(self, name, labels, record=True, hist=None, timings=None,
+                 key=None):
         self._name = name
         self._labels = labels
+        self._record = record
+        self._hist = hist
+        self._timings = timings
+        self._key = key
 
     def __enter__(self) -> "_Span":
+        ann = _annotation() if self._record else None
+        if ann is not None:
+            ann = ann(self._name)
+            ann.__enter__()
+        self._ann = ann
         self._t0 = time.perf_counter_ns()
         return self
 
-    def __exit__(self, *exc) -> bool:
+    def __exit__(self, exc_type, *exc) -> bool:
         t0 = self._t0
-        _emit("X", self._name, t0, time.perf_counter_ns() - t0, self._labels)
+        dur = time.perf_counter_ns() - t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, *exc)
+        if self._record:
+            _emit("X", self._name, t0, dur, self._labels)
+        if exc_type is None:
+            # a phase that raised has no duration worth a histogram
+            # bucket or a place in the caller's breakdown
+            if self._hist is not None:
+                _metrics_hub().verify_phase_seconds.observe(
+                    dur / 1e9, phase=self._hist
+                )
+            if self._timings is not None:
+                self._timings[self._key] = dur / 1e6
         return False
 
 
@@ -309,6 +371,26 @@ def span(name: str, labels: dict | None = None):
     if not _ENABLED:
         return _NOP
     return _Span(name, labels)
+
+
+def phase(span_name: str | None, hist_phase: str | None = None,
+          timings: dict | None = None, key: str | None = None,
+          labels: dict | None = None):
+    """Context manager timing one verifier phase with ONE pair of clock
+    reads, and feeding from it everything that wants the duration:
+
+    - ``verify_phase_seconds{phase=hist_phase}`` on the metrics hub,
+      always (/metrics is the operator's use, tracing on or off);
+    - the span ring and the profiler annotation under ``span_name``,
+      when tracing is on (``labels`` as for :func:`span`);
+    - ``timings[key]`` in milliseconds (the breakdown a verifier keeps
+      as ``last_timings`` and the verify service ships to the client).
+
+    Each of the three is left out by passing None.  A phase that raises
+    still closes its span; histogram and ``timings`` take completed
+    phases only."""
+    return _Span(span_name, labels, _ENABLED and span_name is not None,
+                 hist_phase, timings, key)
 
 
 def instant(name: str, labels: dict | None = None) -> None:

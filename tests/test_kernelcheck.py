@@ -579,6 +579,67 @@ def test_bench_reports_kernelcheck_when_backend_unavailable():
     assert rep["findings"] == [] and "elapsed_s" in rep
 
 
+# ------------------------------------------- the phases a profile reads
+
+def test_scopes_are_collected_from_nested_bodies_and_outside_the_digest():
+    """jax.named_scope names come out of the existing walk (loop bodies
+    included) and move no fingerprint: a scope adds no primitive."""
+    import jax
+    import jax.numpy as jnp
+
+    m = _fixture_module()
+
+    def body(x):
+        def step(i, a):
+            return a + jnp.int32(1)
+
+        return jax.lax.fori_loop(0, 3, step, x) * jnp.int32(2)
+
+    def scoped(x):
+        with jax.named_scope("outer_phase"):
+            def step(i, a):
+                with jax.named_scope("inner_phase"):
+                    return a + jnp.int32(1)
+
+            x = jax.lax.fori_loop(0, 3, step, x)
+        with jax.named_scope("last_phase"):
+            return x * jnp.int32(2)
+
+    m.body, m.scoped = body, scoped
+    plain = kernelcheck.trace_kernel(_kernel("body", (manifest.i32(4),)))
+    named = kernelcheck.trace_kernel(_kernel("scoped", (manifest.i32(4),)))
+    assert plain.scopes == frozenset()
+    assert named.scopes == {"outer_phase", "inner_phase", "last_phase"}
+    assert named.fingerprint() == plain.fingerprint()
+    assert "scopes" not in named.fingerprint()
+
+
+# the names of docs/observability.md "Names inside the device program":
+# the same in both verify programs, so one metric file of benchmarks/
+# reads either
+_PHASES = {
+    "ed25519_verify_batch": {
+        "sha512", "scalar_prep", "decompress", "var_table", "scalar_mul",
+        "final_check",
+    },
+    "comb_device_verify": {
+        "payload_parse", "sha512", "scalar_prep", "decompress", "scalar_mul",
+        "comb_lookup_a", "comb_lookup_b", "tree_reduce", "final_check",
+        "pack_result",
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PHASES))
+def test_verify_programs_name_their_phases(name):
+    """One trace of the manifest kernel, contracts and names together:
+    the per-kernel metrics of the benchmark find the device time of a
+    phase by these names."""
+    t = kernelcheck.trace_kernel(manifest.by_name()[name])
+    assert t.findings == []
+    assert t.scopes == _PHASES[name]
+
+
 # ------------------------------------------------------- the slow gate
 
 @pytest.mark.slow

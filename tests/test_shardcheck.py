@@ -525,19 +525,25 @@ def test_bench_reports_shardcheck(tmp_path):
 def test_compile_cache_rule(tmp_path, monkeypatch):
     """utils/compilecache: with JAX_COMPILATION_CACHE_DIR set the helper
     sets no directory at all (jax reads the variable by itself); unset,
-    it sets the in-checkout default, and nothing else."""
+    it sets the in-checkout default.  Either way the key holds the
+    program's metadata (an executable cached before a jax.named_scope
+    existed must not be served without the name), and nothing else is
+    set."""
     import jax
 
     from cometbft_tpu.utils import compilecache
 
     calls = []
     monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    in_key = ("jax_compilation_cache_include_metadata_in_key", True)
     monkeypatch.setenv(compilecache.ENV_VAR, str(tmp_path / "placed"))
     compilecache.enable()
-    assert calls == []
+    assert calls == [in_key]
     monkeypatch.delenv(compilecache.ENV_VAR)
+    del calls[:]
     compilecache.enable()
-    assert [c[1] for c in calls] == [compilecache.DEFAULT_DIR]
+    assert calls == [
+        ("jax_compilation_cache_dir", compilecache.DEFAULT_DIR), in_key]
     assert compilecache.DEFAULT_DIR == os.path.join(REPO, "tests", ".jax_cache")
 
 

@@ -149,6 +149,260 @@ def test_cross_thread_spans_drain_on_export():
     assert by_name["worker.span"]["tid"] != by_name["main.span"]["tid"]
 
 
+# ------------------------------------ the profiler's clock, one convention
+
+
+class _FakeAnnotation:
+    """Stands where jax.profiler.TraceAnnotation stands: records who
+    entered and left what, on which thread."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name, threading.get_ident()))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(tracing, "_ANNOTATION", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_recording_span_mirrors_one_annotation_on_its_own_thread(annotations):
+    tracing.set_enabled(True)
+    tracing.reset()
+
+    def work():
+        with tracing.span("worker.phase", {"k": 1}):
+            pass
+
+    t = threading.Thread(target=work)
+    t.start()
+    t.join()
+    with tracing.span("caller.phase"):
+        tracing.instant("not.mirrored")
+    me = threading.get_ident()
+    assert annotations == [
+        ("enter", "worker.phase", t.ident), ("exit", "worker.phase", t.ident),
+        ("enter", "caller.phase", me), ("exit", "caller.phase", me),
+    ]
+    names = [e["name"] for e in tracing.chrome_trace_events() if e["ph"] == "X"]
+    assert sorted(names) == ["caller.phase", "worker.phase"]
+
+
+def test_disabled_span_touches_no_annotation(annotations):
+    tracing.set_enabled(False)
+    s = tracing.span("hot.path")
+    assert s is tracing.span("other")  # the shared no-op, as before
+    with s:
+        pass
+    assert annotations == []
+
+
+def test_real_annotation_is_resolved_when_tracing_is_turned_on(monkeypatch):
+    """JAX is looked up by set_enabled(True), not at import and not in a
+    span; with JAX here that is jax.profiler.TraceAnnotation, and a
+    span under it records as before."""
+    monkeypatch.setattr(tracing, "_ANNOTATION", tracing._UNRESOLVED)
+    tracing.set_enabled(True)
+    from jax.profiler import TraceAnnotation
+
+    assert tracing._ANNOTATION is TraceAnnotation
+    tracing.reset()
+    with tracing.span("real.annotation"):
+        pass
+    assert [e["name"] for e in tracing.chrome_trace_events()
+            if e["ph"] == "X"] == ["real.annotation"]
+
+
+def test_jax_is_imported_when_tracing_is_on_and_never_at_import():
+    """A process that never traces never pays for JAX here; under
+    COMETBFT_TPU_TRACE the first recording span looks it up."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "import cometbft_tpu.utils.tracing as t\n"
+        "assert 'jax' not in sys.modules\n"
+        "with t.span('x'): pass\n"
+        "print(t.enabled(), 'jax' in sys.modules)\n"
+    )
+    for trace, want in (("", "False False"), ("1", "True True")):
+        r = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            cwd=REPO, timeout=120,
+            env=dict(os.environ, COMETBFT_TPU_TRACE=trace, JAX_PLATFORMS="cpu"),
+        )
+        assert r.returncode == 0, r.stderr
+        assert r.stdout.strip() == want
+
+
+class _CountingClock:
+    """perf_counter_ns that counts its reads: 1 ms, then 4 ms, ..."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def perf_counter_ns(self):
+        self.reads += 1
+        return 1_000_000 + 3_000_000 * (self.reads - 1)
+
+    def time_ns(self):
+        return 0
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_phase_reads_the_clock_twice_and_feeds_everyone_the_same(
+    monkeypatch, annotations, on
+):
+    from cometbft_tpu.utils import metrics
+
+    monkeypatch.setattr(metrics, "_HUB", metrics.Hub())
+    tracing.set_enabled(on)
+    tracing.reset()
+    clock = _CountingClock()
+    timings = {}
+    with monkeypatch.context() as m:  # the export below reads the real clock
+        m.setattr(tracing, "time", clock)
+        with tracing.phase(
+            "verify.slab_fill", "assembly", timings, "assembly_ms"
+        ):
+            pass
+    assert clock.reads == 2
+    assert timings == {"assembly_ms": 3.0}
+    hist = metrics.hub().verify_phase_seconds
+    k = hist._label_key({"phase": "assembly"})
+    assert hist._totals[k] == 1 and hist._sums[k] == pytest.approx(0.003)
+    spans = [e for e in tracing.chrome_trace_events() if e["ph"] == "X"]
+    if on:
+        assert [(e["name"], e["dur"]) for e in spans] == [
+            ("verify.slab_fill", 3000.0)]
+        assert [a[:2] for a in annotations] == [
+            ("enter", "verify.slab_fill"), ("exit", "verify.slab_fill")]
+    else:
+        assert spans == [] and annotations == []
+
+
+def test_phase_that_raises_closes_its_span_and_feeds_no_duration(monkeypatch):
+    from cometbft_tpu.utils import metrics
+
+    monkeypatch.setattr(metrics, "_HUB", metrics.Hub())
+    tracing.set_enabled(True)
+    tracing.reset()
+    timings = {}
+    with pytest.raises(RuntimeError):
+        with tracing.phase("verify.device_wait", "device_wait", timings,
+                           "device_wait_ms"):
+            raise RuntimeError("lost device")
+    assert timings == {}
+    assert metrics.hub().verify_phase_seconds._totals == {}
+    assert [e["name"] for e in tracing.chrome_trace_events()
+            if e["ph"] == "X"] == ["verify.device_wait"]
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_verify_commit_leaves_the_commit_spans_nested_as_written(on):
+    """commit.assemble, commit.verify, commit.judge: in that order, one
+    after the other on the caller's thread, and the verifier's spans of
+    that thread inside commit.verify; none with tracing off."""
+    from test_types import _keys, _signed_commit, _valset
+
+    import cometbft_tpu.types as T
+
+    keys = _keys(4)
+    vals = _valset(keys)
+    bid, commit = _signed_commit(keys, vals)
+    tracing.set_enabled(on)
+    tracing.reset()
+    T.verify_commit("test-chain", vals, bid, 5, commit)
+    spans = [e for e in tracing.chrome_trace_events() if e["ph"] == "X"]
+    mine = [e for e in spans if e["name"].startswith("commit.")]
+    if not on:
+        assert spans == []
+        return
+    assert [e["name"] for e in mine] == [
+        "commit.assemble", "commit.verify", "commit.judge"]
+    assert len({e["tid"] for e in mine}) == 1
+    for a, b in zip(mine, mine[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-6
+    verify = mine[1]
+    for e in spans:
+        if e["tid"] == verify["tid"] and e["name"].startswith("verify."):
+            assert verify["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= verify["ts"] + verify["dur"] + 1e-6
+
+
+def test_pending_light_verification_leaves_the_commit_spans(monkeypatch):
+    """The pipelined path: commit.assemble and commit.verify (the
+    submit) at submit time, commit.verify (the collect) and commit.judge
+    at collect time."""
+    from test_types import _keys, _signed_commit, _valset
+
+    from cometbft_tpu.types import validation
+
+    class Seam:
+        def __init__(self):
+            self.items = []
+
+        def add(self, pk, msg, sig):
+            self.items.append((pk, msg, sig))
+
+        def submit(self):
+            return "ticket"
+
+        def collect(self, ticket):
+            return True, [True] * len(self.items)
+
+    monkeypatch.setattr(
+        validation.crypto_batch, "create_batch_verifier",
+        lambda *a, **kw: Seam(),
+    )
+    keys = _keys(4)
+    vals = _valset(keys)
+    bid, commit = _signed_commit(keys, vals)
+    tracing.set_enabled(True)
+    tracing.reset()
+    pending = validation.submit_verify_commit_light(
+        "test-chain", vals, bid, 5, commit)
+    pending.collect()
+    assert [e["name"] for e in tracing.chrome_trace_events()
+            if e["ph"] == "X"] == [
+        "commit.assemble", "commit.verify", "commit.verify", "commit.judge"]
+
+
+@pytest.mark.parametrize("lane", ["uncached", "comb"])
+def test_host_route_is_counted_by_lane(monkeypatch, lane):
+    """A batch under DEVICE_BATCH_MIN leaves the span it always left and
+    now a counter, so /metrics shows the route without the ring."""
+    from cometbft_tpu.crypto import ed25519 as host
+    from cometbft_tpu.models import verifier
+    from cometbft_tpu.utils import metrics
+
+    monkeypatch.setattr(metrics, "_HUB", metrics.Hub())
+    tracing.set_enabled(True)
+    tracing.reset()
+    priv = host.PrivKey.from_seed(b"\x07" * 32)
+    msg = b"m"
+    items = [(priv.pub_key().bytes(), msg, priv.sign(msg))]
+    assert verifier.host_route(items, lane) == (True, [True])
+    c = metrics.hub().verify_host_route
+    assert c.value(lane=lane, reason="below_batch_min") == 1.0
+    assert "cometbft_verify_host_route_total" in (
+        metrics.hub().registry.expose_text())
+    assert [e["name"] for e in tracing.chrome_trace_events()
+            if e["ph"] == "X"] == ["verify.host_route"]
+
+
 # --------------------------------------------------------- flight recorder
 
 
