@@ -16,11 +16,15 @@ widths no run may cut:
   are resident, more commits verify through the comb program, and a
   fresh BlocksyncReactor applies the chain.
 * 175 ed25519 validators (CometBFT QA v1: 200 nodes / 175 validators,
-  the shape production chains have): below COMETBFT_TPU_COMB_MIN, so
-  the uncached program — at bucket 256 for verify_commit and for the
-  light check that counts every signature (evidence), and at bucket 128
-  for the default verify_commit_light a light client or blocksync
-  makes, which stops at +2/3 = 117 signatures.
+  the shape production chains have): at COMETBFT_TPU_COMB_MIN's default
+  (32) or above, like every set the device serves at all, so the set is
+  bound at first sight (a host table build of seconds in the caller's
+  thread, 152 KB of device memory a lane) and the comb program serves
+  it at 256 lanes: verify_commit, the light check that counts every
+  signature (evidence), and the default verify_commit_light a light
+  client or blocksync makes, whose +2/3 = 117 signatures are live rows
+  of the same program.  The run fails if a bound set compiles more
+  than one program per lane count, or is served by the uncached one.
 
 Every verdict is compared with the host oracle
 (crypto/ed25519.verify_signature) position by position; a commit with a
@@ -466,6 +470,9 @@ def route_report() -> dict:
             r: m.comb_table_cache.value(result=r)
             for r in ("hit", "miss", "building")
         },
+        "comb_program_cache": {
+            r: m.comb_program_cache.value(result=r) for r in ("hit", "compile")
+        },
         "fallback_spans": {n: names.get(n, 0) for n in FALLBACK_SPANS},
         "dispatch_spans": names.get("verify.sched.dispatch", 0),
         "device_wait_spans": names.get("verify.device_wait", 0),
@@ -530,6 +537,7 @@ def run(width_large: int, width_small: int, facts: dict) -> dict:
     from cometbft_tpu.models.verifier import _next_bucket
     from cometbft_tpu.ops import comb
     from cometbft_tpu.utils import compilecache, tracing
+    from cometbft_tpu.utils.metrics import hub
     from cometbft_tpu.verifysvc.service import reset_global_service
 
     steps = facts.setdefault("setup_seconds", {})
@@ -547,6 +555,8 @@ def run(width_large: int, width_small: int, facts: dict) -> dict:
 
     def compiles_of(program: str) -> int:
         return len(events.compile_s.get(program, ()))
+
+    comb_compiles_0 = hub().comb_program_cache.value(result="compile")
 
     # ---- 10,000 validators: chain, validator-set hash, then the
     # uncached program while the comb tables build in the background
@@ -593,22 +603,27 @@ def run(width_large: int, width_small: int, facts: dict) -> dict:
     blocksync_apply(big, timeout_s=300)
     timed("blocksync_apply", t0)
 
-    # ---- 175 validators: the uncached program at the two buckets a
-    # node runs it at, each one's first verify apart
+    # ---- 175 validators: bound at first sight in the caller's thread,
+    # then the comb program at the set's own lane count; the first
+    # verify apart (table build and compile), the first light check
+    # apart too (it must compile nothing: fewer live rows, same program)
     t0 = time.monotonic()
     small = build_chain(make_keys(width_small, b"S"), N_BLOCKS, f"smoke-{width_small}")
     timed("chain_small", t0)
     check_valset_hash(small)
     bad_small, flipped_small = tampered(small.commit_for(1), width_small)
+    n0 = compiles_of("_device_verify")
     t0 = time.monotonic()
     check_vector(small, small.commit_for(1), [])
-    timed(f"first_verify_uncached_b{_next_bucket(width_small)}", t0)
+    timed("first_verify_comb_small", t0)
+    steps["first_verify_comb_small_compiles_in_step"] = (
+        compiles_of("_device_verify") - n0
+    )
     check_vector(small, bad_small, flipped_small)
     check_refused(small, 1, bad_small, flipped_small[0])
-    light_sigs = width_small * 2 // 3 + 1  # where the default light check stops
     t0 = time.monotonic()
     verify_height(small, 1, light=True)
-    timed(f"first_light_verify_uncached_b{_next_bucket(light_sigs)}", t0)
+    timed("first_light_verify_comb_small", t0)
     verify_heights(small)
     for h in range(1, len(small.blocks)):
         verify_height(small, h, light=True, count_all_signatures=True)
@@ -632,17 +647,29 @@ def run(width_large: int, width_small: int, facts: dict) -> dict:
     facts["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
     problems = route_failures(rep)
     progs = rep["batches_by_program"]
-    for name, least in (
-        ("uncached_b%d" % _next_bucket(width_small), 1),
-        ("uncached_b%d" % _next_bucket(light_sigs), 1),
-        ("uncached_b%d" % _next_bucket(width_large), 1),
-        ("comb", COMB_COMMITS_MIN),
-    ):
+    warming = "uncached_b%d" % _next_bucket(width_large)
+    for name, least in ((warming, 1), ("comb", 2 * COMB_COMMITS_MIN)):
         if progs.get(name, 0) < least:
             problems.append(f"program {name} served {progs.get(name, 0)} "
                             f"batch(es), need {least}")
+    for name in sorted(set(progs) - {warming, "comb"}):
+        problems.append(f"program {name} served {progs[name]} batch(es) "
+                        "of a set that was bound")
     if rep["comb_table_cache"]["hit"] < 1:
         problems.append("comb table cache never hit")
+    lanes = {
+        global_cache().get(
+            ValsetCombCache.fingerprint(c.vals.pub_keys_bytes())
+        ).vpad
+        for c in (big, small)
+    }
+    facts["comb_lanes"] = sorted(lanes)
+    compiled_comb = rep["comb_program_cache"]["compile"] - comb_compiles_0
+    if compiled_comb > len(lanes):
+        problems.append(
+            f"{compiled_comb:g} comb programs compiled for {len(lanes)} "
+            "lane count(s)"
+        )
     require(not problems, "; ".join(problems))
     reset_global_service()
     tracing.set_enabled(False)

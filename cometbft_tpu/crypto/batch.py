@@ -42,10 +42,17 @@ def supports_batch_verifier(key_type: str) -> bool:
 
 
 def comb_min() -> int:
-    """Minimum validator-set size for the device-resident comb-table path.
-    Below it the one-time table build + per-set compiled program don't pay
-    for themselves (and the CPU-backend test suite stays off the
-    minutes-long comb compile)."""
+    """Minimum size of a validator set the caller names for the
+    device-resident comb-table path (COMETBFT_TPU_COMB_MIN).  Its default
+    is COMETBFT_TPU_DEVICE_BATCH_MIN's, 32: below that both device
+    verifiers answer from the host anyway, and from there up every set
+    binds, the 100-200 validators real chains run included.  A set
+    costs 152 KB of device memory a lane (lanes in buckets of 128: 39 MB
+    at 175 validators) and a host table build at first sight (about
+    10 ms a key); the compiled program is shared by every set of one
+    lane bucket (models/comb_verifier).  What a test suite compiles is
+    no reason for a production threshold: a test that wants the uncached
+    program names no set."""
     return envknobs.get_int(envknobs.COMB_MIN)
 
 
@@ -85,11 +92,11 @@ def create_batch_verifier(
     this process's COMETBFT_TPU_VERIFYSVC_TENANT — single-chain callers
     never pass one) — the service owns all batching, scheduling, and
     device dispatch.  When the caller knows the validator set (pubkeys,
-    in set order), large sets bind to the comb-cached program here, in
-    the caller's thread: tables stay device-resident across calls,
-    keyed by the set (the reference's expanded-key LRU, ed25519.go:43,68,
-    writ large), and a first-sight table build never runs on the shared
-    scheduler thread."""
+    in set order), a set of comb_min() keys or more binds to the
+    comb-cached program here, in the caller's thread: tables stay
+    device-resident across calls, keyed by the set (the reference's
+    expanded-key LRU, ed25519.go:43,68, writ large), and a first-sight
+    table build never runs on the shared scheduler thread."""
     if not supports_batch_verifier(key_type):
         raise ValueError(f"no batch verifier for key type {key_type!r}")
     from ..verifysvc.service import remote_plane_configured

@@ -18,12 +18,16 @@ production consumers reach it through the unified verify service
 (verifysvc/ — a request bound to a cache entry via
 ``mode=("comb", entry)`` dispatches as one solo batch on the scheduler).
 
-Shapes are keyed by the validator-set size V, not a power-of-two bucket:
-commits verify against a fixed known set, so one compiled program per
-chain (10,000 lanes for the 10k-validator config, not 16,384).  Rows for
-validators that did not sign carry zeros and are masked out of the
-result, preserving the per-signature blame contract of
-types/validation.go:384-399.
+Shapes are keyed by the validator-set size V padded to the chip's 128
+lanes, not to a power-of-two bucket: 175 validators run 256 lanes and
+10,000 run 10,112 (not 16,384), which is what XLA tiles the minor axis
+to anyway, and a set that gains a member inside its bucket compiles
+nothing.  The compiled program depends on (lanes, payload width) alone
+— tables, validity mask and keys are its arguments — so it is cached
+once per process, not per set (models/verifier._COMB_PROGRAMS).  Rows
+for validators that did not sign, and pad lanes, carry zeros and are
+masked out of the result, preserving the per-signature blame contract
+of types/validation.go:384-399.
 """
 
 from __future__ import annotations
@@ -38,10 +42,17 @@ from ..utils import tracing
 from ..utils.metrics import hub as _mhub
 
 
+# lanes of a single-chip entry come in multiples of the chip's lane
+# width; 152 KB of tables a lane (64 positions x 9 entries x 3 Niels
+# coordinates x 22 int32 limbs)
+LANE_BUCKET = 128
+TABLE_BYTES_PER_LANE = 64 * 9 * 3 * 22 * 4
+
+
 class _CacheEntry:
     __slots__ = (
         "tables", "valid", "pubs", "index", "size", "vpad", "mesh",
-        "verify_fn", "programs", "_program_mtx", "_slabs", "_slab_mtx",
+        "verify_fn", "_slabs", "_slab_mtx",
     )
 
     def __init__(self, tables, valid, pubs, index: dict[bytes, int], mesh=None):
@@ -52,17 +63,16 @@ class _CacheEntry:
         # challenge digest R || A || M)
         self.index = index  # pubkey bytes -> row
         self.size = len(index)
-        self.vpad = int(tables.shape[-1])  # size padded to the mesh width
+        # size padded to the lane bucket (or to the mesh's width)
+        self.vpad = int(tables.shape[-1])
         self.mesh = mesh  # jax Mesh when the sharded path is active
         # One callable serving every payload width, when there is one:
         # the sharded program of a mesh entry (bound at first use; it
         # keeps its own per-shape cache and compiles inside its first
         # call, parallel/verify) or a test's stand-in.  A single-device
-        # entry leaves it None and compiles one program per payload
-        # width into ``programs`` (CombBatchVerifier._program).
+        # entry leaves it None and runs the process-wide program of its
+        # shape (CombBatchVerifier._program).
         self.verify_fn = None
-        self.programs: dict[int, object] = {}
-        self._program_mtx = threading.Lock()
         # reusable host staging buffers, keyed by payload width; two per
         # width = the double-buffer the pipelined submit() path needs
         self._slabs: dict[int, list[_PayloadSlab]] = {}
@@ -155,16 +165,22 @@ def set_active_mesh(mesh) -> None:
 
 
 class ValsetCombCache:
-    """LRU of device-resident comb tables, keyed by the pubkey list.
+    """LRU of device-resident comb tables, keyed by the pubkey list and
+    bounded by the bytes of tables it holds.
 
-    A 10k-validator entry is ~1.5 GB of HBM (152 KB/validator), so the
-    LRU is small; consensus only ever needs the current set and, briefly,
-    the previous one across a validator-set change.
+    An entry is 152 KB of HBM a lane: 39 MB for a 175-validator chain
+    (256 lanes), 1.5 GB at 10,000.  The bound is what two 10,000-validator
+    entries take: consensus on a set that large needs the current set
+    and, briefly, the previous one across a validator-set change, while
+    a production-sized chain keeps some eighty sets resident, so a light
+    -client or evidence check against an older set never evicts the
+    tables consensus is using.  The newest entry is never evicted,
+    whatever its size.
     """
 
-    def __init__(self, max_entries: int = 2):
+    def __init__(self, max_bytes: int = 2 * 10_112 * TABLE_BYTES_PER_LANE):
         self._entries: OrderedDict[bytes, _CacheEntry] = OrderedDict()
-        self._max = max_entries
+        self._max_bytes = max_bytes
         self._mtx = threading.Lock()
         self._building: dict[bytes, threading.Lock] = {}
         self._async_inflight: set[bytes] = set()
@@ -214,8 +230,10 @@ class ValsetCombCache:
             entry = self._build(pubkeys, base)
             with self._mtx:
                 self._entries[fp] = entry
-                while len(self._entries) > self._max:
-                    self._entries.popitem(last=False)
+                held = sum(e.tables.nbytes for e in self._entries.values())
+                while held > self._max_bytes and len(self._entries) > 1:
+                    _, oldest = self._entries.popitem(last=False)
+                    held -= oldest.tables.nbytes
                 self._building.pop(fp, None)
             return entry
 
@@ -270,12 +288,13 @@ class ValsetCombCache:
 
         mesh = active_mesh()
         index = {pk: i for i, pk in enumerate(pubkeys)}
-        if mesh is not None:
-            # pad the lane count to the mesh width; pad lanes carry a
-            # repeated real key but are never scattered into (valid rows
-            # only come from `index`), so they do dead-but-defined work
-            d = mesh.devices.size
-            pad = (-len(pubkeys)) % d
+        # pad the lane count to the chip's lane bucket (to the mesh's
+        # width when sharded); pad lanes carry a repeated real key but
+        # are never scattered into (valid rows only come from `index`),
+        # so they do dead-but-defined work
+        d = LANE_BUCKET if mesh is None else mesh.devices.size
+        pad = (-len(pubkeys)) % d
+        if pad:
             pubkeys = list(pubkeys) + [pubkeys[0]] * pad
         reuse: list[tuple[int, int]] = []  # (new row, base row)
         fresh: list[int] = []
@@ -348,7 +367,11 @@ def _build_tables(pub_arr: np.ndarray):
             "verify.table_build", "table_build_host",
             labels={"backend": "host"},
         ):
-            return comb.build_a_tables_host(pub_arr)
+            # a key is built once: pad lanes repeat one
+            uniq, lanes = np.unique(pub_arr, axis=0, return_inverse=True)
+            lanes = lanes.reshape(-1)
+            tables, valid = comb.build_a_tables_host(uniq)
+            return tables[..., lanes], valid[lanes]
     import jax.numpy as jnp
 
     with tracing.phase(
@@ -605,7 +628,7 @@ class CombBatchVerifier:
         # same rule as the uncached kernel: a small batch (few signers
         # of a large cached set) finishes sooner on the host even though
         # the tables are warm
-        from .verifier import _device_batch_min, host_route
+        from .verifier import _COMB_PROGRAMS, _device_batch_min, host_route
 
         if n < _device_batch_min():
             return ("sync", host_route(self._items, "comb"))
@@ -630,7 +653,7 @@ class CombBatchVerifier:
             on_compile is not None
             and entry.verify_fn is None
             and entry.mesh is None
-            and width not in entry.programs
+            and _program_key(entry, width) not in _COMB_PROGRAMS
         )
         if waiting:
             on_compile(True)
@@ -758,8 +781,9 @@ class CombBatchVerifier:
         return result
 
     def _program(self, width: int):
-        """The verify program for payload rows ``width`` bytes wide,
-        compiled at first use.  Called on the staging thread only."""
+        """The verify program for this entry's lane count and payload
+        rows ``width`` bytes wide, compiled at the first use by any
+        entry of that shape.  Called on the staging thread only."""
         e = self._entry
         if e.verify_fn is None and e.mesh is not None:
             # multi-chip: tables + rows sharded over the mesh's lane
@@ -781,13 +805,32 @@ class CombBatchVerifier:
             # created lazily inside the jit it would be a leaked tracer
             comb.get_b_tables()
             return jax.jit(_device_verify).lower(
-                e.tables, e.valid, e.pubs,
+                *(
+                    jax.ShapeDtypeStruct(x.shape, x.dtype)
+                    for x in (e.tables, e.valid, e.pubs)
+                ),
                 jax.ShapeDtypeStruct((e.vpad, width), np.uint8),
             )
 
-        from .verifier import program_for
+        from .verifier import _COMB_PROGRAMS, _COMB_PROGRAMS_MTX, program_for
 
-        return program_for(e.programs, e._program_mtx, width, lower)
+        compiled = []  # program_for announces a compile, and only that
+        prog = program_for(
+            _COMB_PROGRAMS, _COMB_PROGRAMS_MTX, _program_key(e, width),
+            lower, compiled.append,
+        )
+        _mhub().comb_program_cache.inc(
+            result="compile" if compiled else "hit"
+        )
+        return prog
+
+
+def _program_key(entry: _CacheEntry, width: int) -> tuple:
+    """What the single-device program depends on: lanes, payload width
+    and the accumulation path its trace resolves (ops/comb.tree_enabled)."""
+    from ..ops import comb
+
+    return (entry.vpad, width, comb.tree_enabled())
 
 
 def _device_verify(tables, valid, pubs, payload):

@@ -32,6 +32,12 @@ from ..utils.metrics import hub as _metrics_hub
 # compiled uncached programs, one per input-shape set (bucket x SHA blocks)
 _VERIFY_PROGRAMS: dict[tuple, object] = {}
 _VERIFY_PROGRAMS_MTX = threading.Lock()
+# compiled single-device comb programs, one per (lanes, payload width,
+# tree flag): tables, validity mask and keys are arguments, so every
+# cache entry of that shape runs the same executable
+# (models/comb_verifier.CombBatchVerifier._program)
+_COMB_PROGRAMS: dict[tuple, object] = {}
+_COMB_PROGRAMS_MTX = threading.Lock()
 
 
 def program_for(cache: dict, mtx, key, lower, on_compile=None):

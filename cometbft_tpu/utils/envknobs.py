@@ -55,9 +55,13 @@ CRYPTO_BACKEND = _declare(
     "(auto = accelerator kernel whenever JAX is importable).",
 )
 COMB_MIN = _declare(
-    "COMETBFT_TPU_COMB_MIN", "int", 512,
-    "Minimum validator-set size for the device-resident comb-table path; "
-    "below it the table build + per-set compiled program don't pay off.",
+    "COMETBFT_TPU_COMB_MIN", "int", 32,
+    "Minimum size of a validator set the caller names for the "
+    "device-resident comb-table path.  The default is "
+    "`COMETBFT_TPU_DEVICE_BATCH_MIN`'s: every set the device serves at "
+    "all is served from resident tables (152 KB a lane, lanes in "
+    "buckets of 128), and a narrower batch is verified on the host "
+    "anyway.",
 )
 COMB_ASYNC_MIN = _declare(
     "COMETBFT_TPU_COMB_ASYNC_MIN", "int", 2048,

@@ -409,6 +409,13 @@ class Hub:
             "building; building = async build in flight, batch routed "
             "to the uncached kernel)",
         )
+        self.comb_program_cache = r.counter(
+            "verify_comb_program_cache_total",
+            "Look-ups of the single-device comb verify program (label "
+            "result=hit|compile; one program per lane bucket and "
+            "payload width serves every validator set of that shape, so "
+            "a set change that compiles shows here)",
+        )
         self.secp_pubkey_cache = r.counter(
             "verify_svc_secp_pubkey_cache_total",
             "Decoded-secp256k1-pubkey cache lookups in the MODE_SECP "

@@ -45,9 +45,11 @@ def resolve_mode(pubkeys: list[bytes] | None, key_type: str = "ed25519"):
     tables; the BLS plane owns its own pubkey-validation cache), secp
     sets (both the Cosmos and Ethereum wire formats) the batched ECDSA
     lane (MODE_SECP — the Shamir G table is a process-resident
-    device_put constant, nothing to bind per set), large known ed25519
-    sets use the comb-cached program (background build while warming ->
-    uncached), everything else the uncached kernel."""
+    device_put constant, nothing to bind per set), known ed25519 sets
+    of crypto/batch.comb_min() keys or more use the comb-cached program
+    (tables built here at first sight; from comb_async_min() up in the
+    background, uncached while warming), everything else (no set named,
+    or a set narrower than the device serves) the uncached kernel."""
     if key_type == "bls12_381":
         return MODE_BLS
     if key_type in ("secp256k1", "secp256k1eth", "ecrecover"):

@@ -145,10 +145,13 @@ def test_legs_hold_at_a_tiny_width(smoke, monkeypatch):
     """Both legs end to end on the CPU backend at widths 10 and 5, with
     the thresholds lowered so that the same routes are taken as at
     10,000 and 175: background table build, uncached program meanwhile,
-    then the comb program and blocksync."""
-    monkeypatch.setenv("COMETBFT_TPU_COMB_MIN", "8")
+    then the comb program and blocksync; the small set bound at first
+    sight and served by the comb program (both widths share the one
+    128-lane program here)."""
+    monkeypatch.setenv("COMETBFT_TPU_COMB_MIN", "4")
     monkeypatch.setenv("COMETBFT_TPU_COMB_ASYNC_MIN", "8")
     monkeypatch.setenv("COMETBFT_TPU_COMB_HOST_BUILD_MAX", "0")
     monkeypatch.setenv("COMETBFT_TPU_DEVICE_BATCH_MIN", "1")
     facts = smoke.run(10, 5, {})
-    assert facts["routes"]["batches_by_program"]["comb"] >= 3
+    assert facts["routes"]["batches_by_program"]["comb"] >= 6
+    assert facts["comb_lanes"] == [128]

@@ -87,29 +87,40 @@ def test_host_build_bit_identical_to_jitted_build():
 
 def test_build_routing_honors_host_build_max(monkeypatch):
     """models/comb_verifier._build_tables: host precompute at/below the
-    knob, the jitted kernel above it, device-only at 0."""
+    knob, the jitted kernel above it, device-only at 0.  The host
+    builder sees each distinct key once (pad lanes repeat one) and the
+    lanes come back in the caller's order."""
     from cometbft_tpu.models import comb_verifier as cv
 
     import types
+
+    def keys(*firsts):
+        return np.repeat(np.asarray(firsts, np.uint8)[:, None], 32, axis=1)
 
     calls = []
     dev_t = types.SimpleNamespace(block_until_ready=lambda: None)
     monkeypatch.setattr(
         comb, "build_a_tables_host",
-        lambda a: (calls.append(("host", int(a.shape[0]))), ("T", "V"))[1],
+        lambda a: (
+            calls.append(("host", int(a.shape[0]))),
+            (a[None, :, 0].astype(np.int32), a[:, 0] != 0),
+        )[1],
     )
     monkeypatch.setattr(
         comb, "build_a_tables_jit",
         lambda a: (calls.append(("device", int(a.shape[0]))), (dev_t, "V"))[1],
     )
     monkeypatch.setenv("COMETBFT_TPU_COMB_HOST_BUILD_MAX", "8")
-    cv._build_tables(np.zeros((4, 32), np.uint8))
-    cv._build_tables(np.zeros((8, 32), np.uint8))  # boundary: host
-    cv._build_tables(np.zeros((16, 32), np.uint8))
+    cv._build_tables(keys(*range(4)))
+    cv._build_tables(keys(*range(8)))  # boundary: host
+    cv._build_tables(keys(*range(16)))
+    tables, valid = cv._build_tables(keys(7, 0, 9, 7, 7))  # 7 built once
+    assert tables[0].tolist() == [7, 0, 9, 7, 7]
+    assert valid.tolist() == [True, False, True, True, True]
     monkeypatch.setenv("COMETBFT_TPU_COMB_HOST_BUILD_MAX", "0")
-    cv._build_tables(np.zeros((4, 32), np.uint8))
+    cv._build_tables(keys(*range(4)))
     assert calls == [
-        ("host", 4), ("host", 8), ("device", 16), ("device", 4),
+        ("host", 4), ("host", 8), ("device", 16), ("host", 3), ("device", 4),
     ]
 
 
@@ -128,7 +139,7 @@ def test_entry_built_from_host_tables_verifies_via_host_route(monkeypatch):
         cv, "_build_tables", lambda a: (built.append(a.shape[0]), real(a))[1]
     )
     entry = cv.ValsetCombCache().ensure(pubs)
-    assert built == [n]
+    assert built == [entry.vpad] and (entry.size, entry.vpad) == (n, 128)
     bv = cv.CombBatchVerifier(entry)
     for i, sk in enumerate(keys):
         msg = b"hostbuild-%d" % i

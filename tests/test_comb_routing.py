@@ -118,17 +118,18 @@ def test_foreign_key_demotes_to_uncached(monkeypatch):
     assert ok and per == [True] * 4
 
 
-def test_cache_keying_and_eviction():
-    c = cv.ValsetCombCache(max_entries=2)
+def test_cache_keying():
+    """An entry is keyed by the pubkey list in set order: a set whose
+    order changes is another entry (and, from PR 26, the same compiled
+    program: tests/test_comb_resident.py has that and the eviction
+    rule)."""
+    c = cv.ValsetCombCache()
     sets = [[bytes([i]) * 32 for i in range(k, k + 3)] for k in (0, 10, 20)]
+    sets.append(sets[0][::-1])
     fps = [c.fingerprint(s) for s in sets]
-    assert len({bytes(f) for f in fps}) == 3
-    for s, f in zip(sets, fps):
-        c._entries[f] = object()  # stand-in; ensure() would build tables
-        while len(c._entries) > c._max:
-            c._entries.popitem(last=False)
-    assert c.get(fps[0]) is None  # evicted (LRU)
-    assert c.get(fps[1]) is not None and c.get(fps[2]) is not None
+    assert len({bytes(f) for f in fps}) == 4
+    assert c.fingerprint(list(sets[0])) == fps[0]
+    assert all(c.get(f) is None for f in fps)
 
 
 def test_incremental_churn_reuses_rows(monkeypatch):
@@ -157,22 +158,30 @@ def test_incremental_churn_reuses_rows(monkeypatch):
 
     c = cv.ValsetCombCache()
     pk = lambda x: bytes([x]) * 32
+
+    def lanes(e):  # the set's rows, then pad lanes repeating row 0
+        got = np.asarray(e.tables)[0, 0, :].tolist()
+        assert e.vpad == len(got) == cv.LANE_BUCKET
+        assert got[e.size:] == got[:1] * (e.vpad - e.size)
+        assert np.asarray(e.valid).all()
+        return got[: e.size]
+
     e1 = c.ensure([pk(1), pk(2), pk(3)])
-    assert built_batches == [3]
-    assert np.asarray(e1.tables)[0, 0, :].tolist() == [1, 2, 3]
+    assert built_batches == [cv.LANE_BUCKET]
+    assert lanes(e1) == [1, 2, 3]
 
     # churn: drop 3, add 9, reorder — only the fresh key is built (padded
     # to a power-of-two bucket of 1), other rows gathered from e1
     e2 = c.ensure([pk(2), pk(9), pk(1)])
-    assert built_batches == [3, 1]
-    assert np.asarray(e2.tables)[0, 0, :].tolist() == [2, 9, 1]
-    assert np.asarray(e2.valid).tolist() == [True, True, True]
+    assert built_batches == [cv.LANE_BUCKET, 1]
+    assert lanes(e2) == [2, 9, 1]
     assert e2.index == {pk(2): 0, pk(9): 1, pk(1): 2}
 
-    # three fresh keys pad to a 4-bucket; reused row still gathered
+    # three fresh keys pad to a 4-bucket; reused row (and the pad lanes
+    # that repeat it) still gathered
     e3 = c.ensure([pk(1), pk(5), pk(6), pk(7)])
-    assert built_batches == [3, 1, 4]
-    assert np.asarray(e3.tables)[0, 0, :].tolist() == [1, 5, 6, 7]
+    assert built_batches == [cv.LANE_BUCKET, 1, 4]
+    assert lanes(e3) == [1, 5, 6, 7]
 
 
 def test_validator_set_pubkeys_cache_invalidation():

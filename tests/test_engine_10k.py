@@ -150,15 +150,16 @@ def test_validators_pagination_at_10k(keys_10k):
 def test_comb_bitmap_width_non_pow2():
     """Packed-bitmap readback at a validator count that is NOT a multiple
     of 8: unpackbits(count=vpad) must not truncate or misalign rows
-    (verdict weak #4's vpad/bitmap-width shape class).  V=10 keeps the
-    compile small while exercising the padding byte."""
+    (verdict weak #4's vpad/bitmap-width shape class).  From PR 26 a
+    single-chip entry's lanes are a multiple of 128, so the bitmap has
+    no padding byte; the last real row still sits mid-byte."""
     from cometbft_tpu.models import comb_verifier as cv
 
     n = 10
     keys = [host.PrivKey.from_seed(bytes([i + 1]) * 32) for i in range(n)]
     pubs = [k.pub_key().data for k in keys]
     entry = cv.ValsetCombCache().ensure(pubs)
-    assert entry.vpad == n
+    assert (entry.size, entry.vpad) == (n, 128)
     bv = cv.CombBatchVerifier(entry)
     for i, k in enumerate(keys):
         msg = b"w-%d" % i

@@ -612,7 +612,7 @@ def test_envknobs_typed_getters(monkeypatch):
     monkeypatch.setenv(envknobs.COMB_MIN, "77")
     assert envknobs.get_int(envknobs.COMB_MIN) == 77
     monkeypatch.setenv(envknobs.COMB_MIN, "junk")
-    assert envknobs.get_int(envknobs.COMB_MIN) == 512  # declared default
+    assert envknobs.get_int(envknobs.COMB_MIN) == 32  # declared default
     monkeypatch.setenv(envknobs.COMB_TREE, "0")
     assert envknobs.get_bool(envknobs.COMB_TREE) is False
     monkeypatch.delenv(envknobs.COMB_TREE, raising=False)
