@@ -25,6 +25,13 @@ FALLBACK_SPANS = (
 )
 
 
+# the hub's counters of batches that the host answered or answered again
+HOST_COUNTERS = (
+    "verify_svc_host_reverify", "verify_svc_collect_timeout",
+    "verify_svc_failover", "verify_host_route",
+)
+
+
 class CheckFailure(Exception):
     pass
 
@@ -169,9 +176,7 @@ def route_counters() -> dict:
         "failover_trips": st["failover"]["trips"],
         "rejected": st["rejected"],
         "dispatched_batches": sum(st["dispatched_batches"].values()),
-        "verify_svc_host_reverify": _counter_total(m.verify_svc_host_reverify),
-        "verify_svc_collect_timeout": _counter_total(m.verify_svc_collect_timeout),
-        "verify_svc_failover": _counter_total(m.verify_svc_failover),
+        **{c: _counter_total(getattr(m, c)) for c in HOST_COUNTERS},
     }
 
 
@@ -227,8 +232,7 @@ def route_failures(counters: dict, spans: dict | None = None) -> list[str]:
         out.append(f"{counters['failover_trips']} failover trip(s)")
     if any(counters["rejected"].values()):
         out.append(f"rejected submits: {counters['rejected']}")
-    for c in ("verify_svc_host_reverify", "verify_svc_collect_timeout",
-              "verify_svc_failover"):
+    for c in HOST_COUNTERS:
         if counters[c]:
             out.append(f"{c} = {counters[c]:g}")
     if not counters["dispatched_batches"]:

@@ -169,8 +169,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     events = checks.JaxEvents()
     events.install()
     # the span ring is on through set-up in every run: a batch routed to
-    # the host leaves a span and no counter, and the route depends on
-    # widths that are the same before and inside the window
+    # the host leaves a span there (and, in every phase, a count), and the
+    # route depends on widths that are the same before and inside the window
     tracing.set_enabled(True, ring_capacity=1 << 20)
     tracing.reset()
     problems: list[str] = []
@@ -179,13 +179,16 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     state = driver.setup(cell, seed, log)
     log(f"set-up data and checks done at {time.monotonic() - t_start:.1f} s")
     try:
-        driver.warm(state)
         if not trace:
+            # the ring has seen set-up's batches; off before the warm-up,
+            # which then runs as the window will (the host-route counter
+            # covers both, checks.route_counters)
             problems += checks.span_fallbacks(
                 checks.route_spans(tracing.chrome_trace_events())
             )
             tracing.set_enabled(False)
             tracing.reset()
+        driver.warm(state)
         if trace:
             # the program's own hook around bv.verify(); traced runs
             # only, so that the other runs take the untouched path
@@ -261,7 +264,26 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
         for m in wanted if m["name"] in values
     }
+    # what ``correct`` compared, each number beside its limit: last
+    result["compared"] = compared(window, compiled, counters, problems)
     return result, facts
+
+
+def compared(window: Window, compiled: list, counters: dict,
+             problems: list) -> dict:
+    """Every comparison is exact, so every limit is 0.  A verdict vector
+    that differs from the reference's in set-up ends the run with no
+    result; after the window it is one of ``checks_not_held``."""
+    host = (sum(counters[c] for c in checks.HOST_COUNTERS)
+            + counters["failover_trips"] + sum(counters["rejected"].values()))
+    numbers = {
+        "requests_failed": window.failed,
+        "compiled_in_window": len(compiled),
+        "batches_off_the_device": int(host),
+        "backend_not_tpu": int(counters["backend_mode"] != "tpu"),
+        "checks_not_held": len(problems),
+    }
+    return {k: {"value": v, "limit": 0} for k, v in numbers.items()}
 
 
 def main(argv: list[str], t_start: float) -> int:
@@ -298,6 +320,10 @@ def main(argv: list[str], t_start: float) -> int:
         log(f"the profiler's trace cannot be reduced: {e}")
         return 1
     print("bench facts: " + json.dumps(facts))
+    for p in facts["problems"]:
+        log(f"not correct: {p}")
+    for name, c in result["compared"].items():
+        log(f"compared {name}: {c['value']:g}, limit {c['limit']}")
     # the result line: last, and nothing after it
     print(json.dumps(result), flush=True)
     return 0

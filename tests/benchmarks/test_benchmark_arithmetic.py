@@ -44,6 +44,24 @@ def test_samples_beyond_a_percentile(n, q, want):
     assert stats.samples_beyond(n, q) == want
 
 
+@pytest.mark.parametrize("values,want", [
+    # ten samples: 4 + 3 + 3, the first thirds take the remainder
+    (list(range(1, 11)), [(4, 2.5, 3.7), (3, 6.0, 6.8), (3, 9.0, 9.8)]),
+    # a slow start shows in the first third alone
+    ([30.0, 30.0, 10.0, 10.0, 10.0, 10.0], [(2, 30.0, 30.0), (2, 10.0, 10.0),
+                                            (2, 10.0, 10.0)]),
+    # in the order taken, not sorted
+    ([3.0, 1.0, 2.0], [(1, 3.0, 3.0), (1, 1.0, 1.0), (1, 2.0, 2.0)]),
+    ([7.0, 9.0], [(1, 7.0, 7.0), (1, 9.0, 9.0)]),  # an empty third is left out
+    ([], []),
+])
+def test_thirds_of_a_window_in_the_order_taken(values, want):
+    got = stats.thirds(values)
+    assert [(t["n"], t["p50"], t["p90"]) for t in got] == [
+        (n, pytest.approx(p50), pytest.approx(p90)) for n, p50, p90 in want]
+    assert sum(t["n"] for t in got) == len(values)
+
+
 # one chip, 100 ns window [100, 200]: busy 110-130, 125-140 (overlap),
 # 160-170, and one operation that straddles the window's end
 OPS = [(110, 130, "fusion.1"), (125, 140, "fusion.2 = s32[22,256]{1,0} fusion()"),

@@ -318,3 +318,62 @@ def test_the_kernel_metrics_partition_the_verify_program():
     for f in list(files.values()) + [rest]:
         assert f["reader"] == "xplane_scope_time"
         assert f["args"]["modules"] == whole["args"]["modules"]
+
+
+# the comb program: scalar_mul holds nothing but the two lookups and the
+# fold, each under a scope nested in it; the fold's while holds its
+# iterations' operations
+COMB_MODULES = [(0.0, 1000.0, "jit__device_verify(7)")]
+Q = "jit(_device_verify)/jit(main)/scalar_mul/"
+COMB_NAMES = {"jit__device_verify(7)": {
+    "fusion.1": "jit(_device_verify)/jit(main)/decompress/mul",
+    "gather.2": Q + "comb_lookup_a/gather",
+    "gather.3": Q + "comb_lookup_b/gather",
+    "while.4": Q + "tree_reduce/while",
+    "fusion.5": Q + "tree_reduce/while/body/add",
+}}
+COMB_OPS = [
+    (0.0, 240.0, "%fusion.1 = fusion()"),
+    (240.0, 310.0, "%gather.2 = gather()"),
+    (310.0, 340.0, "%gather.3 = gather()"),
+    (340.0, 900.0, "%while.4 = while()"),
+    (350.0, 600.0, "%fusion.5 = fusion()"),
+    (610.0, 890.0, "%fusion.5 = fusion()"),
+]
+
+
+def test_fold_and_lookups_add_up_to_scalar_mul():
+    """tree_reduce_device_ms and comb_lookup_device_ms read scopes nested
+    in scalar_mul, on the comb program only, and split it in two."""
+    args = {
+        n: spec.load_json(os.path.join(
+            REPO, "benchmarks", "layer_metrics", n + ".json"))["args"]
+        for n in ("scalar_mul_device_ms", "tree_reduce_device_ms",
+                  "comb_lookup_device_ms")
+    }
+    ns = {
+        n: profile_rows.scope_ns(COMB_OPS, COMB_NAMES, a["scopes"],
+                                 COMB_MODULES, a["modules"], 0.0, 1000.0)
+        for n, a in args.items()
+    }
+    assert ns["tree_reduce_device_ms"] == 560.0
+    assert ns["comb_lookup_device_ms"] == 100.0
+    assert ns["scalar_mul_device_ms"] == 660.0
+    assert args["tree_reduce_device_ms"]["modules"] == ["jit__device_verify"]
+    # the uncached program has no such scope: nothing to read there
+    a = args["tree_reduce_device_ms"]
+    assert profile_rows.scope_ns(OPS, OP_NAMES, a["scopes"], MODULES,
+                                 a["modules"], 0.0, 2000.0) is None
+
+
+@pytest.mark.parametrize("name", ["tree_reduce_device_ms", "comb_lookup_device_ms"])
+def test_nested_scope_metric_is_listed_with_the_scope_that_holds_it(name):
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
+    holder = next(m for m in BENCH["per_layer"]
+                  if m["name"] == "scalar_mul_device_ms")
+    assert entry["workloads"] == holder["workloads"]
+    assert (entry["unit"], entry["layer"], entry["moves"], entry["source"]) == (
+        holder["unit"], holder["layer"], holder["moves"], holder["source"])
+    for cell_name in entry["workloads"]:
+        cell = spec.resolve(cell_name)
+        assert name in [m["name"] for m in cell.per_layer]

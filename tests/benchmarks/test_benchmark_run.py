@@ -110,10 +110,10 @@ def fresh(monkeypatch):
     tracing.reset()
 
 
-def tiny_cell(width: int, pool: int = 3):
+def tiny_cell(width: int, pool: int = 3, warm_s: float = 0.05):
     cell = spec.resolve("commit-175-serial")
     cell.config = dict(cell.config, validators=width)
-    cell.traffic = dict(cell.traffic, pool=pool, warm_verdicts=2)
+    cell.traffic = dict(cell.traffic, pool=pool, warm_s=warm_s)
     return cell
 
 
@@ -130,7 +130,12 @@ def test_host_routed_run_counts_rightly_and_is_not_correct(fresh):
     assert any("verify.host_route span(s)" in p for p in facts["problems"])
     assert result["failed"] == 0 and result["attempted"] > 3
     assert facts["samples"] == {"request_s": result["attempted"]}  # one each
-    assert set(result) == {"correct", "attempted", "failed", "device", "metrics"}
+    assert list(result) == ["correct", "attempted", "failed", "device",
+                            "metrics", "compared"]  # what was compared: last
+    assert all(c["limit"] == 0 for c in result["compared"].values())
+    assert result["compared"]["batches_off_the_device"]["value"] > 0
+    assert result["compared"]["requests_failed"]["value"] == 0
+    assert facts["route"]["verify_host_route"] > 0  # the counter, ring or not
     assert set(result["metrics"]) == {"verdict_p50_ms", "verdict_p90_ms", "setup_s"}
     for name, m in result["metrics"].items():
         assert m["value"] > 0 and m["unit"] == ("s" if name == "setup_s" else "ms")
@@ -145,16 +150,112 @@ def test_a_verdict_that_raises_is_a_failed_request(fresh, monkeypatch):
 
     def setup_then_tamper(cell, seed, log):
         state = setup(cell, seed, log)
-        sc = state.pool[2]  # the two warm verdicts take pool[0] and pool[1]
+        sc = state.pool[2]
         sc.commit, _ = checks.tampered(sc.commit, 8)
         return state
 
     monkeypatch.setattr(commit_serial, "setup", setup_then_tamper)
-    result, _ = run_tiny(tiny_cell(8))
+    result, _ = run_tiny(tiny_cell(8, warm_s=0))  # no warm-up to raise in
     assert result["correct"] is False
+    assert result["compared"]["requests_failed"]["value"] == result["failed"]
     assert result["attempted"] >= 3
     assert result["failed"] in (result["attempted"] // 3,
                                 (result["attempted"] + 1) // 3)
+
+
+def test_a_program_that_stops_verifying_is_caught_after_the_window(
+        fresh, monkeypatch):
+    """An answer altered where it is produced: after set-up's checks the
+    program's verify_commit accepts whatever it is given.  No request of
+    the window fails, and the run is still not correct: the check after
+    the window hands it a tampered commit through the same entry."""
+    from cometbft_tpu.types import validation
+
+    setup = commit_serial.setup
+
+    def setup_then_break(cell, seed, log):
+        state = setup(cell, seed, log)
+        monkeypatch.setattr(validation, "verify_commit", lambda *a, **kw: None)
+        return state
+
+    monkeypatch.setattr(commit_serial, "setup", setup_then_break)
+    result, facts = run_tiny(tiny_cell(8))
+    assert result["failed"] == 0 and result["attempted"] > 3
+    assert result["correct"] is False
+    assert any(p.startswith("after the window:") and "accepted" in p
+               for p in facts["problems"])
+
+
+class StandIn:
+    """A driver that verifies nothing and keeps the time of every call:
+    its verdict takes ``cost`` seconds."""
+
+    def __init__(self, cost: float):
+        self.cost, self.warm_calls, self.run_calls = cost, [], []
+        self.ring_on_in_warm = None
+
+    def setup(self, cell, seed, log):
+        return commit_serial.State(None, [None] * 4, cell.traffic["warm_s"], log)
+
+    def verdict(self, valset, sc):
+        from cometbft_tpu.utils import tracing
+
+        self.ring_on_in_warm = tracing.enabled()
+        self.warm_calls.append(time.monotonic())
+        time.sleep(self.cost)
+        self.warm_ended = time.monotonic()
+
+    def run(self, state, window):
+        while not window.expired():
+            with window.request():
+                self.run_calls.append(time.monotonic())
+                time.sleep(self.cost)
+                window.sample("request_s", self.cost)
+
+    def finish(self, state):
+        return []
+
+    end_to_end = staticmethod(commit_serial.end_to_end)
+
+
+@pytest.mark.parametrize("cost", [0.002, 0.02])
+def test_the_warm_up_is_a_time_and_none_of_it_is_sampled(fresh, monkeypatch, cost):
+    """The driver's warm-up with a stand-in verdict: it lasts ``warm_s``
+    whatever a verdict costs (a count would shrink with the verdict),
+    runs as the window does (ring off in an untraced run), ends before
+    the window opens, and leaves no sample in it."""
+    import jax
+
+    stand_in = StandIn(cost)
+    monkeypatch.setattr(commit_serial, "verdict", stand_in.verdict)
+    stand_in.warm = commit_serial.warm  # the driver's own rule
+    cell = tiny_cell(8, warm_s=0.3)
+    cell.driver = stand_in
+    t0 = time.monotonic()
+    result, facts = harness.run_cell(cell, 5, 0.2, False, t0, jax.devices())
+    warm = stand_in.warm_calls
+    # a loaded machine sleeps longer than asked: the time holds, the
+    # count only has its ceiling
+    assert 0.3 <= stand_in.warm_ended - warm[0] < 0.3 + 1.0
+    assert warm[-1] - warm[0] < 0.3
+    assert 3 <= len(warm) <= 0.3 / cost + 1
+    assert stand_in.ring_on_in_warm is False
+    assert warm[-1] < stand_in.run_calls[0]
+    assert facts["setup_s"] >= 0.3  # the warm-up is set-up
+    assert facts["samples"] == {"request_s": result["attempted"]}
+    assert result["attempted"] == len(stand_in.run_calls)
+    thirds = facts["end_to_end"]["verdict_ms_thirds"]
+    assert sum(t["n"] for t in thirds) == result["attempted"]
+    assert "verdict_ms_thirds" not in result["metrics"]  # the facts line only
+
+
+@pytest.mark.parametrize("traffic", sorted(
+    {w["traffic"] for w in BENCH["workloads"]}))
+def test_traffic_files_state_the_warm_up_as_a_time(traffic):
+    t = spec.load_json(os.path.join(
+        REPO, "benchmarks", "traffic", traffic + ".json"))
+    assert "warm_verdicts" not in t
+    assert 3 <= t["warm_s"] <= 30  # seconds of the cell's own traffic
 
 
 def test_a_failed_check_of_setup_prints_no_result(fresh, monkeypatch, capsys):

@@ -92,6 +92,16 @@ def test_every_cell_reports_setup_another_metric_and_a_layer_metric():
         assert set(m.get("workloads", CELLS)) <= set(moved.get("workloads", CELLS)), m
 
 
+@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
+def test_a_per_layer_list_stays_inside_the_list_of_what_it_moves(metric):
+    """A traced run of a cell that does not report the moved end-to-end
+    metric could not show the layer metric moving it."""
+    m = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    moved = next(e for e in BENCH["end_to_end"] if e["name"] == m["moves"])
+    assert set(m.get("workloads", CELLS)) <= set(moved.get("workloads", CELLS))
+    assert set(m.get("workloads", CELLS)) <= set(CELLS)
+
+
 @pytest.mark.parametrize("cell_name", CELLS)
 def test_cell_resolves_to_its_files_by_name(cell_name):
     cell = spec.resolve(cell_name)
@@ -147,7 +157,7 @@ def test_a_new_cell_and_a_new_span_metric_are_new_files_only(tmp_path):
                         tmp_path / "benchmarks" / d)
     bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
     (tmp_path / "benchmarks/traffic/serial-pool2.json").write_text(json.dumps(
-        {"driver": "commit_serial", "pool": 2, "warm_verdicts": 2}))
+        {"driver": "commit_serial", "pool": 2, "warm_s": 3}))
     (tmp_path / "benchmarks/layer_metrics/sched_dispatch_ms.json").write_text(
         json.dumps({"reader": "span_median",
                     "args": {"per": "span", "spans": ["verify.sched.dispatch"]}}))
