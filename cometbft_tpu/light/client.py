@@ -18,7 +18,9 @@ from dataclasses import dataclass
 from fractions import Fraction
 
 from ..types.light_block import LightBlock
+from ..utils import tracing
 from ..utils.log import get_logger
+from ..utils.metrics import hub as _mhub
 from . import detector as detector_mod
 from .provider import (
     ErrHeightTooHigh,
@@ -165,12 +167,42 @@ class Client:
         if height <= 0:
             raise LightClientError("height must be positive")
         now_ns = self.now_ns() if now_ns is None else now_ns
-        lb = self.store.light_block(height)
-        if lb is not None:
-            return lb
-        lb = self.primary.light_block(height)
-        self._verify_light_block(lb, now_ns)
+        with tracing.span("light.walk"):
+            lb = self.store.light_block(height)
+            if lb is None:
+                lb = self.primary.light_block(height)
+                self._verify_light_block(lb, now_ns)
         return lb
+
+    def _hop(self, trusted: LightBlock, new_lb: LightBlock, now_ns: int) -> None:
+        """One ``verifier.verify`` of a walk, as the span ``light.hop``
+        (its result and the two heights as labels) and a count."""
+        labels = (
+            {"mode": self.mode, "from": trusted.height, "to": new_lb.height}
+            if tracing.enabled() else None
+        )
+        result = "refused"
+        # a span reads its labels when it closes: the result goes in then
+        with tracing.span("light.hop", labels):
+            try:
+                verify(
+                    trusted.signed_header,
+                    trusted.validator_set,
+                    new_lb.signed_header,
+                    new_lb.validator_set,
+                    self.trusting_period_ns,
+                    now_ns,
+                    self.max_clock_drift_ns,
+                    self.trust_level,
+                )
+                result = "ok"
+            except ErrNewValSetCantBeTrusted:
+                result = "cant_be_trusted"
+                raise
+            finally:
+                if labels is not None:
+                    labels["result"] = result
+                _mhub().light_hops.inc(mode=self.mode, result=result)
 
     def _verify_light_block(self, new_lb: LightBlock, now_ns: int) -> None:
         """client.go:553 — pick the verification path by position."""
@@ -213,16 +245,7 @@ class Client:
         verified = trusted
         for h in range(trusted.height + 1, new_lb.height + 1):
             lb = new_lb if h == new_lb.height else self.primary.light_block(h)
-            verify(
-                verified.signed_header,
-                verified.validator_set,
-                lb.signed_header,
-                lb.validator_set,
-                self.trusting_period_ns,
-                now_ns,
-                self.max_clock_drift_ns,
-                self.trust_level,
-            )
+            self._hop(verified, lb, now_ns)
             verified = lb
             trace.append(lb)
         return trace
@@ -237,16 +260,7 @@ class Client:
         trace = [trusted]
         while True:
             try:
-                verify(
-                    verified.signed_header,
-                    verified.validator_set,
-                    block_cache[depth].signed_header,
-                    block_cache[depth].validator_set,
-                    self.trusting_period_ns,
-                    now_ns,
-                    self.max_clock_drift_ns,
-                    self.trust_level,
-                )
+                self._hop(verified, block_cache[depth], now_ns)
             except ErrNewValSetCantBeTrusted:
                 # not enough trust to jump: bisect at 9/16 of the gap
                 if depth == len(block_cache) - 1:

@@ -18,6 +18,7 @@ from ..types.validation import (
     verify_commit_light,
     verify_commit_light_trusting,
 )
+from ..utils import tracing
 from ..verifysvc.service import Klass as _VerifyKlass
 
 DEFAULT_TRUST_LEVEL = Fraction(1, 3)
@@ -107,23 +108,25 @@ def verify_adjacent(
         raise ErrOldHeaderExpired(
             trusted_sh.header.time.unix_ns() + trusting_period_ns, now_ns
         )
-    _verify_new_header_and_vals(
-        untrusted_sh, untrusted_vals, trusted_sh, now_ns, max_clock_drift_ns
-    )
+    with tracing.span("light.header_check"):
+        _verify_new_header_and_vals(
+            untrusted_sh, untrusted_vals, trusted_sh, now_ns, max_clock_drift_ns
+        )
     if untrusted_sh.header.validators_hash != trusted_sh.header.next_validators_hash:
         raise ErrInvalidHeader(
             f"header next validators {trusted_sh.header.next_validators_hash.hex()} "
             f"do not match new validators {untrusted_sh.header.validators_hash.hex()}"
         )
     try:
-        verify_commit_light(
-            trusted_sh.header.chain_id,
-            untrusted_vals,
-            untrusted_sh.commit.block_id,
-            untrusted_sh.header.height,
-            untrusted_sh.commit,
-            klass=_VerifyKlass.BACKGROUND,
-        )
+        with tracing.span("light.commit_check"):
+            verify_commit_light(
+                trusted_sh.header.chain_id,
+                untrusted_vals,
+                untrusted_sh.commit.block_id,
+                untrusted_sh.header.height,
+                untrusted_sh.commit,
+                klass=_VerifyKlass.BACKGROUND,
+            )
     except Exception as e:  # noqa: BLE001
         raise ErrInvalidHeader(f"invalid commit: {e}") from e
 
@@ -146,34 +149,37 @@ def verify_non_adjacent(
         raise ErrOldHeaderExpired(
             trusted_sh.header.time.unix_ns() + trusting_period_ns, now_ns
         )
-    _verify_new_header_and_vals(
-        untrusted_sh, untrusted_vals, trusted_sh, now_ns, max_clock_drift_ns
-    )
+    with tracing.span("light.header_check"):
+        _verify_new_header_and_vals(
+            untrusted_sh, untrusted_vals, trusted_sh, now_ns, max_clock_drift_ns
+        )
 
     cache = SignatureCache()
     try:
-        verify_commit_light_trusting(
-            trusted_sh.header.chain_id,
-            trusted_vals,
-            untrusted_sh.commit,
-            trust_level,
-            cache=cache,
-            klass=_VerifyKlass.BACKGROUND,
-        )
+        with tracing.span("light.trusting_check"):
+            verify_commit_light_trusting(
+                trusted_sh.header.chain_id,
+                trusted_vals,
+                untrusted_sh.commit,
+                trust_level,
+                cache=cache,
+                klass=_VerifyKlass.BACKGROUND,
+            )
     except NotEnoughVotingPowerError as e:
         raise ErrNewValSetCantBeTrusted(str(e)) from e
 
     # always last: untrusted_vals can be made arbitrarily large to DoS
     try:
-        verify_commit_light(
-            trusted_sh.header.chain_id,
-            untrusted_vals,
-            untrusted_sh.commit.block_id,
-            untrusted_sh.header.height,
-            untrusted_sh.commit,
-            cache=cache,
-            klass=_VerifyKlass.BACKGROUND,
-        )
+        with tracing.span("light.commit_check"):
+            verify_commit_light(
+                trusted_sh.header.chain_id,
+                untrusted_vals,
+                untrusted_sh.commit.block_id,
+                untrusted_sh.header.height,
+                untrusted_sh.commit,
+                cache=cache,
+                klass=_VerifyKlass.BACKGROUND,
+            )
     except Exception as e:  # noqa: BLE001
         raise ErrInvalidHeader(f"invalid commit: {e}") from e
 

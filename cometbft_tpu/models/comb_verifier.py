@@ -706,7 +706,10 @@ class CombBatchVerifier:
                 m.verify_submit_queue_depth.add(-1)
 
         try:
-            fut = _staging_executor().submit(stage)
+            # under the batch's trace identity (the scheduler installs it
+            # around this call), so that the staging thread's spans carry
+            # the id the dispatch, the wait and the blame unpack carry
+            fut = _staging_executor().submit(tracing.carry_context(stage))
         except BaseException:
             m.verify_submit_queue_depth.add(-1)  # stage() never ran
             if waiting:

@@ -403,6 +403,13 @@ class Hub:
             "lane=uncached|comb, reason=below_batch_min: narrower than "
             "COMETBFT_TPU_DEVICE_BATCH_MIN)",
         )
+        self.light_hops = r.counter(
+            "light_hops_total",
+            "Hops a light client tried (light/client: one verifier.verify "
+            "call; label mode=skipping|sequential, result=ok|"
+            "cant_be_trusted|refused; cant_be_trusted = the bisection's "
+            "next pivot, refused = the walk ends)",
+        )
         self.comb_table_cache = r.counter(
             "verify_comb_table_cache_total",
             "Valset comb-table cache lookups (label result=hit|miss|"
@@ -433,8 +440,9 @@ class Hub:
         self.verify_svc_flush = r.counter(
             "verify_svc_flush_total",
             "Verify-service batch flushes (labels class, reason=full|"
-            "deadline: full = batch width reached, deadline = class "
-            "flush deadline expired first)",
+            "deadline|solo: full = batch width reached, deadline = class "
+            "flush deadline expired first, solo = a comb- or bls-bound "
+            "request, which nothing can join and no deadline holds)",
         )
         self.verify_svc_rejected = r.counter(
             "verify_svc_rejected_total",

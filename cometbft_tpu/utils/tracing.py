@@ -230,6 +230,22 @@ def context_scope(ctx: SpanContext | None):
     return _CtxScope(ctx if propagation_enabled() else None)
 
 
+def carry_context(fn):
+    """``fn`` as a callable that runs under the CALLING thread's current
+    context: for work handed to another thread, whose spans then carry
+    the trace identity of the request they serve.  ``fn`` itself where
+    no context is installed."""
+    ctx = current_context()
+    if ctx is None:
+        return fn
+
+    def run(*args, **kwargs):
+        with context_scope(ctx):
+            return fn(*args, **kwargs)
+
+    return run
+
+
 def propagation_enabled() -> bool:
     return _ENABLED and _CTX_ENABLED
 

@@ -976,6 +976,11 @@ class VerifyService:
         q = self._queues[klass].get(tenant)
         if not q:
             return False
+        if q[0].mode[0] not in _COALESCIBLE_MODES:
+            # the head dispatches solo (comb- or bls-bound): the class's
+            # flush deadline is a window for coalescing, and nothing can
+            # join this request however long it waits
+            return True
         if self._queued_sigs[klass].get(tenant, 0) >= self.batch_max:
             return True
         return (now - q[0].enq) >= self._deadline_s[klass]
@@ -1089,7 +1094,10 @@ class VerifyService:
             del self._queues[klass][tenant]
             self._queued_sigs[klass].pop(tenant, None)
         self._class_sigs[klass] -= total
-        reason = "full" if (was_full or total >= self.batch_max) else "deadline"
+        if was_full or total >= self.batch_max:
+            reason = "full"
+        else:
+            reason = "deadline" if kind in _COALESCIBLE_MODES else "solo"
         return batch, reason
 
     def _track_inflight(self, batch: list[_Request], where: str) -> None:

@@ -116,6 +116,36 @@ def test_deadline_triggered_flush(svc):
     assert _flush_count("mempool", "deadline") == before + 1  # …then flushed
 
 
+@pytest.mark.parametrize("klass, mode", [
+    (Klass.BACKGROUND, ("comb", object())),
+    (Klass.BLOCKSYNC, ("comb", object())),
+    (Klass.MEMPOOL, ("bls",)),
+])
+def test_a_request_that_dispatches_solo_waits_for_no_deadline(svc, klass, mode):
+    """A comb- or bls-bound request can be joined by nothing, so its
+    class's flush deadline (a coalescing window) does not hold it: it is
+    ready at once and flushes with reason ``solo``, in every class that
+    has a deadline, while a plain request of that class still waits."""
+    from cometbft_tpu.verifysvc.service import _HostBatchVerifier
+
+    deadlines = {k: 0 for k in Klass}
+    deadlines[klass] = 60_000
+    s = svc(batch_max=1024, deadlines_ms=deadlines)
+    s._make_verifier = lambda mode: _HostBatchVerifier(("plain",))
+    label = klass.name.lower()
+    solo = _flush_count(label, "solo")
+    t0 = time.monotonic()
+    bound = s.submit(_sigs(3, b"bound", tamper=(1,)), klass, mode)
+    # queued behind it (a queue is first in, first out: a solo request
+    # BEHIND a coalescible one would wait for that one's flush)
+    plain = s.submit(_sigs(2, b"plain"), klass)
+    ok, per = bound.collect(WAIT)
+    assert time.monotonic() - t0 < 5.0  # not the 60 s of its class
+    assert not ok and per == [True, False, True]
+    assert _flush_count(label, "solo") == solo + 1
+    assert not plain.done()  # coalescible: still inside its window
+
+
 def test_full_batch_flush_and_coalescing(svc):
     """Two sub-width requests coalesce; crossing the batch width flushes
     with reason=full before the (absurd) deadline."""
