@@ -664,6 +664,15 @@ def run(width_large: int, width_small: int, facts: dict) -> dict:
         for c in (big, small)
     }
     facts["comb_lanes"] = sorted(lanes)
+    facts["comb_fold_chains"] = {
+        str(n): hub().comb_fold_chains.value(lanes=str(n)) for n in sorted(lanes)
+    }
+    print(
+        f"chip_smoke: comb_fold_chains {facts['comb_fold_chains']}",
+        file=sys.stderr, flush=True,
+    )
+    if not all(facts["comb_fold_chains"].values()):
+        problems.append("a compiled comb program set no fold-chains gauge")
     compiled_comb = rep["comb_program_cache"]["compile"] - comb_compiles_0
     if compiled_comb > len(lanes):
         problems.append(

@@ -150,12 +150,14 @@ KERNELS: tuple[Kernel, ...] = (
         args=(_TABLES, boolean(V), u8(V, 32), u8(V, 32), u8(V, 64), _B_TABLES),
         out=(boolean(V),),
         static_kwargs=(("tree", True),),
-        max_eqns=50_000,  # measured 38,618
+        # the default path: K parallel add_niels chains in one rolled
+        # scan (K = 8 at the 4-lane trace)
+        max_eqns=39_000,  # measured 29,892
         arg_ranges=(DIGITS, None, None, None, None, DIGITS),
     ),
     Kernel(
         # the sequential cross-check path must stay pinned too: it is the
-        # bit-exactness witness for the tree path (COMETBFT_TPU_COMB_TREE=0)
+        # bit-exactness witness for the chains (COMETBFT_TPU_COMB_TREE=0)
         name="comb_verify_cached_seq",
         fn="cometbft_tpu.ops.comb:verify_cached",
         args=(_TABLES, boolean(V), u8(V, 32), u8(V, 32), u8(V, 64), _B_TABLES),
@@ -409,7 +411,7 @@ KERNELS: tuple[Kernel, ...] = (
         fn="cometbft_tpu.models.comb_verifier:_device_verify",
         args=(_TABLES, boolean(V), u8(V, 32), u8(V, PAYLOAD_W)),
         out=(u8(2),),  # packbits(V=4 lanes) -> 1 byte, + the all-ok byte
-        max_eqns=50_000,  # measured 39,068
+        max_eqns=39_500,  # measured 30,334
         arg_ranges=(DIGITS, None, None, None),
     ),
     # ---- parallel/verify.py — the mesh-sharded programs (1-device CPU
@@ -430,7 +432,7 @@ KERNELS: tuple[Kernel, ...] = (
         out=(u8(2),),
         needs_mesh=True,
         mesh_static=(True,),  # tree=True, part of the jit cache key
-        max_eqns=50_000,  # measured 39,075
+        max_eqns=39_500,  # measured 30,341
         arg_ranges=(DIGITS, None, None, None),
     ),
     Kernel(
@@ -712,10 +714,10 @@ SHARDED_KERNELS: tuple[ShardedKernel, ...] = (
         ),
         out_specs=((),),
         collectives=(("all_gather", 1), ("psum", 1)),
-        # measured 39,075 eqns / loop depth 1 / ~24.9 MB per device at
+        # measured 30,341 eqns / loop depth 1 / ~24.9 MB per device at
         # the 8-lane trace (the replicated radix-4096 basepoint comb is
         # ~23.8 MB on EVERY device — the estimate is dominated by it)
-        max_eqns=50_000,
+        max_eqns=39_500,
         max_loop_depth=4,
         max_device_bytes=48 << 20,
         # the per-call staging payload is consumed by the dispatch;

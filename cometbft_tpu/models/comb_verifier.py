@@ -822,9 +822,16 @@ class CombBatchVerifier:
             _COMB_PROGRAMS, _COMB_PROGRAMS_MTX, _program_key(e, width),
             lower, compiled.append,
         )
-        _mhub().comb_program_cache.inc(
-            result="compile" if compiled else "hit"
-        )
+        hub = _mhub()
+        hub.comb_program_cache.inc(result="compile" if compiled else "hit")
+        if compiled:
+            from ..ops import comb
+
+            # the schedule is fixed when the program is traced
+            hub.comb_fold_chains.set(
+                comb.fold_chains(e.vpad) if comb.tree_enabled() else 1,
+                lanes=str(e.vpad),
+            )
         return prog
 
 

@@ -21,7 +21,8 @@ HBM for those doublings entirely:
 
 verify_cached then needs NO doublings and NO per-signature table build:
    acc = sum_i T[i][k_i][v]  +  sum_i B_TAB[i][s_i]  - R,   check [8]acc = 0
-64 + 22 + 1 additions and one point decompression (R) per signature,
+64 + 22 mixed additions in K parallel chains (K from the lane count:
+fold_chains), K unified ones, and one point decompression (R) per signature,
 versus 256 doublings + 128 additions + 2 decompressions + table build for
 the uncached kernel.
 
@@ -36,14 +37,17 @@ one-hot table lookups are proved to select, not accumulate, so the
 peak |f32 value| is 4095, leaving ~12 bits of slack under the 2^24
 exact-integer envelope (docs/limb_headroom.md: that slack is what
 funds wider comb digits).  The int32 plane peaks at 1,252,794,005 in
-the shared field walk.  One proved-adversarial hazard shapes this
-module: comb tables are attacker-influenced device inputs (a hostile
-validator key produces arbitrary canonical table coords), and the
-TREE accumulation path sums two lifted Niels points before the first
-field mul — without the F.carry in ed25519.niels_to_extended those
-sums exceed the MULIN mul-input bound and the conv partial sums
-clear 2^31.  The certificate pins the carried version; the rangecheck
-gate fails any regression.
+the shared field walk.  Comb tables are attacker-influenced device
+inputs (a hostile validator key produces arbitrary canonical table
+coords), and they reach the field multiplications as they are: every
+accumulation path adds a table entry in Niels form with E.add_niels,
+whose second operands are the entry's own limbs (|limb| <= 4095,
+sign-flipped or not, inside the MULIN mul-input bound) and whose first
+are sums of two TIGHT accumulator coordinates.  No lifted sums feed a
+multiplication (a fold of lifted points needs the F.carry in
+ed25519.niels_to_extended for that; the regression tests in
+tests/test_rangecheck.py keep both cases).  The certificate pins the
+walk; the rangecheck gate fails any regression.
 """
 
 from __future__ import annotations
@@ -460,26 +464,52 @@ def _b_tables_cached() -> np.ndarray:
 def tree_enabled() -> bool:
     """COMETBFT_TPU_COMB_TREE = "0" selects the sequential fori_loop
     accumulation (the cross-check path); anything else (default) the
-    log-depth tree reduction.  Read at TRACE time: programs already
-    compiled keep the path they were traced with, so flip the flag
-    before the first verify of a process (or use a fresh jit wrapper)."""
+    parallel chains of mixed additions.  Read at TRACE time: programs
+    already compiled keep the path they were traced with, so flip the
+    flag before the first verify of a process (or use a fresh jit
+    wrapper)."""
     from ..utils import envknobs
 
     return envknobs.get_bool(envknobs.COMB_TREE)
 
 
-def accumulation_depth() -> int:
-    """Dependent point-add rounds in the active accumulation path —
-    the number the profile/bench scripts report.  Tree: ceil(log2) fold
-    of the 64 + 22 + 1 point stack; sequential: one add per position
-    plus the R fold."""
+NPART = NPOS_A + NPOS_B  # Niels partials a lane sums, before -R
+
+# For each K, the widest batch (K chains x lanes) of one field
+# multiplication at which a chain step was measured running out of on-chip
+# memory on the chip: up to it a step is bound by the launches of its ~290
+# short fusions, so more chains a step are nearly free; past it every
+# fusion streams through HBM, time follows the bytes (two to five times
+# more a lane), and the fewest multiplications win.  Measured on a TPU v5e
+# (PERF.md section 6, PR 30, the K sweep over fifteen lane counts): 8
+# chains fast at 384 lanes and slow at 512, 4 fast at 768 and slow at
+# 1,024, 2 fast at 2,560 and slow at 3,072.  No K = 16: it pads the 86
+# partials to 96, and read 5% under K = 8 at 128 lanes and three times
+# over it at 256.
+CHAIN_WIDTHS = ((8, 3072), (4, 3072), (2, 5120))
+
+
+def fold_chains(lanes: int) -> int:
+    """K, the number of parallel add_niels chains the accumulation runs
+    at this lane count: the most chains whose batch K * lanes stays
+    within the width measured fast for that K (CHAIN_WIDTHS), else 1.
+    Read off k_dig's lane axis at trace time, so under shard_map it sees
+    the shard's own lanes."""
+    for k, width in CHAIN_WIDTHS:
+        if k * lanes <= width:
+            return k
+    return 1
+
+
+def accumulation_depth(lanes: int) -> int:
+    """Dependent point-add rounds in the active accumulation path at a
+    lane count: the number the profile/bench scripts report.  Chains:
+    ceil(86 / K) mixed additions, then the fold of K accumulators and
+    -R; sequential: one add per position plus the R fold."""
     if not tree_enabled():
-        return NPOS_A + NPOS_B + 1  # 87 dependent adds
-    n, depth = NPOS_A + NPOS_B + 1, 0
-    while n > 1:
-        n = (n + 1) // 2
-        depth += 1
-    return depth  # 7
+        return NPART + 1  # 87 dependent adds
+    k = fold_chains(lanes)
+    return -(-NPART // k) + k.bit_length()  # ceil(log2(k + 1)) fold rounds
 
 
 def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None):
@@ -499,7 +529,8 @@ def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None
 
     Manifest kernels ``comb_verify_cached_tree`` / ``_seq`` (one per
     accumulation path — both fingerprints are pinned, since the
-    sequential path is the tree path's bit-exactness witness).  As the
+    sequential path is the chains' bit-exactness witness; ``_tree``
+    names the default path, the parallel chains).  As the
     shard_map body of ``sharded_verify_cached`` this must stay
     lane-local over the validator axis: any collective it grows is
     caught by the sharded census (analysis/shardcheck,
@@ -523,7 +554,7 @@ def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None
 
     if tree is None:
         tree = tree_enabled()
-    acc_fn = _accumulate_tree if tree else _accumulate_sequential
+    acc_fn = _accumulate_chains if tree else _accumulate_sequential
     with jax.named_scope("scalar_mul"):
         acc = acc_fn(tables, k_dig, s_dig, b_tables, r_pt)
 
@@ -535,8 +566,9 @@ def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None
 
 def _accumulate_sequential(tables, k_dig, s_dig, b_tables, r_pt):
     """The original accumulation: 64 + 22 dependent position adds in two
-    fori_loops, then the R fold — an 87-step serial chain.  Kept as the
-    bit-exact cross-check for the tree path (COMETBFT_TPU_COMB_TREE=0)."""
+    fori_loops, then the R fold — an 87-step serial chain, 612 field
+    muls a lane.  Kept as the bit-exact cross-check for the chains
+    (COMETBFT_TPU_COMB_TREE=0)."""
     V = k_dig.shape[-1]
 
     # ---- A part: acc += T[i][|k_i|][v] (sign-adjusted), 64 adds
@@ -580,20 +612,11 @@ def _accumulate_sequential(tables, k_dig, s_dig, b_tables, r_pt):
     return E.add(acc, E.neg(r_pt))
 
 
-def _accumulate_tree(tables, k_dig, s_dig, b_tables, r_pt):
-    """Log-depth accumulation: select every position's partial point at
-    once (leading position axis), convert to extended, and fold the
-    64 A + 22 B partials together with -R in a binary tree of batched
-    unified adds (E.tree_reduce_points) — 7 dependent rounds instead of
-    the 87-step serial chain of _accumulate_sequential.
-
-    The selects do the same total work as the sequential loops but carry
-    no loop dependence, so XLA can schedule them freely; only the tree's
-    7 add rounds are serial.  Extra cost vs sequential: unified add
-    (9 muls) instead of mixed add_niels (7 muls) per fold, plus one mul
-    per partial for the Niels->extended lift — ~45% more multiplies for
-    a 12x shorter dependency chain, a clear win on a latency-bound chip.
-    """
+def _lookup_partials(tables, k_dig, s_dig, b_tables):
+    """Every position's partial point at once, in Niels form: the 64
+    sign-adjusted A selections and the 22 B selections, coords
+    (64, 22, V) and (22, 22, V).  The selects do the same total work as
+    the sequential loops but carry no loop dependence."""
     # ---- A part: all 64 sign-adjusted selections in one shot
     with jax.named_scope("comb_lookup_a"):
         neg_d = k_dig < 0
@@ -608,7 +631,6 @@ def _accumulate_tree(tables, k_dig, s_dig, b_tables, r_pt):
             F.select(neg_d, sel[:, 0], sel[:, 1]),
             F.select(neg_d, -sel[:, 2], sel[:, 2]),
         )
-        pa = E.niels_to_extended(na)  # coords (64, 22, V)
 
     # ---- B part: 22 independent one-hot MXU matmuls (no add chain);
     # unrolled so each keeps the (4096, V) onehot transient of the
@@ -627,17 +649,50 @@ def _accumulate_tree(tables, k_dig, s_dig, b_tables, r_pt):
                 ).astype(jnp.int32)
             )  # (66, V)
         selb = jnp.stack(sels)  # (22, 66, V)
-        pb = E.niels_to_extended(
-            E.Niels(selb[:, 0:22], selb[:, 22:44], selb[:, 44:66])
-        )
+        nb = E.Niels(selb[:, 0:22], selb[:, 22:44], selb[:, 44:66])
+    return na, nb
 
-    # ---- fold A partials + B partials + (-R) in one tree
+
+def _accumulate_chains(tables, k_dig, s_dig, b_tables, r_pt, chains=None):
+    """K parallel chains of mixed additions, then a short fold.
+
+    The 86 looked-up partials stay in Niels form (no lift), are padded
+    with the Niels identity to K * steps and viewed as (steps, K, 22, V);
+    K accumulators start at the identity and take steps = ceil(86 / K)
+    dependent E.add_niels steps (7 field muls each, one rolled body),
+    and the K accumulators fold with -R by unified additions
+    (E.tree_reduce_points, ten muls each).  Field muls a lane: K = 1:
+    612 (the sequential path's count), 2: 622, 4: 656, 8: 696, against
+    946 for lifting all 86 and folding them by unified additions.  K
+    comes from the lane count (fold_chains); chains overrides it for
+    tests and sweeps only.
+    """
+    V = k_dig.shape[-1]
+    K = fold_chains(V) if chains is None else chains
+    steps = -(-NPART // K)
+    na, nb = _lookup_partials(tables, k_dig, s_dig, b_tables)
+
+    # the benchmark's per-layer readers match on this scope's name: the
+    # whole accumulation, chains and fold, reads under it
     with jax.named_scope("tree_reduce"):
-        nr = E.neg(r_pt)
-        stack = E.Point(
-            jnp.concatenate([pa.x, pb.x, nr.x[None]], axis=0),
-            jnp.concatenate([pa.y, pb.y, nr.y[None]], axis=0),
-            jnp.concatenate([pa.z, pb.z, nr.z[None]], axis=0),
-            jnp.concatenate([pa.t, pb.t, nr.t[None]], axis=0),
+        npad = K * steps - NPART
+        pad = E.niels_identity_like(E.Niels(*(c[:npad] for c in na)))
+        xs = E.Niels(
+            *(
+                jnp.concatenate([a, b, i], axis=0).reshape(
+                    steps, K, F.NLIMBS, V
+                )
+                for a, b, i in zip(na, nb, pad)
+            )
         )
-        return E.tree_reduce_points(stack)
+        accs, _ = lax.scan(
+            lambda acc, n: (E.add_niels(acc, n), None),
+            E.identity((K, V)),
+            xs,
+        )
+        nr = E.neg(r_pt)
+        return E.tree_reduce_points(
+            E.Point(
+                *(jnp.concatenate([a, r[None]], axis=0) for a, r in zip(accs, nr))
+            )
+        )

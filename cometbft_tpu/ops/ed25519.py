@@ -32,7 +32,7 @@ partial sums).  The contract leans on two limb invariants from
 ops/field.py: TIGHT (|limb0| <= 3584, others <= 2051) out of carry,
 and MULIN (|limb0| <= 14336, others <= 8204) into mul — any point sum
 wider than MULIN must pass through F.carry before the next mul (see
-niels_to_extended for the one production site where this bit).
+niels_to_extended for the one site where this bit).
 """
 
 from __future__ import annotations
@@ -93,7 +93,8 @@ def select(cond, p: Point, q: Point) -> Point:
 
 
 def add(p: Point, q: Point) -> Point:
-    """Unified complete addition (9 field muls)."""
+    """Unified complete addition: ten F.mul calls, the constant 2d
+    among them."""
     a = F.mul(F.sub(p.y, p.x), F.sub(q.y, q.x))
     b = F.mul(F.add(p.y, p.x), F.add(q.y, q.x))
     c = F.mul(F.mul(p.t, q.t), _c(_D2_L))
@@ -152,21 +153,20 @@ def niels_to_extended(n: Niels) -> Point:
     """Niels (y+x, y-x, 2dxy) -> extended (2x : 2y : 2 : 2xy).
 
     One field mul (t2d * d^-1); the uniform projective scale by 2 is
-    free.  Lets precomputed table entries join unified additions — in
-    particular the log-depth tree fold of tree_reduce_points, whose
+    free.  Lets precomputed table entries join unified additions, whose
     inputs must be full extended points.  Works for the identity
     ((1,1,0) -> (0:2:2:0)) and for sign-flipped entries
-    ((y-x, y+x, -2dxy) -> (-2x : 2y : 2 : -2xy)).
+    ((y-x, y+x, -2dxy) -> (-2x : 2y : 2 : -2xy)).  The comb verify
+    program adds its partials in Niels form and lifts nothing
+    (ops/comb._accumulate_chains); the range regression tests use this.
     """
     # Carry the lifted sums back into the TIGHT profile: for canonical
-    # table entries the raw y+x +/- y-x limbs reach +/-8190, and the
-    # FIRST tree fold adds two lifted points — its F.add(p.y, p.x) would
-    # hit +/-12285 per limb, past the MULIN contract (|limb|<=8204), and
+    # table entries the raw y+x +/- y-x limbs reach +/-8190, and a
+    # unified addition of two lifted points would put +/-12285 per limb
+    # into its F.add(p.y, p.x), past the MULIN contract (|limb|<=8204):
     # the mul conv partial sums would clear 2^31 on adversarial
     # (attacker-chosen pubkey) tables.  One carry pass is elementwise
-    # shifts, noise next to the fold's 9 muls; the range certificate
-    # (analysis/range_fingerprints.json, comb_verify_cached_tree) pins
-    # the proof.
+    # shifts (tests/test_rangecheck.py pins the proof).
     x2 = F.carry(F.sub(n.yplusx, n.yminusx))
     y2 = F.carry(F.add(n.yplusx, n.yminusx))
     batch = x2.shape[:-2] + x2.shape[-1:]
@@ -179,9 +179,10 @@ def tree_reduce_points(p: Point) -> Point:
     binary tree of batched unified additions: ceil(log2(N)) dependent
     rounds instead of an (N-1)-deep sequential accumulation chain.  The
     addition law is complete, so identity entries and odd-level
-    carry-overs are safe anywhere in the tree.  This is the comb verify
-    kernel's accumulation primitive (ops/comb._accumulate_tree): its
-    87-point stack folds in 7 rounds instead of 86.
+    carry-overs are safe anywhere in the tree.  The comb verify kernel
+    folds its K chain accumulators and -R with it
+    (ops/comb._accumulate_chains): K + 1 points, ceil(log2(K + 1))
+    rounds.
     """
     n = p.x.shape[0]
     while n > 1:

@@ -80,7 +80,7 @@ COMB_HOST_BUILD_MAX = _declare(
 COMB_TREE = _declare(
     "COMETBFT_TPU_COMB_TREE", "bool", True,
     "`0` selects the sequential fori_loop comb accumulation (the bit-exact "
-    "cross-check path) instead of the log-depth tree reduction.",
+    "cross-check path) instead of the parallel chains of mixed additions.",
 )
 BTAB_CACHE = _declare(
     "COMETBFT_TPU_BTAB_CACHE", "str", "",

@@ -423,6 +423,13 @@ class Hub:
             "payload width serves every validator set of that shape, so "
             "a set change that compiles shows here)",
         )
+        self.comb_fold_chains = r.gauge(
+            "verify_comb_fold_chains",
+            "Parallel add_niels chains the comb verify program of a "
+            "lane count sums its 86 partial points in (label lanes; "
+            "ops/comb.fold_chains reads K off the lane count when the "
+            "program is traced; 1 = the sequential witness path)",
+        )
         self.secp_pubkey_cache = r.counter(
             "verify_svc_secp_pubkey_cache_total",
             "Decoded-secp256k1-pubkey cache lookups in the MODE_SECP "

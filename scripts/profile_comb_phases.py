@@ -1,5 +1,5 @@
 """Phase profiler for the comb-cached VerifyCommit path: host assembly,
-H2D+dispatch, kernel (tree-reduced AND sequential accumulation), result
+H2D+dispatch, kernel (parallel-chains AND sequential accumulation), result
 fetch, table build, scalar reduce, R decompression, A/B comb loops,
 single field ops — run on the real chip to direct optimization (numbers
 recorded in BASELINE.md).
@@ -7,8 +7,9 @@ recorded in BASELINE.md).
 The headline lines:
   assembly_ms   — host staging-slab fill (models/comb_verifier), the
                   phase the round-5 capture measured at ~22 ms
-  kernel tree/seq — verify_cached with the log-depth tree fold
-                  (acc depth 7) vs the 87-step sequential chain
+  kernel tree/seq — verify_cached with K parallel add_niels chains and
+                  a short fold (K from the lane count) vs the 87-step
+                  sequential chain
   fetch_ms      — the one packed device->host result readback
 
 Layout note: field elements are limbs-first (..., 22, V) since round 4
@@ -96,7 +97,8 @@ def timeit(name, f, *args):
 
 print(
     f"accumulation: tree={comb.tree_enabled()} "
-    f"dependent_depth={comb.accumulation_depth()} "
+    f"chains={comb.fold_chains(V)} "
+    f"dependent_depth={comb.accumulation_depth(V)} "
     f"(sequential chain would be {comb.NPOS_A + comb.NPOS_B + 1})",
     flush=True,
 )

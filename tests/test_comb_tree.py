@@ -1,14 +1,17 @@
-"""Property: the tree-reduced comb accumulation (ops/comb._accumulate_tree)
-is bit-identical to the sequential comb path AND to the Straus fallback
-kernel on randomized vectors — including non-signer zero rows and ZIP-215
-edge encodings — with the pure-Python host verifier as ground truth.
+"""Property: the default comb accumulation (ops/comb._accumulate_chains,
+K parallel chains of mixed additions; ``tree=True``) is bit-identical to
+the sequential comb path AND to the Straus fallback kernel on randomized
+vectors — including non-signer zero rows and ZIP-215 edge encodings —
+with the pure-Python host verifier as ground truth.  (The name of this
+file is the older fold's, a binary tree over all 87 points; the fast
+tier's tests/test_comb_chains.py holds the per-K checks.)
 
-The tree path is the engine default (COMETBFT_TPU_COMB_TREE); the
+The chains are the engine default (COMETBFT_TPU_COMB_TREE); the
 sequential fori_loop path is kept exactly as the cross-check this module
 runs.  The mesh-sharded program runs the same verify_cached body
 (parallel/verify.sharded_verify_cached) and is cross-checked in a fresh
 interpreter by tests/test_parallel.py::test_sharded_comb_path_matches_host
-(tests/sharded_comb_check.py), which exercises the default (tree) path.
+(tests/sharded_comb_check.py), which exercises the default path.
 """
 
 import hashlib

@@ -345,6 +345,36 @@ def test_comb_tree_fold_uncarried_lift_overflows():
     ), findings
 
 
+@pytest.mark.parametrize(
+    "t2d_range", [(0, 4095), (-4095, 0)], ids=["canonical", "sign_flipped"]
+)
+def test_comb_chain_step_takes_maximal_table_entries(t2d_range):
+    """What the comb's chains do at every step (PR 30): E.add_niels of a
+    TIGHT accumulator with a table entry as it is, no lift and no carry.
+    The adversarial entry is every limb at its canonical maximum, in the
+    stored form and in the sign-flipped one the lookup makes of it
+    ((y-x, y+x, -2dxy)); both must prove overflow-free, and the bounds
+    the interpreter gives the step's outputs must carry a second step to
+    outputs no wider: the chain's loop invariant."""
+    from cometbft_tpu.ops import ed25519 as E
+
+    tight = np.full((22, 4), 2051, np.int64)
+    tight[0] = 3584
+    coord = lambda: rc.IVal(-tight, tight.copy(), np.dtype(np.int32))
+
+    def two_steps(x, y, z, t, yplusx, yminusx, t2d):
+        n = E.Niels(yplusx, yminusx, t2d)
+        first = E.add_niels(E.Point(x, y, z, t), n)
+        return tuple(first) + tuple(E.add_niels(first, n))
+
+    entry = [_iv(0, 4095, (22, 4)), _iv(0, 4095, (22, 4)),
+             _iv(*t2d_range, (22, 4))]
+    findings, outs, _ = _interp(two_steps, [coord() for _ in range(4)] + entry)
+    assert findings == [], findings
+    for first, second in zip(outs[:4], outs[4:]):
+        assert (second.lo >= first.lo).all() and (second.hi <= first.hi).all()
+
+
 # ------------------------------------------------------- certificates
 
 
