@@ -67,7 +67,7 @@ class Kernel:
     must produce.
 
     fn            : "package.module:function".  With needs_mesh, the
-                    function is a FACTORY taking (mesh, *mesh_static)
+                    function is a FACTORY taking the mesh
                     and returning the jitted callable (the
                     parallel/verify.py pattern).
     args          : canonical input leaves, in call order.
@@ -76,10 +76,9 @@ class Kernel:
                     shape/dtype drift fails before any fingerprint
                     comparison.
     static_kwargs : Python-level keyword arguments bound before tracing
-                    (trace-time constants: the comb tree flag, churn V);
+                    (trace-time constants: the secp glv flag, churn V);
                     with needs_mesh they are bound onto the factory call.
     needs_mesh    : build a 1-device CPU mesh and call fn as a factory.
-    mesh_static   : extra factory positionals after the mesh.
     max_eqns      : compile-cost budget — hard ceiling on the traced
                     jaxpr's total equation count (nested bodies
                     included).  EVERY production kernel must declare a
@@ -116,7 +115,6 @@ class Kernel:
     out: tuple[Arg, ...]
     static_kwargs: tuple[tuple[str, object], ...] = ()
     needs_mesh: bool = False
-    mesh_static: tuple = ()
     max_eqns: int = 0  # fixture rows may omit; production rows may not
     arg_ranges: tuple | None = None
     out_ranges: tuple | None = None
@@ -145,25 +143,13 @@ KERNELS: tuple[Kernel, ...] = (
         out_ranges=(DIGITS, None),
     ),
     Kernel(
-        name="comb_verify_cached_tree",
+        name="comb_verify_cached",
         fn="cometbft_tpu.ops.comb:verify_cached",
         args=(_TABLES, boolean(V), u8(V, 32), u8(V, 32), u8(V, 64), _B_TABLES),
         out=(boolean(V),),
-        static_kwargs=(("tree", True),),
-        # the default path: K parallel add_niels chains in one rolled
+        # the accumulation: K parallel add_niels chains in one rolled
         # scan (K = 8 at the 4-lane trace)
         max_eqns=39_000,  # measured 29,892
-        arg_ranges=(DIGITS, None, None, None, None, DIGITS),
-    ),
-    Kernel(
-        # the sequential cross-check path must stay pinned too: it is the
-        # bit-exactness witness for the chains (COMETBFT_TPU_COMB_TREE=0)
-        name="comb_verify_cached_seq",
-        fn="cometbft_tpu.ops.comb:verify_cached",
-        args=(_TABLES, boolean(V), u8(V, 32), u8(V, 32), u8(V, 64), _B_TABLES),
-        out=(boolean(V),),
-        static_kwargs=(("tree", False),),
-        max_eqns=36_000,  # measured 27,633
         arg_ranges=(DIGITS, None, None, None, None, DIGITS),
     ),
     # ---- ops/ed25519.py — the uncached Straus kernel
@@ -277,7 +263,7 @@ KERNELS: tuple[Kernel, ...] = (
     # program.  The G window table is host-precomputed and
     # device_put-resident (PR-11 pattern: never a table-build compile),
     # passed as the last tensor argument.  TWO static axes, each the
-    # COMB_TREE witness pattern: ``glv`` selects the GLV endomorphism
+    # default-and-witness pattern: ``glv`` selects the GLV endomorphism
     # quad-scalar walk over 33 windows (True, the default) vs the plain
     # 66-window Shamir chain (False, the bit-exactness witness —
     # COMETBFT_TPU_SECP_GLV=0), and ``recover`` adds the ecrecover
@@ -431,7 +417,6 @@ KERNELS: tuple[Kernel, ...] = (
         args=(_TABLES, boolean(V), u8(V, 32), u8(V, PAYLOAD_W)),
         out=(u8(2),),
         needs_mesh=True,
-        mesh_static=(True,),  # tree=True, part of the jit cache key
         max_eqns=39_500,  # measured 30,341
         arg_ranges=(DIGITS, None, None, None),
     ),

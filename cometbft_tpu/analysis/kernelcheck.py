@@ -67,32 +67,6 @@ _FORBIDDEN_PRIMS = frozenset(
 _FORBIDDEN_PRIM_SUBSTRINGS = ("callback",)
 
 
-# Knobs that are read at TRACE time and change the traced program.  The
-# checker unsets them while tracing so the checked-in fingerprints always
-# describe the DEFAULT program, whatever the ambient environment
-# (models/comb_verifier._device_verify resolves comb.tree_enabled() during
-# its trace; a stray COMETBFT_TPU_COMB_TREE=0 would silently regenerate
-# the sequential-path fingerprint).
-_TRACE_ENV_PINS = ("COMETBFT_TPU_COMB_TREE",)
-
-
-class _pinned_trace_env:
-    """Context manager: default trace environment for deterministic
-    fingerprints; restores whatever the caller had on exit."""
-
-    def __enter__(self):
-        self._saved = {k: os.environ.pop(k, None) for k in _TRACE_ENV_PINS}
-        return self
-
-    def __exit__(self, *exc):
-        for k, v in self._saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        return False
-
-
 def _ensure_cpu_backend() -> None:
     """Force the CPU backend when jax has not been imported yet — even
     over an ambient JAX_PLATFORMS=tpu: the gate must run (and stay
@@ -148,9 +122,7 @@ def _resolve(kernel: manifest.Kernel):
     if kernel.needs_mesh:
         from ..parallel.mesh import make_mesh
 
-        return fn(
-            make_mesh(1), *kernel.mesh_static, **dict(kernel.static_kwargs)
-        )
+        return fn(make_mesh(1), **dict(kernel.static_kwargs))
     if kernel.static_kwargs:
         return functools.partial(fn, **dict(kernel.static_kwargs))
     return fn
@@ -204,9 +176,8 @@ def trace_kernel(kernel: manifest.Kernel) -> Trace:
                                 f"[{kernel.name}] {msg}"))
 
     try:
-        with _pinned_trace_env():
-            fn = _resolve(kernel)
-            closed = jax.make_jaxpr(fn)(*_arg_structs(kernel))
+        fn = _resolve(kernel)
+        closed = jax.make_jaxpr(fn)(*_arg_structs(kernel))
     except Exception as e:  # noqa: BLE001 - a kernel that fails to trace IS the finding
         add(f"failed to trace: {type(e).__name__}: {e}")
         return Trace(kernel, UNTRACEABLE_SIG, {}, findings)
@@ -431,7 +402,7 @@ def run_check(
     ``allowlist`` (an :class:`analysis.linter.Allowlist`) filters the
     findings when given.  The default is raw so callers that do their
     own allowlist bookkeeping (scripts/lint.py tracks stale entries)
-    see every finding exactly once; standalone consumers (bench.py)
+    see every finding exactly once; standalone consumers (the tests)
     pass :func:`default_allowlist` so a justified entry reads green
     everywhere the gate does."""
     traces = [trace_kernel(k) for k in (kernels or manifest.KERNELS)]
@@ -464,8 +435,7 @@ def regenerate(fingerprints_path: str = FINGERPRINTS_PATH) -> tuple[list[Finding
 
 
 def summary(findings: list[Finding], traces: list[Trace]) -> dict:
-    """Machine-readable result (bench.py embeds this when the device
-    backend is unavailable, so a bench round still carries signal)."""
+    """Machine-readable result: ``kernel`` in ``lint.py --json``."""
     return {
         "ok": not findings,
         "kernels": len(traces),
@@ -473,8 +443,7 @@ def summary(findings: list[Finding], traces: list[Trace]) -> dict:
             sum(t.primitives.values()) for t in traces
         ),
         # per-kernel eqn counts next to their budgets: the acceptance
-        # surface for "the table path fits the budget" on backend-less
-        # rounds (bench.py embeds this summary)
+        # surface for "the table path fits the budget"
         "eqns": {
             t.kernel.name: {"eqns": t.eqns, "max_eqns": t.kernel.max_eqns}
             for t in traces

@@ -1918,15 +1918,14 @@ def _headroom_bits(peak: int, limit: int) -> float:
 
 def _trace_closed(kernel):
     """The kernel's ClosedJaxpr under the PR-4 deterministic trace
-    environment (CPU backend pinned, trace-time knobs unset)."""
+    environment (CPU backend pinned)."""
     from . import kernelcheck
 
     kernelcheck._ensure_cpu_backend()
     import jax
 
-    with kernelcheck._pinned_trace_env():
-        fn = kernelcheck._resolve(kernel)
-        return jax.make_jaxpr(fn)(*kernelcheck._arg_structs(kernel))
+    fn = kernelcheck._resolve(kernel)
+    return jax.make_jaxpr(fn)(*kernelcheck._arg_structs(kernel))
 
 
 def check_kernel(kernel) -> RangeReport:
@@ -2159,8 +2158,7 @@ def regenerate(
 
 
 def summary(findings: list[Finding], reports: list) -> dict:
-    """Machine-readable result (bench.py embeds this on backend-less
-    rounds next to the kernelcheck/shardcheck summaries)."""
+    """Machine-readable result: ``range`` in ``lint.py --json``."""
     return {
         "ok": not findings,
         "kernels": len(reports),
@@ -2178,55 +2176,6 @@ def summary(findings: list[Finding], reports: list) -> dict:
             {"check": f.check, "path": f.path, "message": f.message}
             for f in findings
         ],
-    }
-
-
-#: The fast hash-plane subset a bench round can afford to re-interpret
-#: live (each under a second; the field kernels are minutes of CPU).
-SPOT_KERNELS = (
-    "sha256_blocks",
-    "sha512_blocks",
-    "keccak256_blocks",
-    "merkle_root_from_leaves",
-)
-
-
-def bench_summary(spot_kernels=SPOT_KERNELS) -> dict:
-    """Certificate-backed summary for bench embedding.
-
-    The full interval pass is minutes of CPU (the ed25519/secp walks
-    dominate), far over a bench round's patience, so headroom comes from
-    the checked-in certificates; a LIVE spot-check re-interprets the
-    hash-plane subset and diffs it against the same certificates, so a
-    drifted tree still trips the round's ok bit."""
-    golden = load_fingerprints()
-    spot = [k for k in km.KERNELS if k.name in set(spot_kernels)]
-    findings, reports = run_check(
-        kernels=spot, allowlist=default_allowlist()
-    )
-    certs_ok = bool(golden) and all(
-        v.get("ok") and not v.get("findings") for v in golden.values()
-    )
-    return {
-        "ok": certs_ok and not findings,
-        "mode": "certificates+spot",
-        "certificates": len(golden),
-        "certificates_ok": certs_ok,
-        "spot_kernels": [k.name for k in spot],
-        "spot_findings": [
-            {"check": f.check, "path": f.path, "message": f.message}
-            for f in findings
-        ],
-        "headroom": {
-            name: {
-                "ok": v.get("ok"),
-                "peak_int32": v.get("peak_int32"),
-                "peak_f32": v.get("peak_f32"),
-                "headroom_int32_bits": v.get("headroom_int32_bits"),
-                "headroom_f32_bits": v.get("headroom_f32_bits"),
-            }
-            for name, v in sorted(golden.items())
-        },
     }
 
 

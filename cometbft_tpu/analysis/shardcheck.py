@@ -56,7 +56,7 @@ import time
 from dataclasses import dataclass, field
 
 from . import kernel_manifest as manifest
-from .kernelcheck import UNTRACEABLE_SIG, _aval_str, _pinned_trace_env, _walk_jaxprs
+from .kernelcheck import UNTRACEABLE_SIG, _aval_str, _walk_jaxprs
 from .linter import Finding
 
 SHARD_FINGERPRINTS_PATH = os.path.join(
@@ -177,7 +177,7 @@ def _resolve_sharded(sk: manifest.ShardedKernel, row: manifest.Kernel, mesh):
 
     mod_name, _, fn_name = row.fn.partition(":")
     fn = getattr(importlib.import_module(mod_name), fn_name)
-    return fn(mesh, *row.mesh_static, **dict(row.static_kwargs))
+    return fn(mesh, **dict(row.static_kwargs))
 
 
 def _loop_depth(jaxpr) -> int:
@@ -256,9 +256,8 @@ def trace_sharded(
         ]
 
     try:
-        with _pinned_trace_env():
-            fn = _resolve_sharded(sk, row, mesh)
-            closed = jax.make_jaxpr(fn)(*structs())
+        fn = _resolve_sharded(sk, row, mesh)
+        closed = jax.make_jaxpr(fn)(*structs())
     except Exception as e:  # noqa: BLE001 - failing to trace IS the finding
         add(f"failed to trace under the {mesh.devices.size}-way mesh: "
             f"{type(e).__name__}: {e}")
@@ -649,8 +648,7 @@ def regenerate(
 
 
 def summary(findings: list[Finding], traces: list[ShardTrace]) -> dict:
-    """Machine-readable result (bench.py embeds this on backend-less
-    rounds, the same pattern as the PR-4 "kernelcheck" field)."""
+    """Machine-readable result: ``sharding`` in ``lint.py --json``."""
     return {
         "ok": not findings,
         "kernels": {

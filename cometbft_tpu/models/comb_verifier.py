@@ -829,18 +829,15 @@ class CombBatchVerifier:
 
             # the schedule is fixed when the program is traced
             hub.comb_fold_chains.set(
-                comb.fold_chains(e.vpad) if comb.tree_enabled() else 1,
-                lanes=str(e.vpad),
+                comb.fold_chains(e.vpad), lanes=str(e.vpad)
             )
         return prog
 
 
 def _program_key(entry: _CacheEntry, width: int) -> tuple:
-    """What the single-device program depends on: lanes, payload width
-    and the accumulation path its trace resolves (ops/comb.tree_enabled)."""
-    from ..ops import comb
-
-    return (entry.vpad, width, comb.tree_enabled())
+    """What the single-device program depends on: lanes and payload
+    width."""
+    return (entry.vpad, width)
 
 
 def _device_verify(tables, valid, pubs, payload):
@@ -850,10 +847,7 @@ def _device_verify(tables, valid, pubs, payload):
     Returns ONE uint8 array [packbits(ok & live) | all_ok] so the caller
     pays a single device->host fetch.
 
-    Manifest kernel ``comb_device_verify``.  The trace resolves
-    comb.tree_enabled() (the kernelcheck gate pins the knob to its
-    default while fingerprinting, so goldens always describe the tree
-    path).
+    Manifest kernel ``comb_device_verify``.
     """
     import jax
     import jax.numpy as jnp

@@ -69,16 +69,6 @@ ECR_SIG = host_eth.SIGNATURE_SIZE  # 65: R || S || V
 
 _MISS = object()
 
-# Phase attribution of the LAST device dispatch: "*_ms" keys
-# (hash / decode / assembly / h2d / kernel / fetch) plus rows /
-# hash_device / glv markers.  Overwritten on every device dispatch —
-# consumed by bench.py (BENCH_WORKLOAD=secp phase_attribution) and
-# scripts/profile_secp_phases.py, which run one dispatch at a time, so
-# no thread merging.  ``hash_ms`` is the HOST side of hashing: the
-# digest loop on the host-hash path, just the block padding on the
-# fused path (the digests themselves then ride inside kernel_ms).
-LAST_PHASES: dict[str, float] = {}
-
 # pubkey bytes -> affine (x, y) int pair | None (malformed encoding).
 # Decoding a compressed key costs one field sqrt (~pow mod p); CheckTx
 # ingest repeats senders, so the fact caches like the BLS lane's.
@@ -194,7 +184,6 @@ def _verify_items(items, use_device: bool) -> tuple[bool, list[bool]]:
         and all(len(msg) <= hmax for (_, msg, _) in items)
     )
     msgs: list[bytes] = [b""] * b
-    phases = {"decode_ms": 0.0, "hash_ms": 0.0}
 
     qxs, qys, es, rs, ss, rows = [], [], [], [], [], []
     for i, (pub, msg, sig) in enumerate(items):
@@ -208,9 +197,7 @@ def _verify_items(items, use_device: bool) -> tuple[bool, list[bool]]:
             aff = (0, 0)
             addr[i] = np.frombuffer(pub, dtype=np.uint8)
         else:
-            td = _time.perf_counter()
             aff = _decode_pub(pub)
-            phases["decode_ms"] += (_time.perf_counter() - td) * 1e3
             # the signature wire shape must match the KEY's wire format
             # — the host modules' own length gate
             sig_len = ETH_SIG if eth else COSMOS_SIG
@@ -224,9 +211,7 @@ def _verify_items(items, use_device: bool) -> tuple[bool, list[bool]]:
         if hash_dev:
             msgs[i] = msg
         else:
-            th = _time.perf_counter()
             h = keccak256(msg) if (eth or rec) else hashlib.sha256(msg).digest()
-            phases["hash_ms"] += (_time.perf_counter() - th) * 1e3
             es.append(int.from_bytes(h, "big"))
         qxs.append(aff[0])
         qys.append(aff[1])
@@ -244,9 +229,6 @@ def _verify_items(items, use_device: bool) -> tuple[bool, list[bool]]:
     m = _mhub()
     assembly_s = _time.perf_counter() - t0
     m.verify_phase_seconds.observe(assembly_s, phase="secp_assembly")
-    phases["assembly_ms"] = (
-        assembly_s * 1e3 - phases["decode_ms"] - phases["hash_ms"]
-    )
     t1 = _time.perf_counter()
     with tracing.span(
         "verify.secp_batch",
@@ -257,32 +239,25 @@ def _verify_items(items, use_device: bool) -> tuple[bool, list[bool]]:
             from ..ops import keccak as kops
             from ..ops import sha2 as sops
 
-            tp = _time.perf_counter()
             sha_blocks, sha_active = sops.pad_messages_sha256(
                 msgs, max_len=hmax
             )
             kec_blocks, kec_active = kops.pad_messages_keccak(
                 msgs, max_len=hmax
             )
-            phases["hash_ms"] += (_time.perf_counter() - tp) * 1e3
             ok = dev.hash_verify_batch_device(
                 sha_blocks, sha_active, kec_blocks, kec_active,
                 qx, qy, valid, r, s, is_eth, v,
-                is_rec=is_rec, addr=addr, glv=glv, timings=phases,
+                is_rec=is_rec, addr=addr, glv=glv,
             )
         else:
             ok = dev.verify_batch_device(
                 qx, qy, valid, e, r, s, is_eth, v,
-                is_rec=is_rec, addr=addr, glv=glv, timings=phases,
+                is_rec=is_rec, addr=addr, glv=glv,
             )
     m.verify_phase_seconds.observe(
         _time.perf_counter() - t1, phase="secp_device"
     )
-    phases["rows"] = float(n)
-    phases["hash_device"] = 1.0 if hash_dev else 0.0
-    phases["glv"] = 1.0 if glv else 0.0
-    LAST_PHASES.clear()
-    LAST_PHASES.update(phases)
     res = [bool(x) for x in ok[:n]]
     return (all(res) and bool(res), res)
 

@@ -461,18 +461,6 @@ def _b_tables_cached() -> np.ndarray:
 # ------------------------------------------------------------ verification
 
 
-def tree_enabled() -> bool:
-    """COMETBFT_TPU_COMB_TREE = "0" selects the sequential fori_loop
-    accumulation (the cross-check path); anything else (default) the
-    parallel chains of mixed additions.  Read at TRACE time: programs
-    already compiled keep the path they were traced with, so flip the
-    flag before the first verify of a process (or use a fresh jit
-    wrapper)."""
-    from ..utils import envknobs
-
-    return envknobs.get_bool(envknobs.COMB_TREE)
-
-
 NPART = NPOS_A + NPOS_B  # Niels partials a lane sums, before -R
 
 # For each K, the widest batch (K chains x lanes) of one field
@@ -501,18 +489,7 @@ def fold_chains(lanes: int) -> int:
     return 1
 
 
-def accumulation_depth(lanes: int) -> int:
-    """Dependent point-add rounds in the active accumulation path at a
-    lane count: the number the profile/bench scripts report.  Chains:
-    ceil(86 / K) mixed additions, then the fold of K accumulators and
-    -R; sequential: one add per position plus the R fold."""
-    if not tree_enabled():
-        return NPART + 1  # 87 dependent adds
-    k = fold_chains(lanes)
-    return -(-NPART // k) + k.bit_length()  # ceil(log2(k + 1)) fold rounds
-
-
-def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None):
+def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables):
     """Batched cofactored verification against cached comb tables.
 
     tables   : (64, 9, 3, 22, V) int32 — build_a_tables output
@@ -521,16 +498,11 @@ def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None
     s_bytes  : (V, 32) uint8 — signature s halves
     k_digest : (V, 64) uint8 — SHA-512(R || A || M)
     b_tables : (22, 66, 4096) f32 — get_b_tables()
-    tree     : None (resolve tree_enabled() at trace time) or a Python
-               bool; close over it rather than passing through jit args.
 
     Returns (V,) bool.  Rows whose validator did not sign carry dummy
     inputs; callers mask the result.
 
-    Manifest kernels ``comb_verify_cached_tree`` / ``_seq`` (one per
-    accumulation path — both fingerprints are pinned, since the
-    sequential path is the chains' bit-exactness witness; ``_tree``
-    names the default path, the parallel chains).  As the
+    Manifest kernel ``comb_verify_cached``.  As the
     shard_map body of ``sharded_verify_cached`` this must stay
     lane-local over the validator axis: any collective it grows is
     caught by the sharded census (analysis/shardcheck,
@@ -552,11 +524,8 @@ def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None
     with jax.named_scope("decompress"):
         r_pt, r_valid = E.decompress(r_enc)
 
-    if tree is None:
-        tree = tree_enabled()
-    acc_fn = _accumulate_chains if tree else _accumulate_sequential
     with jax.named_scope("scalar_mul"):
-        acc = acc_fn(tables, k_dig, s_dig, b_tables, r_pt)
+        acc = _accumulate_chains(tables, k_dig, s_dig, b_tables, r_pt)
 
     # ---- clear cofactor, check identity
     with jax.named_scope("final_check"):
@@ -564,59 +533,10 @@ def verify_cached(tables, a_valid, r_enc, s_bytes, k_digest, b_tables, tree=None
         return E.is_identity(acc) & a_valid & r_valid & s_ok
 
 
-def _accumulate_sequential(tables, k_dig, s_dig, b_tables, r_pt):
-    """The original accumulation: 64 + 22 dependent position adds in two
-    fori_loops, then the R fold — an 87-step serial chain, 612 field
-    muls a lane.  Kept as the bit-exact cross-check for the chains
-    (COMETBFT_TPU_COMB_TREE=0)."""
-    V = k_dig.shape[-1]
-
-    # ---- A part: acc += T[i][|k_i|][v] (sign-adjusted), 64 adds
-    ents_a = jnp.arange(NENT_A, dtype=jnp.int32)[:, None]
-
-    def a_body(i, acc):
-        slab = lax.dynamic_index_in_dim(tables, i, axis=0, keepdims=False)
-        dig = lax.dynamic_index_in_dim(k_dig, i, axis=0, keepdims=False)
-        neg = dig < 0
-        absd = jnp.abs(dig)
-        # int32 one-hot: the select stays in the tables' own dtype end to
-        # end (no float round trip; dtype-closure audited, no promotion)
-        onehot = (ents_a == absd[None, :]).astype(jnp.int32)  # (9, V)
-        sel = jnp.sum(slab * onehot[:, None, None, :], axis=0)  # (3, 22, V)
-        yplusx = F.select(neg, sel[1], sel[0])
-        yminusx = F.select(neg, sel[0], sel[1])
-        t2d = F.select(neg, -sel[2], sel[2])
-        return E.add_niels(acc, E.Niels(yplusx, yminusx, t2d))
-
-    with jax.named_scope("comb_lookup_a"):
-        acc = lax.fori_loop(0, NPOS_A, a_body, E.identity((V,)))
-
-    # ---- B part: acc += B_TAB[i][:, s_i], 22 adds, MXU one-hot matmul
-    ents_b = jnp.arange(NENT_B, dtype=jnp.int32)[:, None]
-
-    def b_body(i, acc):
-        slab = lax.dynamic_index_in_dim(b_tables, i, axis=0, keepdims=False)
-        dig = lax.dynamic_index_in_dim(s_dig, i, axis=0, keepdims=False)
-        onehot = (ents_b == dig[None, :]).astype(jnp.float32)  # (4096, V)
-        # HIGHEST: the TPU MXU default is bf16 passes (8 mantissa bits);
-        # the Niels limbs are 12-bit values and must come through exact.
-        sel = jnp.matmul(
-            slab, onehot, precision=lax.Precision.HIGHEST
-        ).astype(jnp.int32)  # (66, V)
-        return E.add_niels(
-            acc, E.Niels(sel[0:22], sel[22:44], sel[44:66])
-        )
-
-    with jax.named_scope("comb_lookup_b"):
-        acc = lax.fori_loop(0, NPOS_B, b_body, acc)
-    return E.add(acc, E.neg(r_pt))
-
-
 def _lookup_partials(tables, k_dig, s_dig, b_tables):
     """Every position's partial point at once, in Niels form: the 64
     sign-adjusted A selections and the 22 B selections, coords
-    (64, 22, V) and (22, 22, V).  The selects do the same total work as
-    the sequential loops but carry no loop dependence."""
+    (64, 22, V) and (22, 22, V).  The selects carry no loop dependence."""
     # ---- A part: all 64 sign-adjusted selections in one shot
     with jax.named_scope("comb_lookup_a"):
         neg_d = k_dig < 0
@@ -633,8 +553,8 @@ def _lookup_partials(tables, k_dig, s_dig, b_tables):
         )
 
     # ---- B part: 22 independent one-hot MXU matmuls (no add chain);
-    # unrolled so each keeps the (4096, V) onehot transient of the
-    # sequential path instead of one (22, 4096, V) monster
+    # unrolled so each keeps a (4096, V) onehot transient instead of one
+    # (22, 4096, V) monster
     # f32 one-hot for the MXU path: int32 -> float32 -> int32 is exact
     # for the 12-bit Niels limbs (both conversions are in the manifest's
     # justified ALLOWED_CONVERSIONS set; HIGHEST forbids bf16 passes)
@@ -662,8 +582,8 @@ def _accumulate_chains(tables, k_dig, s_dig, b_tables, r_pt, chains=None):
     dependent E.add_niels steps (7 field muls each, one rolled body),
     and the K accumulators fold with -R by unified additions
     (E.tree_reduce_points, ten muls each).  Field muls a lane: K = 1:
-    612 (the sequential path's count), 2: 622, 4: 656, 8: 696, against
-    946 for lifting all 86 and folding them by unified additions.  K
+    612 (one add_niels a position and the R fold, the fewest), 2: 622,
+    4: 656, 8: 696.  K
     comes from the lane count (fold_chains); chains overrides it for
     tests and sweeps only.
     """

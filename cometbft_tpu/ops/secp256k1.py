@@ -35,7 +35,7 @@ The ECDSA batch (one fused program per bucket shape):
   inverse (pinned by tests/test_secp_ops.py).
 * **GLV quad-scalar multiplication** (the default; ``glv=False`` keeps
   the plain Shamir chain as the bit-exactness witness, the PR-1
-  ``COMB_TREE`` pattern) — u1*G + u2*Q with one shared doubling chain.
+  witness pattern) — u1*G + u2*Q with one shared doubling chain.
   The secp256k1 endomorphism phi(x, y) = (beta*x, y) acts as
   multiplication by lambda (a cube root of 1 mod n), so each scalar
   splits as k = k1 + lambda*k2 with |k1|, |k2| < ~2^129 (lattice
@@ -887,7 +887,7 @@ def verify_batch(
               constant
     glv     : trace-time: GLV quad-scalar walk (default) vs the plain
               Shamir witness walk — bit-identical by contract
-              (tests/test_secp_glv.py), knob-selected like COMB_TREE
+              (tests/test_secp_glv.py), by COMETBFT_TPU_SECP_GLV
     recover : trace-time: compile the R-lift sqrt chain + the on-device
               address Keccak.  False keeps verify-only batches on a
               program that never pays for either
@@ -1079,7 +1079,7 @@ def _rec_defaults(b: int, is_rec, addr):
 
 def verify_batch_device(
     qx, qy, q_valid, e, r, s, is_eth, v,
-    is_rec=None, addr=None, glv=True, timings=None,
+    is_rec=None, addr=None, glv=True,
 ) -> np.ndarray:
     """One device dispatch of the batched ECDSA kernel over pre-packed
     host arrays; the blocking result fetch is this bridge's declared
@@ -1087,11 +1087,7 @@ def verify_batch_device(
 
     The ``recover`` trace flag is derived here: batches without
     ecrecover rows ride the cheaper program (no sqrt chain, no address
-    Keccak).  When ``timings`` is a dict the bridge splits its wall
-    time into h2d / kernel / fetch milliseconds (additive — repeated
-    dispatches accumulate) for the bench/profiler phase attribution."""
-    import time
-
+    Keccak)."""
     import jax
 
     global _VERIFY_JIT
@@ -1102,7 +1098,6 @@ def verify_batch_device(
                     verify_batch, static_argnames=("glv", "recover")
                 )
     is_rec, addr = _rec_defaults(qx.shape[0], is_rec, addr)
-    t0 = time.perf_counter()
     dev_args = (
         jnp.asarray(qx),
         jnp.asarray(qy),
@@ -1116,33 +1111,19 @@ def verify_batch_device(
         jnp.asarray(addr),
         g_table(),
     )
-    t1 = time.perf_counter()
     ok = _VERIFY_JIT(
         *dev_args, glv=bool(glv), recover=bool(np.any(is_rec))
     )
-    ok.block_until_ready()
-    t2 = time.perf_counter()
-    out = np.asarray(ok)
-    if timings is not None:
-        t3 = time.perf_counter()
-        timings["h2d_ms"] = timings.get("h2d_ms", 0.0) + (t1 - t0) * 1e3
-        timings["kernel_ms"] = (
-            timings.get("kernel_ms", 0.0) + (t2 - t1) * 1e3
-        )
-        timings["fetch_ms"] = timings.get("fetch_ms", 0.0) + (t3 - t2) * 1e3
-    return out
+    return np.asarray(ok)
 
 
 def hash_verify_batch_device(
     sha_blocks, sha_active, kec_blocks, kec_active,
     qx, qy, q_valid, r, s, is_eth, v,
-    is_rec=None, addr=None, glv=True, timings=None,
+    is_rec=None, addr=None, glv=True,
 ) -> np.ndarray:
     """The fused hash->verify dispatch (device-resident hashing); same
-    collect-point and ``timings`` contract as
-    :func:`verify_batch_device`."""
-    import time
-
+    collect-point contract as :func:`verify_batch_device`."""
     import jax
 
     global _HASH_VERIFY_JIT
@@ -1153,7 +1134,6 @@ def hash_verify_batch_device(
                     hash_verify_batch, static_argnames=("glv", "recover")
                 )
     is_rec, addr = _rec_defaults(qx.shape[0], is_rec, addr)
-    t0 = time.perf_counter()
     dev_args = (
         jnp.asarray(sha_blocks),
         jnp.asarray(sha_active),
@@ -1170,18 +1150,7 @@ def hash_verify_batch_device(
         jnp.asarray(addr),
         g_table(),
     )
-    t1 = time.perf_counter()
     ok = _HASH_VERIFY_JIT(
         *dev_args, glv=bool(glv), recover=bool(np.any(is_rec))
     )
-    ok.block_until_ready()
-    t2 = time.perf_counter()
-    out = np.asarray(ok)
-    if timings is not None:
-        t3 = time.perf_counter()
-        timings["h2d_ms"] = timings.get("h2d_ms", 0.0) + (t1 - t0) * 1e3
-        timings["kernel_ms"] = (
-            timings.get("kernel_ms", 0.0) + (t2 - t1) * 1e3
-        )
-        timings["fetch_ms"] = timings.get("fetch_ms", 0.0) + (t3 - t2) * 1e3
-    return out
+    return np.asarray(ok)

@@ -131,7 +131,7 @@ def sharded_verify_batch(mesh: Mesh, a_enc, r_enc, s_bytes, msg_blocks, msg_acti
         return _verify_fn(mesh)(a_enc, r_enc, s_bytes, msg_blocks, msg_active)
 
 
-def _comb_verify_fn(mesh: Mesh, tree: bool):
+def _comb_verify_fn(mesh: Mesh):
     """Sharded comb-cached commit verification — the engine's production
     path (models/comb_verifier.py) over a device mesh.
 
@@ -143,11 +143,6 @@ def _comb_verify_fn(mesh: Mesh, tree: bool):
     A 10k-validator set's 1.5 GB of tables become ~190 MB per chip on an
     8-chip mesh — the component that most needs sharding.
 
-    tree selects the accumulation path (ops/comb tree_enabled) and is
-    part of the cache key, so flipping COMETBFT_TPU_COMB_TREE between
-    calls never serves a stale compiled program.  Both paths are
-    lane-local over the validator axis, so sharding is unaffected.
-
     The per-call payload rows are DONATED (donate_argnums=(3,)): the
     staging buffer's device copy is consumed by the dispatch and its HBM
     is reusable for the outputs — host code must never touch the device
@@ -157,9 +152,9 @@ def _comb_verify_fn(mesh: Mesh, tree: bool):
     contract keep it that way).  Tables/valid/pubs persist across calls
     in the cache entry and are never donated.
 
-    Manifest kernel ``sharded_verify_cached`` (traced with tree=True).
+    Manifest kernel ``sharded_verify_cached``.
     """
-    key = ("verify_cached", mesh_cache_key(mesh), "tree" if tree else "seq")
+    key = ("verify_cached", mesh_cache_key(mesh))
     cached = _cached_program(key)
     if cached is not None:
         return cached
@@ -173,7 +168,7 @@ def _comb_verify_fn(mesh: Mesh, tree: bool):
     def local(tables, valid, pubs, payload):
         r, s, blocks, active, live = sha2.parse_verify_payload(payload, pubs)
         dig = sha2.sha512_blocks(blocks, active)
-        ok = comb.verify_cached(tables, valid, r, s, dig, bt, tree=tree)
+        ok = comb.verify_cached(tables, valid, r, s, dig, bt)
         bad = jnp.sum((~(ok | ~live)).astype(jnp.int32))
         total_bad = jax.lax.psum(bad, axis)
         ok_all = jax.lax.all_gather(ok & live, axis, tiled=True)
@@ -230,15 +225,11 @@ def sharded_verify_cached(mesh: Mesh, tables, valid, pubs, payload):
     on its cache entry), stage the donated value inline in the call
     expression — never bind it — as stage() does.
     """
-    from ..ops import comb
-
     with tracing.span(
         "verify.shard_dispatch",
         {"devices": int(mesh.devices.size)} if tracing.enabled() else None,
     ):
-        return _comb_verify_fn(mesh, comb.tree_enabled())(
-            tables, valid, pubs, payload
-        )
+        return _comb_verify_fn(mesh)(tables, valid, pubs, payload)
 
 
 def _merkle_fn(mesh: Mesh):

@@ -3,8 +3,8 @@
 Cold compiles are the large part of a cold start here (the fused
 Ed25519 kernel is minutes on the CPU backend; the 10,000-validator comb
 table build and verify programs are the large part of a first run on a
-TPU), so every entry point — ``python -m cometbft_tpu``, ``bench.py``,
-``chip_smoke.py``, the test suite, the profile scripts — calls
+TPU), so every entry point — ``python -m cometbft_tpu``,
+``benchmarks/run.py``, ``chip_smoke.py``, the test suite — calls
 :func:`enable` before its first compile.  The rule:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: jax reads the variable by itself,

@@ -77,11 +77,6 @@ COMB_HOST_BUILD_MAX = _declare(
     "compile.  Bigger builds use the scan-rolled jitted kernel (persistent "
     "compile cache amortizes it).  0 = always the device kernel.",
 )
-COMB_TREE = _declare(
-    "COMETBFT_TPU_COMB_TREE", "bool", True,
-    "`0` selects the sequential fori_loop comb accumulation (the bit-exact "
-    "cross-check path) instead of the parallel chains of mixed additions.",
-)
 BTAB_CACHE = _declare(
     "COMETBFT_TPU_BTAB_CACHE", "str", "",
     "Path (`.npy` appended if missing) disk-caching the constant "
@@ -629,7 +624,8 @@ def to_markdown() -> str:
         "docs/knobs.md`.  Every `COMETBFT_TPU_*` knob is declared in that "
         "registry and read through its typed getters; the static linter "
         "(`scripts/lint.py`, check `raw-env-read`) rejects reads anywhere "
-        "else, so this table is the complete inventory.",
+        f"else, so this table of {len(all_knobs())} knobs is the complete "
+        "inventory.",
         "",
         "| Knob | Type | Default | Description |",
         "|---|---|---|---|",

@@ -13,7 +13,7 @@ scheduler loops, and hung consensus routines first-class signals:
   imported JAX holds (or is about to hold) the chip, so it probes
   in-process: one trivial computation on its own default device, run on
   a worker thread and judged from outside with a hard deadline.  A
-  process that has not imported JAX (``bench.py`` before it attaches)
+  process that has not imported JAX (a script, before it attaches)
   asks a throwaway subprocess instead (own session, killpg escalation,
   poll-don't-communicate), which exits — and frees the chip — before
   the caller attaches.  Either way the probe is ``ok`` only when the

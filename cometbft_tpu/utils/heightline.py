@@ -34,8 +34,7 @@ the process that produced it losing its in-memory state.
 
 Surfaces: ``consensus_height_phase_seconds{phase}`` Hub histogram
 observations (the delta between consecutive phase marks), the
-``/height_timeline`` RPC route, and the per-height summary in
-``BENCH_WORKLOAD=mixed`` output.
+``/height_timeline`` RPC route, and the flight recorder's ``heightline`` kind.
 
 Bounded by ``COMETBFT_TPU_HEIGHTLINE_CAP`` heights; disabled entirely
 with ``COMETBFT_TPU_HEIGHTLINE=0`` (marks become no-ops, the RPC

@@ -428,7 +428,7 @@ class Hub:
             "Parallel add_niels chains the comb verify program of a "
             "lane count sums its 86 partial points in (label lanes; "
             "ops/comb.fold_chains reads K off the lane count when the "
-            "program is traced; 1 = the sequential witness path)",
+            "program is traced)",
         )
         self.secp_pubkey_cache = r.counter(
             "verify_svc_secp_pubkey_cache_total",

@@ -26,7 +26,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def _enable_compile_cache() -> None:
     """Share the repo's persistent XLA compile cache (same recipe as
-    bench.py): cold comb/Straus compiles are minutes on a 1-core box; a
+    chip_smoke.py): cold comb/Straus compiles are minutes on a 1-core box; a
     warm cache makes the synthetic load I/O-bound instead."""
     try:
         from __graft_entry__ import _enable_compile_cache as enable

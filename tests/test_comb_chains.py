@@ -1,7 +1,7 @@
 """The comb accumulation by K parallel chains of mixed additions
-(ops/comb._accumulate_chains) against its witness, the sequential path,
-and the pure-Python ZIP-215 host verifier, at the 128-lane bucket the
-fast tier compiles: the verdict vector and the accumulated point itself
+(ops/comb._accumulate_chains) against its witness, the sequential
+reference kept in tests/test_comb_tree.py, and the pure-Python ZIP-215
+host verifier, at the 128-lane bucket the fast tier compiles: the verdict vector and the accumulated point itself
 for every K the rule can return and for K = 16 (4, 8 and 16 do not
 divide 86, so they pad with the Niels identity), and the rule that reads
 K off the lane count.
@@ -22,9 +22,11 @@ import pytest
 
 from cometbft_tpu.crypto import _ref25519 as ref
 from cometbft_tpu.crypto import ed25519 as host
-from cometbft_tpu.ops import comb, ed25519 as E, scalar
+from cometbft_tpu.ops import comb, ed25519 as E
 
-from test_comb_tree import _edge_r_encodings, _edge_sig
+from test_comb_tree import (
+    _accumulate_sequential, _edge_r_encodings, _edge_sig, _scalar_prep,
+)
 
 V = 128
 CHAINS = (1, 2, 4, 8, 16)
@@ -43,16 +45,6 @@ def test_chains_come_from_the_lane_count(lanes, k):
     widths = dict(comb.CHAIN_WIDTHS)
     assert k == 1 or k * lanes <= widths[k]
     assert all(c * lanes > w for c, w in comb.CHAIN_WIDTHS if c > k)
-
-
-@pytest.mark.parametrize("lanes", [128, 256, 10112])
-def test_accumulation_depth_follows_the_schedule(lanes, monkeypatch):
-    k = comb.fold_chains(lanes)
-    steps = -(-86 // k)
-    rounds = {1: 1, 2: 2, 4: 3, 8: 4}[k]
-    assert comb.accumulation_depth(lanes) == steps + rounds
-    monkeypatch.setenv("COMETBFT_TPU_COMB_TREE", "0")
-    assert comb.accumulation_depth(lanes) == 87
 
 
 @pytest.fixture(scope="module")
@@ -93,20 +85,7 @@ def corpus():
     assert all(want[i] for i in edge_rows), "ZIP-215 edge rows must verify"
     assert not any(want[i] for i in tampered)
 
-    def prep(r_enc, s_bytes, k_digest):
-        k_dig = scalar.signed_digits_radix16(
-            scalar.reduce_mod_l(scalar.bytes_to_limbs(k_digest, scalar.NL_X)),
-            comb.NPOS_A,
-        )
-        r_pt, r_valid = E.decompress(r_enc)
-        return (
-            k_dig,
-            scalar.bytes_to_limbs(s_bytes, comb.NPOS_B),
-            r_pt,
-            r_valid & scalar.s_lt_l(s_bytes),
-        )
-
-    k_dig, s_dig, r_pt, rs_ok = jax.jit(prep)(r, s, dig)
+    k_dig, s_dig, r_pt, rs_ok = jax.jit(_scalar_prep)(r, s, dig)
     args = (jnp.asarray(tables), k_dig, s_dig, comb.get_b_tables(), r_pt)
 
     def run(accumulate):
@@ -120,7 +99,7 @@ def corpus():
         enc, ok = jax.jit(program)(*args)
         return np.asarray(enc), np.asarray(ok).tolist()
 
-    seq_enc, seq_ok = run(comb._accumulate_sequential)
+    seq_enc, seq_ok = run(_accumulate_sequential)
     return {"want": want, "seq_enc": seq_enc, "seq_ok": seq_ok, "run": run}
 
 

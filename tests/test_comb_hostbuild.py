@@ -9,14 +9,9 @@ compile-cost budget gate, and the checked-in goldens carrying the
 table path under its budget (the deleted grandfather clause).
 
 Slow tier: the same bit-identity against the genuinely JITTED build
-(one XLA compile of the scan-rolled kernel), and a bench.py
-multichip-sweep smoke over a forced 2-device CPU mesh.
+(one XLA compile of the scan-rolled kernel).
 """
 
-import json
-import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -26,8 +21,6 @@ import pytest
 from cometbft_tpu.crypto import ed25519 as host
 from cometbft_tpu.ops import comb
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "bench.py")
 
 
 def _corpus(rng, n_valid):
@@ -227,41 +220,3 @@ def test_table_build_fits_its_budget_in_the_goldens():
     eqns = golden["comb_build_a_tables"]["costs"]["eqns"]
     assert 0 < eqns <= row.max_eqns
     assert eqns < 40_000  # the grandfathered build was ~84k
-
-
-@pytest.mark.slow
-def test_bench_multichip_smoke():
-    """bench.py BENCH_WORKLOAD=multichip end to end on a forced
-    2-device CPU mesh: one JSON line with the per-device-count scaling
-    table and cold-start-to-first-verify."""
-    env = os.environ.copy()
-    env.pop("JAX_PLATFORMS", None)
-    env.update({
-        "BENCH_SKIP_PROBE": "1",
-        "BENCH_WORKLOAD": "multichip",
-        "BENCH_MULTICHIP_CPU": "1",
-        "BENCH_MULTICHIP_DEVICES": "1,2",
-        "BENCH_MULTICHIP_ITERS": "1",
-        "BENCH_N": "16",
-        "BENCH_SHARDCHECK": "0",  # covered by the shardcheck suite
-        "BENCH_KERNELCHECK": "0",
-        "BENCH_HARD_TIMEOUT": "0",
-        "COMETBFT_TPU_DEVICE_BATCH_MIN": "1",
-    })
-    r = subprocess.run(
-        [sys.executable, BENCH], capture_output=True, text=True,
-        timeout=900, env=env, cwd=REPO,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    lines = [l for l in r.stdout.strip().splitlines() if l.startswith("{")]
-    assert len(lines) == 1, r.stdout
-    out = json.loads(lines[0])
-    assert "error" not in out, out
-    assert out["workload"] == "multichip"
-    assert set(out["scaling"]) == {"1", "2"}
-    for d, rec in out["scaling"].items():
-        assert rec["p50_ms"] > 0
-        assert rec["cold_start_to_first_verify_s"] >= 0
-        assert "table_build_s" in rec
-    assert out["value"] == out["scaling"]["2"]["p50_ms"]
-    assert "speedup_vs_1dev" in out

@@ -137,13 +137,25 @@ def test_entries_of_one_shape_share_one_compiled_program(
     assert told == [True, False] * compiles
 
 
-def test_the_tree_flag_is_part_of_the_program_key(marker_tables, monkeypatch):
-    """The trace resolves ops/comb.tree_enabled(): a process-wide cache
-    must never serve the other accumulation path's program."""
-    e = cv.ValsetCombCache().ensure(_pubs(40))
-    assert cv._program_key(e, 100) == (128, 100, True)
+def test_the_program_key_is_lanes_and_payload_width(marker_tables, monkeypatch):
+    """One accumulation, so nothing else: an environment variable of the
+    name that once chose a second one moves neither the key nor the
+    gauge of the schedule the program was traced with."""
+    monkeypatch.setattr(cv, "_device_verify", _stand_in_program)
+    monkeypatch.setattr(verifier, "_COMB_PROGRAMS", {})
+    pubs, mlen = SETS["x175"]
+    e = cv.ValsetCombCache().ensure(pubs)
+    assert cv._program_key(e, 100) == (256, 100)
     monkeypatch.setenv("COMETBFT_TPU_COMB_TREE", "0")
-    assert cv._program_key(e, 100) == (128, 100, False)
+    assert cv._program_key(e, 100) == (256, 100)
+    hub().comb_fold_chains.set(0, lanes="256")
+    bv = cv.CombBatchVerifier(e)
+    for pk in pubs:
+        bv.add(pk, b"v" * mlen, bytes(64))
+    assert bv.verify() == (True, [True] * len(pubs))
+    (key,) = verifier._COMB_PROGRAMS
+    assert key == (256, cv._payload_width([(pubs[0], b"v" * mlen, bytes(64))]))
+    assert hub().comb_fold_chains.value(lanes="256") == 8
 
 
 # ------------------------------------------------- the cache, by bytes

@@ -1,14 +1,15 @@
-"""Property: the default comb accumulation (ops/comb._accumulate_chains,
-K parallel chains of mixed additions; ``tree=True``) is bit-identical to
-the sequential comb path AND to the Straus fallback kernel on randomized
-vectors — including non-signer zero rows and ZIP-215 edge encodings —
-with the pure-Python host verifier as ground truth.  (The name of this
-file is the older fold's, a binary tree over all 87 points; the fast
-tier's tests/test_comb_chains.py holds the per-K checks.)
+"""Property: the comb accumulation (ops/comb._accumulate_chains, K
+parallel chains of mixed additions) is bit-identical to the sequential
+reference below AND to the Straus fallback kernel on randomized vectors
+— including non-signer zero rows and ZIP-215 edge encodings — with the
+pure-Python host verifier as ground truth.  (The name of this file is
+the older fold's, a binary tree over all 87 points; the fast tier's
+tests/test_comb_chains.py holds the per-K checks.)
 
-The chains are the engine default (COMETBFT_TPU_COMB_TREE); the
-sequential fori_loop path is kept exactly as the cross-check this module
-runs.  The mesh-sharded program runs the same verify_cached body
+The sequential reference, _accumulate_sequential, is test code: it left
+ops/comb when the chip had judged it (PERF.md section 6, PR 30) and is
+kept here as the witness both files compare against.  The mesh-sharded
+program runs the same verify_cached body
 (parallel/verify.sharded_verify_cached) and is cross-checked in a fresh
 interpreter by tests/test_parallel.py::test_sharded_comb_path_matches_host
 (tests/sharded_comb_check.py), which exercises the default path.
@@ -20,6 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import lax
 
 pytestmark = [
     pytest.mark.slow,  # kernel compiles take minutes on the CPU backend
@@ -28,9 +30,79 @@ pytestmark = [
 
 from cometbft_tpu.crypto import _ref25519 as ref
 from cometbft_tpu.crypto import ed25519 as host
-from cometbft_tpu.ops import comb, ed25519 as E, sha2
+from cometbft_tpu.ops import comb, ed25519 as E, field as F, scalar, sha2
 
 V = 8
+
+
+def _accumulate_sequential(tables, k_dig, s_dig, b_tables, r_pt):
+    """The reference accumulation: 64 + 22 dependent position adds in two
+    fori_loops, each looking its own partial up, then the R fold — an
+    87-step serial chain.  Same arguments and result as
+    ops/comb._accumulate_chains, which it witnesses bit for bit."""
+    V = k_dig.shape[-1]
+
+    # ---- A part: acc += T[i][|k_i|][v] (sign-adjusted), 64 adds
+    ents_a = jnp.arange(comb.NENT_A, dtype=jnp.int32)[:, None]
+
+    def a_body(i, acc):
+        slab = lax.dynamic_index_in_dim(tables, i, axis=0, keepdims=False)
+        dig = lax.dynamic_index_in_dim(k_dig, i, axis=0, keepdims=False)
+        neg = dig < 0
+        absd = jnp.abs(dig)
+        # int32 one-hot: the select stays in the tables' own dtype end to
+        # end (no float round trip; dtype-closure audited, no promotion)
+        onehot = (ents_a == absd[None, :]).astype(jnp.int32)  # (9, V)
+        sel = jnp.sum(slab * onehot[:, None, None, :], axis=0)  # (3, 22, V)
+        yplusx = F.select(neg, sel[1], sel[0])
+        yminusx = F.select(neg, sel[0], sel[1])
+        t2d = F.select(neg, -sel[2], sel[2])
+        return E.add_niels(acc, E.Niels(yplusx, yminusx, t2d))
+
+    acc = lax.fori_loop(0, comb.NPOS_A, a_body, E.identity((V,)))
+
+    # ---- B part: acc += B_TAB[i][:, s_i], 22 adds, MXU one-hot matmul
+    ents_b = jnp.arange(comb.NENT_B, dtype=jnp.int32)[:, None]
+
+    def b_body(i, acc):
+        slab = lax.dynamic_index_in_dim(b_tables, i, axis=0, keepdims=False)
+        dig = lax.dynamic_index_in_dim(s_dig, i, axis=0, keepdims=False)
+        onehot = (ents_b == dig[None, :]).astype(jnp.float32)  # (4096, V)
+        # HIGHEST: the TPU MXU default is bf16 passes (8 mantissa bits);
+        # the Niels limbs are 12-bit values and must come through exact.
+        sel = jnp.matmul(
+            slab, onehot, precision=lax.Precision.HIGHEST
+        ).astype(jnp.int32)  # (66, V)
+        return E.add_niels(
+            acc, E.Niels(sel[0:22], sel[22:44], sel[44:66])
+        )
+
+    acc = lax.fori_loop(0, comb.NPOS_B, b_body, acc)
+    return E.add(acc, E.neg(r_pt))
+
+
+def _scalar_prep(r_enc, s_bytes, k_digest):
+    """What ops/comb.verify_cached does before it accumulates: the
+    digits of k and s, R, and whether R and s are admissible."""
+    k_dig = scalar.signed_digits_radix16(
+        scalar.reduce_mod_l(scalar.bytes_to_limbs(k_digest, scalar.NL_X)),
+        comb.NPOS_A,
+    )
+    r_pt, r_valid = E.decompress(r_enc)
+    return (
+        k_dig,
+        scalar.bytes_to_limbs(s_bytes, comb.NPOS_B),
+        r_pt,
+        r_valid & scalar.s_lt_l(s_bytes),
+    )
+
+
+def _verify_sequential(tables, a_valid, r_enc, s_bytes, k_digest, b_tables):
+    """ops/comb.verify_cached with the reference accumulation."""
+    k_dig, s_dig, r_pt, rs_ok = _scalar_prep(r_enc, s_bytes, k_digest)
+    acc = _accumulate_sequential(tables, k_dig, s_dig, b_tables, r_pt)
+    acc = E.double(E.double(E.double(acc)))
+    return E.is_identity(acc) & a_valid & rs_ok
 
 
 def _edge_r_encodings():
@@ -66,8 +138,8 @@ def test_tree_matches_sequential_straus_and_host():
     assert np.asarray(valid).all()
     bt = comb.get_b_tables()
 
-    tree_fn = jax.jit(lambda *x: comb.verify_cached(*x, tree=True))
-    seq_fn = jax.jit(lambda *x: comb.verify_cached(*x, tree=False))
+    tree_fn = jax.jit(comb.verify_cached)
+    seq_fn = jax.jit(_verify_sequential)
     straus_fn = jax.jit(E.verify_batch)
 
     edges = _edge_r_encodings()

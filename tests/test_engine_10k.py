@@ -1,13 +1,13 @@
 """The engine — not just the kernel — at the flagship 10,000-validator
 scale (round-5 verdict item 4): a real chain built through the
 BlockExecutor with 10k-signature commits, verified through
-types/validation.py (not bench.py's synthetic batch), vote-set bitmaps
+types/validation.py (not a synthetic batch), vote-set bitmaps
 and proposer rotation at full width, and `validators` pagination over
 the 10k set.
 
 Crypto runs on the sequential host path: the comb/Straus device kernels
-are shape-tested separately (tests/test_comb.py V=8/V=10, bench on the
-real chip) — a 10k-lane compile on the CPU test backend takes hours and
+are shape-tested separately (tests/test_comb.py V=8/V=10; the
+benchmark's commit-10k-serial cell on the real chip) — a 10k-lane compile on the CPU test backend takes hours and
 proves nothing the small shapes don't.  What 10k exercises here is the
 ENGINE: set construction, priority cycling, VoteSet majority tracking,
 commit assembly width, batch-verify assembly + blame indexing, and the

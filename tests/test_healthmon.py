@@ -446,24 +446,13 @@ def test_expected_platform_defaults_to_tpu(monkeypatch):
 
 
 def test_probe_in_child_ok_on_cpu(monkeypatch):
-    """The child form (for a process that has not imported JAX — bench.py
-    before it attaches) against the CPU backend."""
+    """The child form (for a process that has not imported JAX) against
+    the CPU backend."""
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     res = healthmon._probe_in_child(60.0)
     assert res.ok is True and res.timed_out is False
     assert res.latency_s < 60.0
     assert (res.platform, res.device_kind) == ("cpu", "cpu")
-
-
-def test_bench_imports_shared_probe():
-    """bench.py probes with THE library probe, not a copy: the module
-    source references healthmon.probe_devices and carries no Popen of
-    its own."""
-    src = open(os.path.join(os.path.dirname(__file__), "..", "bench.py")).read()
-    assert "healthmon" in src
-    assert "probe_devices" in src
-    assert "subprocess.Popen" not in src  # the bespoke copy is gone
-    assert "os.killpg" not in src  # kill escalation lives in the library now
 
 
 # --------------------------------------------- verifysvc in-flight ages

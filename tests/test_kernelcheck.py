@@ -506,7 +506,7 @@ def test_untracked_jit_refuses_allowlist_suppression(tmp_path):
 
 def test_run_check_applies_provided_allowlist(tmp_path, monkeypatch):
     """A justified allowlist entry reads green through run_check too
-    (the bench.py path), and lets regenerate() re-bless the goldens."""
+    and lets regenerate() re-bless the goldens."""
     _fixture_module()
     k = _kernel("weak_float", (manifest.f32(4),), name="fix_allow")
     monkeypatch.setattr(manifest, "KERNELS", (k,))
@@ -537,7 +537,7 @@ def test_regenerate_refuses_broken_contract(tmp_path, monkeypatch):
     assert not os.path.exists(p)
 
 
-# --------------------------------------------------- CLI & bench wiring
+# ------------------------------------------------------------ CLI wiring
 
 def test_lint_cli_check_selector(tmp_path):
     bad = tmp_path / "ops" / "fake.py"
@@ -556,27 +556,6 @@ def test_lint_cli_check_selector(tmp_path):
         capture_output=True, text=True, timeout=120, cwd=REPO,
     )
     assert proc.returncode == 2
-
-
-def test_bench_reports_kernelcheck_when_backend_unavailable():
-    """bench.py's backend-unavailable path embeds the static pass: wire
-    check with run_check stubbed (the real pass is the slow gate)."""
-    code = (
-        "import sys, json\n"
-        f"sys.path.insert(0, {REPO!r})\n"
-        "import bench\n"
-        "from cometbft_tpu.analysis import kernelcheck\n"
-        "kernelcheck.run_check = lambda **kw: ([], [])\n"
-        "print(json.dumps(bench._kernelcheck_report()))\n"
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True, text=True, timeout=120, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stderr
-    rep = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rep["ok"] is True and rep["kernels"] == 0
-    assert rep["findings"] == [] and "elapsed_s" in rep
 
 
 # ------------------------------------------- the phases a profile reads

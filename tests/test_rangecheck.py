@@ -487,47 +487,6 @@ def test_range_gate_fast_hash_plane_clean():
     assert all(r.ok for r in reports)
 
 
-def test_bench_summary_is_certificate_backed():
-    s = rc.bench_summary(spot_kernels=("sha256_blocks",))
-    assert s["mode"] == "certificates+spot"
-    assert s["ok"] is True and s["certificates_ok"] is True
-    assert s["spot_kernels"] == ["sha256_blocks"]
-    assert s["spot_findings"] == []
-    # every certificate surfaces its headroom row
-    assert s["certificates"] == len(s["headroom"])
-    assert "ed25519_verify_batch" in s["headroom"]
-
-
-def test_bench_embeds_rangecheck_report():
-    """bench.py's backend-less path embeds the range pass: wire check
-    with the interpreter stubbed (the real pass is the slow gate)."""
-    import json
-    import os
-    import subprocess
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    code = (
-        "import sys, json\n"
-        f"sys.path.insert(0, {repo!r})\n"
-        "import bench\n"
-        "from cometbft_tpu.analysis import rangecheck\n"
-        "rangecheck.run_check = lambda **kw: ([], [])\n"
-        "rangecheck.load_fingerprints = lambda *a: "
-        "{'k': {'ok': True, 'findings': [], 'peak_int32': 7}}\n"
-        "print(json.dumps(bench._rangecheck_report()))\n"
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True, text=True, timeout=120, cwd=repo,
-    )
-    assert proc.returncode == 0, proc.stderr
-    rep = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rep["ok"] is True and rep["mode"] == "certificates+spot"
-    assert rep["certificates"] == 1 and rep["spot_findings"] == []
-    assert rep["headroom"]["k"]["peak_int32"] == 7
-    assert "elapsed_s" in rep
-
-
 @pytest.mark.slow
 def test_range_certificates_match_full_manifest():
     """The acceptance gate, in-process: interpret every manifest kernel

@@ -144,8 +144,8 @@ def test_glv_bit_identical_to_shamir_witness_device():
     """THE witness pin: the GLV program and the non-GLV Shamir program
     produce bit-identical verdicts — equal to the host gauntlet — over
     the rec-extended adversarial corpus (all three wire shapes, every
-    invalid class, poison rows both sides of victims) in one dispatch;
-    the COMB_TREE witness pattern."""
+    invalid class, poison rows both sides of victims) in one dispatch:
+    the witness walk pins the default one."""
     _witness_pin(_rec_corpus(), hash_min="0")
 
 

@@ -5,7 +5,7 @@ kernels in ``tests/shardcheck_fixtures.py`` (the suite already runs
 with 8 forced host devices, so the genuine 8-way mesh is available
 without a child interpreter), the golden round-trip/drift machinery,
 the ``donated-read-after-dispatch`` AST check, the per-equivalent-mesh
-program cache regression, and the bench/CLI wiring.  One subprocess
+program cache regression, and the CLI wiring.  One subprocess
 smoke proves the forced-environment child end to end.
 
 Slow tier: the full golden-match pass — every real sharded kernel
@@ -271,7 +271,7 @@ def test_costs_ride_the_golden_but_not_the_digest(tmp_path):
 def test_one_program_per_equivalent_mesh():
     """The PR-6 cache fix: two make_mesh calls over the same devices
     hand out the SAME program object — one trace, one compile — while a
-    different axis name or comb path keys a different program."""
+    different axis name keys a different program."""
     from cometbft_tpu.parallel import verify as PV
     from cometbft_tpu.parallel.mesh import make_mesh, mesh_cache_key
 
@@ -279,9 +279,8 @@ def test_one_program_per_equivalent_mesh():
     assert m1 is not m2 or mesh_cache_key(m1) == mesh_cache_key(m2)
     assert PV._verify_fn(m1) is PV._verify_fn(m2)
     assert PV._merkle_fn(m1) is PV._merkle_fn(m2)
-    assert PV._comb_verify_fn(m1, True) is PV._comb_verify_fn(m2, True)
-    # knob flag and axis name are part of the key
-    assert PV._comb_verify_fn(m1, True) is not PV._comb_verify_fn(m1, False)
+    assert PV._comb_verify_fn(m1) is PV._comb_verify_fn(m2)
+    # the axis name is part of the key
     other = make_mesh(1, axis="other")
     assert PV._verify_fn(other) is not PV._verify_fn(m1)
 
@@ -493,30 +492,6 @@ def test_lint_cli_sharding_ast_check(tmp_path):
     assert {f["check"] for f in data["findings"]} == {
         "donated-read-after-dispatch"
     }
-
-
-def test_bench_reports_shardcheck(tmp_path):
-    """bench.py's backend-unavailable path embeds the sharded pass —
-    wire check with run_subprocess stubbed (the real pass is slow)."""
-    code = (
-        "import sys, json\n"
-        f"sys.path.insert(0, {REPO!r})\n"
-        "import bench\n"
-        "from cometbft_tpu.analysis import shardcheck\n"
-        "shardcheck.run_subprocess = lambda **kw: ([], {\n"
-        "    'ok': True, 'device_count': 8,\n"
-        "    'kernels': {'sharded_merkle_root': {'eqns': 633}}})\n"
-        "print(json.dumps(bench._shardcheck_report()))\n"
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True, text=True, timeout=120, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stderr
-    rep = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rep["ok"] is True and rep["findings"] == 0
-    assert rep["kernels"] == {"sharded_merkle_root": 633}
-    assert "elapsed_s" in rep
 
 
 # ------------------------------------------------- compile-cache rule

@@ -11,6 +11,8 @@ import subprocess
 import sys
 import threading
 
+import pytest
+
 from cometbft_tpu.analysis import (
     jax_purity,
     linter,
@@ -613,14 +615,14 @@ def test_envknobs_typed_getters(monkeypatch):
     assert envknobs.get_int(envknobs.COMB_MIN) == 77
     monkeypatch.setenv(envknobs.COMB_MIN, "junk")
     assert envknobs.get_int(envknobs.COMB_MIN) == 32  # declared default
-    monkeypatch.setenv(envknobs.COMB_TREE, "0")
-    assert envknobs.get_bool(envknobs.COMB_TREE) is False
-    monkeypatch.delenv(envknobs.COMB_TREE, raising=False)
-    assert envknobs.get_bool(envknobs.COMB_TREE) is True
+    monkeypatch.setenv(envknobs.SECP_GLV, "0")
+    assert envknobs.get_bool(envknobs.SECP_GLV) is False
+    monkeypatch.delenv(envknobs.SECP_GLV, raising=False)
+    assert envknobs.get_bool(envknobs.SECP_GLV) is True
     # set-but-empty (`KNOB= cmd`) means default, never False — this
     # knob keys a compiled-program cache
-    monkeypatch.setenv(envknobs.COMB_TREE, "")
-    assert envknobs.get_bool(envknobs.COMB_TREE) is True
+    monkeypatch.setenv(envknobs.SECP_GLV, "")
+    assert envknobs.get_bool(envknobs.SECP_GLV) is True
     monkeypatch.delenv(envknobs.DEVICE_BATCH_MIN, raising=False)
     assert envknobs.get_opt_int(envknobs.DEVICE_BATCH_MIN) is None
     monkeypatch.setenv(envknobs.DEVICE_BATCH_MIN, "9")
@@ -776,3 +778,67 @@ def test_lint_script_json_contract(tmp_path):
     assert "swallowed-exception-in-thread" in checks
     for f in data["findings"]:
         assert {"check", "path", "line", "col", "message"} <= set(f)
+
+
+GATE_STUBS = {
+    # gate id -> (module, function lint.py calls, what the stub returns,
+    #             the summary --json must carry under the gate's key)
+    "kernel": (
+        "kernelcheck", "run_check", "([], [])",
+        {"ok": True, "kernels": 0, "primitive_total": 0, "eqns": {},
+         "findings": []},
+    ),
+    "sharding": (
+        "shardcheck", "run_subprocess",
+        "([], {'ok': True, 'device_count': 8,"
+        " 'kernels': {'sharded_merkle_root': {'eqns': 633}}})",
+        {"ok": True, "findings": 0, "device_count": 8,
+         "kernels": {"sharded_merkle_root": {"eqns": 633}}},
+    ),
+    "range": (
+        "rangecheck", "run_check", "([], [])",
+        {"ok": True, "kernels": 0, "headroom": {}, "findings": []},
+    ),
+}
+
+
+@pytest.mark.parametrize("gate", sorted(GATE_STUBS))
+def test_lint_json_carries_each_trace_gates_summary(gate, tmp_path):
+    """scripts/lint.py --check <gate> --json is where a trace gate's
+    machine-readable summary is read: wire check with the pass stubbed
+    (the real passes are the slow gates)."""
+    mod, fn, result, want = GATE_STUBS[gate]
+    clean = tmp_path / "clean.py"
+    clean.write_text("x = 1\n")
+    code = (
+        "import sys\n"
+        f"sys.path.insert(0, {os.path.join(REPO, 'scripts')!r})\n"
+        "import lint\n"
+        f"from cometbft_tpu.analysis import {mod}\n"
+        f"{mod}.{fn} = lambda **kw: {result}\n"
+        f"sys.exit(lint.main([{str(clean)!r}, '--check', {gate!r}, '--json']))\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+    )
+    assert proc.returncode == 0, proc.stderr
+    data = json.loads(proc.stdout)
+    assert data["ok"] is True and data["findings"] == []
+    assert data[gate] == want
+
+
+@pytest.mark.parametrize("gate", ["kernelcheck", "rangecheck"])
+def test_golden_holds_exactly_the_manifests_kernels(gate):
+    """No tracing: a golden entry whose manifest row is gone (or a row
+    with no entry) is caught here, not only by the slow gates.  The
+    shard golden's half is tests/test_shardcheck.py's
+    test_real_sharded_programs_census_is_reshard_free."""
+    import importlib
+
+    from cometbft_tpu.analysis import kernel_manifest
+
+    mod = importlib.import_module(f"cometbft_tpu.analysis.{gate}")
+    assert set(mod.load_fingerprints()) == {
+        k.name for k in kernel_manifest.KERNELS
+    }
