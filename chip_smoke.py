@@ -473,6 +473,10 @@ def route_report() -> dict:
         "comb_program_cache": {
             r: m.comb_program_cache.value(result=r) for r in ("hit", "compile")
         },
+        "commit_assemble_rows": {
+            r: m.commit_assemble_rows.value(path=r)
+            for r in ("columns", "per_row")
+        },
         "fallback_spans": {n: names.get(n, 0) for n in FALLBACK_SPANS},
         "dispatch_spans": names.get("verify.sched.dispatch", 0),
         "device_wait_spans": names.get("verify.device_wait", 0),
@@ -657,6 +661,10 @@ def run(width_large: int, width_small: int, facts: dict) -> dict:
                         "of a set that was bound")
     if rep["comb_table_cache"]["hit"] < 1:
         problems.append("comb table cache never hit")
+    rows = rep["commit_assemble_rows"]
+    if rows["per_row"] or not rows["columns"]:
+        problems.append(f"commit.assemble encoded its rows {rows}, not all "
+                        "by columns")
     lanes = {
         global_cache().get(
             ValsetCombCache.fingerprint(c.vals.pub_keys_bytes())

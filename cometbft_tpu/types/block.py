@@ -412,8 +412,10 @@ class Commit:
 
     def vote_sign_bytes_fn(self, chain_id: str):
         """idx -> sign bytes, with the per-flag canonical prefixes
-        encoded once — the batch-assembly fast path for a whole commit
-        (10k encodes collapse to 10k timestamp splices)."""
+        encoded once and the timestamp spliced in a row at a time: what
+        the sequential path and the verdict-vector checks ask for, and
+        the per-row route of vote_sign_bytes_rows (which encodes a
+        batch's rows in one pass)."""
         from ..wire.canonical import PRECOMMIT_TYPE, make_vote_sign_bytes_batch
 
         for_block = make_vote_sign_bytes_batch(
@@ -430,6 +432,41 @@ class Commit:
             return maker(cs.timestamp)
 
         return fn
+
+    def vote_sign_bytes_rows(
+        self, chain_id: str, idxs: list[int]
+    ) -> tuple[list[bytes], str]:
+        """The sign bytes of rows ``idxs``, in that order, and the route
+        that encoded them: "columns" (wire/canonical.
+        vote_sign_bytes_columns: one numpy pass over the rows' seconds
+        and nanos, whatever their values) or, where a timestamp of the
+        commit does not fit the columns, "per_row" (vote_sign_bytes_fn
+        a row).  Every row equals vote_sign_bytes(chain_id, idx)."""
+        from ..wire.canonical import (
+            PRECOMMIT_TYPE, vote_sign_bytes_columns, vote_sign_bytes_frame,
+        )
+
+        rows = [self.signatures[i] for i in idxs]
+        stamps = [cs.timestamp for cs in rows]
+        nil = [cs.block_id_flag != BLOCK_ID_FLAG_COMMIT for cs in rows]
+        block_ids = [self.block_id.to_canonical()]
+        if any(nil):  # the second frame only where a row needs it
+            block_ids.append(None)
+        msgs = vote_sign_bytes_columns(
+            [
+                vote_sign_bytes_frame(
+                    chain_id, PRECOMMIT_TYPE, self.height, self.round, bid
+                )
+                for bid in block_ids
+            ],
+            nil,
+            [t.seconds for t in stamps],
+            [t.nanos for t in stamps],
+        )
+        if msgs is not None:
+            return msgs, "columns"
+        fn = self.vote_sign_bytes_fn(chain_id)
+        return [fn(i) for i in idxs], "per_row"
 
     def hash(self) -> bytes:
         """Merkle root over proto-encoded CommitSigs (block.go:988)."""

@@ -26,9 +26,13 @@ from __future__ import annotations
 from dataclasses import dataclass
 from fractions import Fraction
 
+import numpy as np
+
 from ..crypto import batch as crypto_batch
-from ..utils import tracing
-from .block import BlockID, Commit, CommitSig
+from ..utils import metrics, tracing
+from .block import (
+    BLOCK_ID_FLAG_ABSENT, BLOCK_ID_FLAG_COMMIT, BlockID, Commit, CommitSig,
+)
 from .validators import ValidatorSet
 
 BATCH_VERIFY_THRESHOLD = 2  # validation.go:15
@@ -99,17 +103,15 @@ def verify_commit(
     (validation.go:30)."""
     _verify_basic_vals_and_commit(vals, commit, height, block_id)
     voting_power_needed = vals.total_voting_power() * 2 // 3
-    ignore = lambda cs: cs.absent_flag()
-    count = lambda cs: cs.for_block()
     if should_batch_verify(vals, commit):
         _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, ignore, count,
+            chain_id, vals, commit, voting_power_needed, commit_only=False,
             count_all_signatures=True, lookup_by_index=True, cache=None,
             klass=klass,
         )
     else:
         _verify_commit_single(
-            chain_id, vals, commit, voting_power_needed, ignore, count,
+            chain_id, vals, commit, voting_power_needed, commit_only=False,
             count_all_signatures=True, lookup_by_index=True, cache=None,
         )
 
@@ -128,17 +130,15 @@ def verify_commit_light(
     (validation.go:65-147)."""
     _verify_basic_vals_and_commit(vals, commit, height, block_id)
     voting_power_needed = vals.total_voting_power() * 2 // 3
-    ignore = lambda cs: not cs.for_block()
-    count = lambda cs: True
     if should_batch_verify(vals, commit):
         _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, ignore, count,
+            chain_id, vals, commit, voting_power_needed, commit_only=True,
             count_all_signatures=count_all_signatures, lookup_by_index=True,
             cache=cache, klass=klass,
         )
     else:
         _verify_commit_single(
-            chain_id, vals, commit, voting_power_needed, ignore, count,
+            chain_id, vals, commit, voting_power_needed, commit_only=True,
             count_all_signatures=count_all_signatures, lookup_by_index=True,
             cache=cache,
         )
@@ -163,17 +163,15 @@ def verify_commit_light_trusting(
         raise CommitVerificationError("trustLevel has zero Denominator")
     total = vals.total_voting_power()
     voting_power_needed = total * trust_level.numerator // trust_level.denominator
-    ignore = lambda cs: not cs.for_block()
-    count = lambda cs: True
     if should_batch_verify(vals, commit):
         _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, ignore, count,
+            chain_id, vals, commit, voting_power_needed, commit_only=True,
             count_all_signatures=count_all_signatures, lookup_by_index=False,
             cache=cache, klass=klass,
         )
     else:
         _verify_commit_single(
-            chain_id, vals, commit, voting_power_needed, ignore, count,
+            chain_id, vals, commit, voting_power_needed, commit_only=True,
             count_all_signatures=count_all_signatures, lookup_by_index=False,
             cache=cache,
         )
@@ -202,63 +200,140 @@ def _verify_basic_vals_and_commit(vals, commit, height, block_id):
         )
 
 
+def _select_rows(
+    vals: ValidatorSet,
+    commit: Commit,
+    voting_power_needed: int,
+    commit_only: bool,
+    count_all_signatures: bool,
+    lookup_by_index: bool,
+):
+    """Which rows of the commit the loop of validation.go:265 would have
+    reached and kept, from columns and with nothing encoded: returns
+    (rows, val_idxs, tallied, fault).  ``rows`` are the commit's row
+    indices in order, ``val_idxs`` each row's validator in ``vals``,
+    ``tallied`` the power counted over them, and ``fault`` the double
+    vote that ends the scan (the rows before it stand), or None.
+
+    commit_only: a row is kept if it is for the block and every kept
+    row counts (the light checks); else a row is kept unless absent and
+    counts if it is for the block (verify_commit)."""
+    sigs = commit.signatures
+    # no dtype asked for: a flag off the wire may be any varint, and
+    # numpy then compares it as the Python int it is
+    flags = np.array([cs.block_id_flag for cs in sigs])
+    for_block = flags == BLOCK_ID_FLAG_COMMIT
+    rows = np.flatnonzero(
+        for_block if commit_only else flags != BLOCK_ID_FLAG_ABSENT
+    )
+    fault = None
+    if lookup_by_index:
+        val_idxs = rows
+    else:
+        index = vals.address_index()
+        found = np.array(
+            [index.get(sigs[i].validator_address, -1) for i in rows.tolist()],
+            dtype=np.int64,
+        )
+        rows, val_idxs = rows[found >= 0], found[found >= 0]
+        firsts = np.unique(val_idxs, return_index=True)[1]
+        if len(firsts) != len(val_idxs):
+            # a validator signs twice: the scan ends at the first row
+            # whose validator an earlier row named
+            again = np.ones(len(val_idxs), dtype=bool)
+            again[firsts] = False
+            at = int(np.flatnonzero(again)[0])
+            first = int(np.flatnonzero(val_idxs == val_idxs[at])[0])
+            fault = CommitVerificationError(
+                f"double vote from {vals.validators[val_idxs[at]]} "
+                f"({rows[first]} and {rows[at]})"
+            )
+            rows, val_idxs = rows[:at], val_idxs[:at]
+    counted = vals.voting_powers()[val_idxs]
+    if not commit_only:
+        counted = np.where(for_block[rows], counted, 0)
+    if count_all_signatures:
+        tallied = int(counted.sum())
+    else:
+        # the scan stops behind the first row that carries the tally
+        # over the bar; a double vote behind that row is never seen
+        running = np.cumsum(counted)
+        over = np.flatnonzero(running > voting_power_needed)
+        if len(over):
+            stop = int(over[0]) + 1
+            rows, val_idxs, running, fault = (
+                rows[:stop], val_idxs[:stop], running[:stop], None
+            )
+        tallied = int(running[-1]) if len(running) else 0
+    return rows.tolist(), val_idxs.tolist(), tallied, fault
+
+
 def _assemble_commit_batch(
     bv,
     chain_id: str,
     vals: ValidatorSet,
     commit: Commit,
     voting_power_needed: int,
-    ignore_sig,
-    count_sig,
+    commit_only: bool,
     count_all_signatures: bool,
     lookup_by_index: bool,
     cache: SignatureCache | None,
 ):
     """(validation.go:265, assembly half) — fill the batch verifier and
     tally power; raises on insufficient power / double votes.  Returns
-    (batch_sig_idxs, sign_bytes_at) for the judging half."""
-    seen_vals: dict[int, int] = {}
-    batch_sig_idxs: list[int] = []
-    tallied = 0
-    sign_bytes_at = commit.vote_sign_bytes_fn(chain_id)
+    (batch_sig_idxs, msgs) for the judging half: the rows handed to the
+    verifier and their sign-bytes, in add() order.
 
-    for idx, cs in enumerate(commit.signatures):
-        if ignore_sig(cs):
-            continue
-        if lookup_by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(cs.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen_vals:
-                raise CommitVerificationError(
-                    f"double vote from {val} ({seen_vals[val_idx]} and {idx})"
-                )
-            seen_vals[val_idx] = idx
-
-        sign_bytes = sign_bytes_at(idx)
-
-        cache_hit = False
+    By columns, and equal to the reference's loop row for row (kept as
+    the oracle in tests/test_commit_assemble_columns.py): the rows are
+    selected first and nothing is encoded for a row that is not in; the
+    selected rows' sign-bytes come from one pass (Commit.
+    vote_sign_bytes_rows); the cache drops the rows it knows; the rest
+    go to the verifier in one add_many.  What the loop raised first
+    still comes first: a row the verifier refuses as malformed, then a
+    double vote, then the tally."""
+    labels = {} if tracing.enabled() else None
+    with tracing.span("commit.assemble", labels):
+        rows, val_idxs, tallied, fault = _select_rows(
+            vals, commit, voting_power_needed, commit_only,
+            count_all_signatures, lookup_by_index,
+        )
+        msgs, path = commit.vote_sign_bytes_rows(chain_id, rows)
+        metrics.hub().commit_assemble_rows.inc(len(rows), path=path)
+        if labels is not None:
+            labels.update(rows=len(rows), path=path)
+        sigs = [commit.signatures[i].signature for i in rows]
         if cache is not None:
-            cv = cache.get(cs.signature)
-            cache_hit = (
-                cv is not None
-                and cv.validator_address == val.pub_key.address()
-                and cv.vote_sign_bytes == sign_bytes
+            validators = vals.validators
+            fresh = []
+            for j, (sig, msg) in enumerate(zip(sigs, msgs)):
+                cv = cache.get(sig)
+                if (
+                    cv is None
+                    or cv.validator_address != validators[val_idxs[j]].address
+                    or cv.vote_sign_bytes != msg
+                ):
+                    fresh.append(j)
+            if len(fresh) != len(rows):
+                rows, val_idxs, msgs, sigs = (
+                    [col[j] for j in fresh]
+                    for col in (rows, val_idxs, msgs, sigs)
+                )
+        all_pubs = vals.pub_keys_bytes()
+        pubs = [all_pubs[i] for i in val_idxs]
+        add_many = getattr(bv, "add_many", None)
+        if add_many is not None:
+            add_many(pubs, msgs, sigs)
+        else:
+            for row in zip(pubs, msgs, sigs):
+                bv.add(*row)
+        if fault is not None:
+            raise fault
+        if tallied <= voting_power_needed:
+            raise NotEnoughVotingPowerError(
+                got=tallied, needed=voting_power_needed
             )
-        if not cache_hit:
-            bv.add(val.pub_key.bytes(), sign_bytes, cs.signature)
-            batch_sig_idxs.append(idx)
-
-        if count_sig(cs):
-            tallied += val.voting_power
-        if not count_all_signatures and tallied > voting_power_needed:
-            break
-
-    if tallied <= voting_power_needed:
-        raise NotEnoughVotingPowerError(got=tallied, needed=voting_power_needed)
-    return batch_sig_idxs, sign_bytes_at
+    return rows, msgs
 
 
 def _judge_batch_result(
@@ -266,19 +341,21 @@ def _judge_batch_result(
     valid_sigs: list[bool],
     commit: Commit,
     batch_sig_idxs: list[int],
-    sign_bytes_at,
+    msgs: list[bytes],
     cache: SignatureCache | None,
 ) -> None:
-    """(validation.go:384-399, judging half) — blame order + cache fill."""
+    """(validation.go:384-399, judging half) — blame order + cache fill.
+    ``msgs`` are the batch's sign-bytes as assembled, in the order of
+    ``batch_sig_idxs``: nothing is encoded a second time."""
     if ok:
         if cache is not None:
-            for idx in batch_sig_idxs:
+            for idx, msg in zip(batch_sig_idxs, msgs):
                 cs = commit.signatures[idx]
                 cache.add(
                     cs.signature,
                     SignatureCacheValue(
                         validator_address=cs.validator_address,
-                        vote_sign_bytes=sign_bytes_at(idx),
+                        vote_sign_bytes=msg,
                     ),
                 )
         return
@@ -296,7 +373,7 @@ def _judge_batch_result(
                 cs.signature,
                 SignatureCacheValue(
                     validator_address=cs.validator_address,
-                    vote_sign_bytes=sign_bytes_at(idx),
+                    vote_sign_bytes=msgs[i],
                 ),
             )
     raise CommitVerificationError(
@@ -309,8 +386,7 @@ def _verify_commit_batch(
     vals: ValidatorSet,
     commit: Commit,
     voting_power_needed: int,
-    ignore_sig,
-    count_sig,
+    commit_only: bool,
     count_all_signatures: bool,
     lookup_by_index: bool,
     cache: SignatureCache | None,
@@ -322,11 +398,10 @@ def _verify_commit_batch(
     bv = crypto_batch.create_batch_verifier(
         proposer.pub_key.type, pubkeys=vals.pub_keys_bytes(), klass=klass
     )
-    with tracing.span("commit.assemble"):
-        batch_sig_idxs, sign_bytes_at = _assemble_commit_batch(
-            bv, chain_id, vals, commit, voting_power_needed, ignore_sig,
-            count_sig, count_all_signatures, lookup_by_index, cache,
-        )
+    batch_sig_idxs, msgs = _assemble_commit_batch(
+        bv, chain_id, vals, commit, voting_power_needed, commit_only,
+        count_all_signatures, lookup_by_index, cache,
+    )
     if not batch_sig_idxs:
         return  # everything came from the cache
 
@@ -340,9 +415,7 @@ def _verify_commit_batch(
         else:
             ok, valid_sigs = bv.verify()
     with tracing.span("commit.judge"):
-        _judge_batch_result(
-            ok, valid_sigs, commit, batch_sig_idxs, sign_bytes_at, cache
-        )
+        _judge_batch_result(ok, valid_sigs, commit, batch_sig_idxs, msgs, cache)
 
 
 class PendingCommitVerification:
@@ -352,14 +425,14 @@ class PendingCommitVerification:
     waits for the result and raises exactly what verify_commit_light
     would have."""
 
-    __slots__ = ("_bv", "_ticket", "_commit", "_idxs", "_sign_bytes_at", "_cache")
+    __slots__ = ("_bv", "_ticket", "_commit", "_idxs", "_msgs", "_cache")
 
-    def __init__(self, bv, ticket, commit, idxs, sign_bytes_at, cache):
+    def __init__(self, bv, ticket, commit, idxs, msgs, cache):
         self._bv = bv
         self._ticket = ticket
         self._commit = commit
         self._idxs = idxs
-        self._sign_bytes_at = sign_bytes_at
+        self._msgs = msgs  # the batch's sign-bytes, in the order of idxs
         self._cache = cache
 
     def collect(self) -> None:
@@ -370,7 +443,7 @@ class PendingCommitVerification:
         with tracing.span("commit.judge"):
             _judge_batch_result(
                 ok, valid_sigs, self._commit, self._idxs,
-                self._sign_bytes_at, self._cache,
+                self._msgs, self._cache,
             )
 
 
@@ -406,21 +479,19 @@ def submit_verify_commit_light(
     if not hasattr(bv, "submit"):
         return None  # host verifier: no async seam, caller runs sync
     voting_power_needed = vals.total_voting_power() * 2 // 3
-    with tracing.span("commit.assemble"):
-        batch_sig_idxs, sign_bytes_at = _assemble_commit_batch(
-            bv, chain_id, vals, commit, voting_power_needed,
-            ignore_sig=lambda cs: not cs.for_block(),
-            count_sig=lambda cs: True,
-            count_all_signatures=count_all_signatures,
-            lookup_by_index=True,
-            cache=cache,
-        )
+    batch_sig_idxs, msgs = _assemble_commit_batch(
+        bv, chain_id, vals, commit, voting_power_needed,
+        commit_only=True,
+        count_all_signatures=count_all_signatures,
+        lookup_by_index=True,
+        cache=cache,
+    )
     if not batch_sig_idxs:
-        return PendingCommitVerification(None, None, commit, [], sign_bytes_at, cache)
+        return PendingCommitVerification(None, None, commit, [], [], cache)
     with tracing.span("commit.verify"):
         ticket = bv.submit()
     return PendingCommitVerification(
-        bv, ticket, commit, batch_sig_idxs, sign_bytes_at, cache
+        bv, ticket, commit, batch_sig_idxs, msgs, cache
     )
 
 
@@ -429,8 +500,7 @@ def _verify_commit_single(
     vals: ValidatorSet,
     commit: Commit,
     voting_power_needed: int,
-    ignore_sig,
-    count_sig,
+    commit_only: bool,
     count_all_signatures: bool,
     lookup_by_index: bool,
     cache: SignatureCache | None,
@@ -440,7 +510,7 @@ def _verify_commit_single(
     tallied = 0
     sign_bytes_at = commit.vote_sign_bytes_fn(chain_id)
     for idx, cs in enumerate(commit.signatures):
-        if ignore_sig(cs):
+        if (not cs.for_block()) if commit_only else cs.absent_flag():
             continue
         try:
             cs.validate_basic()
@@ -487,7 +557,7 @@ def _verify_commit_single(
                     ),
                 )
 
-        if count_sig(cs):
+        if commit_only or cs.for_block():
             tallied += val.voting_power
         if not count_all_signatures and tallied > voting_power_needed:
             return

@@ -9,6 +9,8 @@ over SimpleValidator encodings (validator_set.go:386).
 
 from __future__ import annotations
 
+import numpy as np
+
 from ..crypto import encoding as keyenc
 from ..crypto import merkle
 from ..wire import types_pb as pb
@@ -109,7 +111,11 @@ def _val_sort_key(v: Validator):
 
 
 class ValidatorSet:
-    """Sorted validator set with proposer rotation (validator_set.go:43)."""
+    """Sorted validator set with proposer rotation (validator_set.go:43).
+
+    ``validators`` changes through update_with_change_set only, which
+    drops everything cached about the membership (hash, pubkey list,
+    _facts); code that edits the list in place would read stale caches."""
 
     def __init__(self, validators: list[Validator]):
         vals = sorted((v.copy() for v in validators), key=_val_sort_key)
@@ -135,7 +141,11 @@ class ValidatorSet:
         new = ValidatorSet.__new__(ValidatorSet)
         new.validators = [v.copy() for v in self.validators]
         new._total_voting_power = self._total_voting_power
-        new._set_hash = getattr(self, "_set_hash", None)  # same membership
+        # same membership in the same order: the hash and the per-set
+        # facts hold for the copy (a fact is never edited in place;
+        # update_with_change_set drops them on the set it changes)
+        new._set_hash = getattr(self, "_set_hash", None)
+        new._set_facts = dict(self._facts())
         new.proposer = None
         if self.proposer is not None:
             for v in new.validators:
@@ -161,11 +171,43 @@ class ValidatorSet:
             self._update_total_voting_power()
         return self._total_voting_power
 
+    def _facts(self) -> dict:
+        """What is kept about the membership beside _pub_keys_bytes,
+        each fact built at its first use: dropped by
+        update_with_change_set and, the guard pub_keys_bytes has, when
+        the list is no longer as long as it was."""
+        facts = getattr(self, "_set_facts", None)
+        if facts is None or facts["size"] != len(self.validators):
+            facts = self._set_facts = {"size": len(self.validators)}
+        return facts
+
+    def address_index(self) -> dict[bytes, int]:
+        """address -> index in set order.  Of two validators with one
+        address the first wins, as in a scan from the front."""
+        facts = self._facts()
+        index = facts.get("address_index")
+        if index is None:
+            index = facts["address_index"] = {}
+            for i, v in enumerate(self.validators):
+                index.setdefault(v.address, i)
+        return index
+
+    def voting_powers(self) -> np.ndarray:
+        """The voting powers in set order (int64; a set's total is
+        capped at MaxInt64/8)."""
+        facts = self._facts()
+        powers = facts.get("voting_powers")
+        if powers is None:
+            powers = facts["voting_powers"] = np.array(
+                [v.voting_power for v in self.validators], dtype=np.int64
+            )
+        return powers
+
     def get_by_address(self, address: bytes) -> tuple[int, Validator | None]:
-        for i, v in enumerate(self.validators):
-            if v.address == address:
-                return i, v
-        return -1, None
+        i = self.address_index().get(address)
+        if i is None:
+            return -1, None
+        return i, self.validators[i]
 
     def validator_blocks_the_chain(self, address: bytes) -> bool:
         """True if this validator alone holds > 1/3 power, i.e. the chain
@@ -200,10 +242,13 @@ class ValidatorSet:
 
     def all_keys_have_same_type(self) -> bool:
         """Batch-verification precondition (validator_set.go AllKeysHaveSameType)."""
-        if not self.validators:
-            return True
-        t = self.validators[0].pub_key.type
-        return all(v.pub_key.type == t for v in self.validators)
+        facts = self._facts()
+        same = facts.get("same_key_type")
+        if same is None:
+            same = facts["same_key_type"] = (
+                len({v.pub_key.type for v in self.validators}) <= 1
+            )
+        return same
 
     def pub_keys_bytes(self) -> list[bytes]:
         """Raw pubkeys in set order, cached — the key for the device-side
@@ -336,7 +381,9 @@ class ValidatorSet:
 
         self.validators = sorted(merged.values(), key=_val_sort_key)
         self._total_voting_power = None
-        self._pub_keys_bytes = None  # membership changed: drop pubkey cache
+        # membership changed: drop the pubkey cache and the per-set facts
+        self._pub_keys_bytes = None
+        self._set_facts = None
         self._set_hash = None
         self._update_total_voting_power()
         if self.proposer is not None and self.proposer.address not in merged:

@@ -403,6 +403,13 @@ class Hub:
             "lane=uncached|comb, reason=below_batch_min: narrower than "
             "COMETBFT_TPU_DEVICE_BATCH_MIN)",
         )
+        self.commit_assemble_rows = r.counter(
+            "commit_assemble_rows_total",
+            "Commit rows whose sign-bytes types/validation encoded for a "
+            "batch (label path=columns|per_row: columns = one numpy pass "
+            "over the commit's timestamps, per_row = the per-row encoder, "
+            "taken for a commit with a timestamp outside int64)",
+        )
         self.light_hops = r.counter(
             "light_hops_total",
             "Hops a light client tried (light/client: one verifier.verify "

@@ -89,6 +89,22 @@ def resolve_mode(pubkeys: list[bytes] | None, key_type: str = "ed25519"):
     return ("comb", global_cache().ensure(list(pubkeys)))
 
 
+# What add() holds a row to, by the mode's lane: the name in the refusal,
+# the pubkey and signature lengths, and the bound on the message.
+_ED25519_ROWS = (
+    "ed25519", frozenset({32}), frozenset({64}),
+    1 << 24,  # the comb payload's mlen field is 3 bytes (models/comb_verifier)
+)
+_ROW_RULES = {
+    # 48-byte compressed G1 pubkey, 96-byte compressed G2 sig
+    "bls": ("bls12-381", frozenset({48}), frozenset({96}), None),
+    # 33-byte compressed (cosmos, 64-byte r||s), 65-byte uncompressed
+    # (eth, 65-byte R||S||V), or 20-byte sender address (ecrecover,
+    # 65-byte R||S||V) wire shapes
+    "secp": ("secp256k1", frozenset({20, 33, 65}), frozenset({64, 65}), None),
+}
+
+
 class ServiceBatchVerifier:
     """BatchVerifier bound to a priority class of the verify service.
 
@@ -127,28 +143,38 @@ class ServiceBatchVerifier:
         return self._tenant
 
     def add(self, pub_key: bytes, msg: bytes, sig: bytes) -> None:
-        if self._mode[0] == "bls":
-            # 48-byte compressed G1 pubkey, 96-byte compressed G2 sig
-            if len(pub_key) != 48 or len(sig) != 96:
-                raise ValueError("malformed bls12-381 pubkey or signature")
-            self._items.append((pub_key, msg, sig))
-            return
-        if self._mode[0] == "secp":
-            # 33-byte compressed (cosmos, 64-byte r||s), 65-byte
-            # uncompressed (eth, 65-byte R||S||V), or 20-byte sender
-            # address (ecrecover, 65-byte R||S||V) wire shapes
-            if len(pub_key) not in (20, 33, 65) or len(sig) not in (64, 65):
-                raise ValueError("malformed secp256k1 pubkey or signature")
-            self._items.append((pub_key, msg, sig))
-            return
-        if len(pub_key) != 32 or len(sig) != 64:
-            raise ValueError("malformed ed25519 pubkey or signature")
-        if len(msg) >= 1 << 24:
-            # the comb payload's mlen field is 3 bytes (models/
-            # comb_verifier); raise at add() time like CombBatchVerifier
-            # did, not as a deferred dispatch failure
+        name, pub_lens, sig_lens, msg_bound = _ROW_RULES.get(
+            self._mode[0], _ED25519_ROWS
+        )
+        if len(pub_key) not in pub_lens or len(sig) not in sig_lens:
+            raise ValueError(f"malformed {name} pubkey or signature")
+        if msg_bound is not None and len(msg) >= msg_bound:
+            # raise at add() time like CombBatchVerifier did, not as a
+            # deferred dispatch failure
             raise ValueError("message too large for batch verification")
         self._items.append((pub_key, msg, sig))
+
+    def add_many(
+        self, pub_keys: list[bytes], msgs: list[bytes], sigs: list[bytes]
+    ) -> None:
+        """add() for a whole batch handed over as three columns of one
+        length: the same checks, the rows kept in the columns' order,
+        and a ValueError for the first row add() would have refused
+        (the rows before it are kept, as after that many add() calls)."""
+        _, pub_lens, sig_lens, msg_bound = _ROW_RULES.get(
+            self._mode[0], _ED25519_ROWS
+        )
+        rows = zip(pub_keys, msgs, sigs, strict=True)
+        if (
+            set(map(len, pub_keys)) <= pub_lens
+            and set(map(len, sigs)) <= sig_lens
+            and (msg_bound is None
+                 or max(map(len, msgs), default=0) < msg_bound)
+        ):
+            self._items.extend(rows)
+            return
+        for row in rows:  # some row is malformed: find it as add() does
+            self.add(*row)
 
     def _service(self) -> VerifyService:
         if self._svc is None:

@@ -11,6 +11,8 @@ whole message varint-length-delimited (protoio MarshalDelimited).
 
 from __future__ import annotations
 
+import numpy as np
+
 from .proto import Message, Field, encode_delimited, encode_varint
 
 # SignedMsgType enum (types.proto SIGNED_MSG_TYPE_*)
@@ -130,6 +132,25 @@ class _CanonicalVoteSuffix(Message):
 
 
 _TS_TAG = bytes([5 << 3 | 2])  # field 5, length-delimited
+_SECONDS_TAG = 1 << 3  # Timestamp.seconds, varint
+_NANOS_TAG = 2 << 3  # Timestamp.nanos, varint
+
+
+def vote_sign_bytes_frame(
+    chain_id: str,
+    msg_type: int,
+    height: int,
+    round_: int,
+    block_id: CanonicalBlockID | None,
+) -> tuple[bytes, bytes]:
+    """What every vote of one (type, height, round, block_id, chain)
+    shares: the encoded fields before the timestamp and the encoded
+    fields after it."""
+    prefix = _CanonicalVotePrefix(
+        type=msg_type, height=height, round=round_, block_id=block_id
+    ).encode()
+    suffix = _CanonicalVoteSuffix(chain_id=chain_id).encode()
+    return prefix, suffix
 
 
 def make_vote_sign_bytes_batch(
@@ -141,15 +162,12 @@ def make_vote_sign_bytes_batch(
 ):
     """Returns sign_bytes(timestamp) closing over the once-encoded
     prefix (fields 1-4) and suffix (chain_id): only the ~13-byte
-    timestamp message re-encodes per signature.  For a 10k-validator
-    commit this is the difference between 10k full canonical encodes
-    and 10k tiny splices on the batch-assembly hot path
-    (types/validation.go:324 does the full encode per sig).
-    Byte-identical to vote_sign_bytes (differential-tested)."""
-    prefix = _CanonicalVotePrefix(
-        type=msg_type, height=height, round=round_, block_id=block_id
-    ).encode()
-    suffix = _CanonicalVoteSuffix(chain_id=chain_id).encode()
+    timestamp message re-encodes per signature.  The per-row form of
+    vote_sign_bytes_columns below; byte-identical to vote_sign_bytes
+    (differential-tested)."""
+    prefix, suffix = vote_sign_bytes_frame(
+        chain_id, msg_type, height, round_, block_id
+    )
 
     def sign_bytes(timestamp: Timestamp) -> bytes:
         ts_payload = timestamp.encode()
@@ -163,6 +181,89 @@ def make_vote_sign_bytes_batch(
         return encode_varint(len(body)) + body
 
     return sign_bytes
+
+
+# 2^0, 2^7, ..., 2^63: a uint64 takes as many varint bytes as it reaches
+_VARINT_STEPS = np.array([1 << s for s in range(0, 64, 7)], dtype=np.uint64)
+
+
+def _varint_widths(u: np.ndarray) -> np.ndarray:
+    """Bytes each uint64 takes as a varint, 0 for 0 (proto3 leaves a
+    zero scalar out, tag and all)."""
+    return np.searchsorted(_VARINT_STEPS, u, side="right")
+
+
+def _put_varints(buf: np.ndarray, col: int, u: np.ndarray, width: int) -> None:
+    """Write the uint64s ``u``, all ``width`` bytes wide as varints,
+    into columns col..col+width of the rows of ``buf``."""
+    shifts = np.arange(0, 7 * width, 7, dtype=np.uint64)
+    b = (u >> shifts[:, None]).astype(np.uint8)  # one row a varint byte
+    b &= 0x7F
+    b[:-1] |= 0x80  # continuation bit on every byte but the last
+    buf[:, col:col + width] = b.T
+
+
+def _encode_rows(
+    frame: tuple[bytes, bytes], sec_u: np.ndarray, sec_w: int,
+    nano_u: np.ndarray, nano_w: int,
+) -> list[bytes]:
+    """The sign-bytes of rows that share a frame and the varint widths
+    of their seconds and nanos (0: the field is left out): one template
+    row, repeated, and the two varints written down their columns."""
+    prefix, suffix = frame
+    ts = (bytes([_SECONDS_TAG]) + bytes(sec_w) if sec_w else b"") + (
+        bytes([_NANOS_TAG]) + bytes(nano_w) if nano_w else b""
+    )
+    body = prefix + _TS_TAG + encode_varint(len(ts)) + ts + suffix
+    row = encode_varint(len(body)) + body
+    buf = np.empty((len(sec_u), len(row)), np.uint8)
+    buf[:] = np.frombuffer(row, np.uint8)
+    col = len(row) - len(suffix) - len(ts)
+    if sec_w:
+        _put_varints(buf, col + 1, sec_u, sec_w)
+        col += 1 + sec_w
+    if nano_w:
+        _put_varints(buf, col + 1, nano_u, nano_w)
+    # one bytes object a row: a void scalar's tolist() is its bytes
+    return buf.view(f"V{len(row)}").ravel().tolist()
+
+
+def vote_sign_bytes_columns(
+    frames: list[tuple[bytes, bytes]], kinds, seconds, nanos
+) -> list[bytes] | None:
+    """The sign-bytes of many votes in one numpy pass: row i is the
+    vote of frame ``frames[kinds[i]]`` (vote_sign_bytes_frame) with the
+    timestamp (seconds[i], nanos[i]); byte-identical to vote_sign_bytes
+    row for row (differential-tested).
+
+    Rows are grouped by (frame, varint width of seconds, of nanos): a
+    group's rows have one length and differ only in the timestamp's
+    varint bytes (_encode_rows).  A real commit has a handful of groups
+    (seconds are five bytes wide until 2106, nanos one to five); the
+    cost does not depend on whether the timestamps are equal.
+
+    Returns None where the columns cannot hold the input, i.e. seconds
+    or nanos are not all int64 (a Python int beyond 64 bits, a bool, a
+    float): the caller encodes such a commit row by row."""
+    kinds = np.asarray(kinds)
+    seconds = np.asarray(seconds)
+    nanos = np.asarray(nanos)
+    if len(kinds) == 0:
+        return []
+    if seconds.dtype != np.int64 or nanos.dtype != np.int64:
+        return None
+    sec_u = seconds.view(np.uint64)  # two's complement, like encode_varint
+    nano_u = nanos.view(np.uint64)
+    key = (kinds * 11 + _varint_widths(sec_u)) * 11 + _varint_widths(nano_u)
+    out: list = [None] * len(kinds)
+    for k in np.unique(key).tolist():
+        at = np.flatnonzero(key == k)
+        chunks = _encode_rows(
+            frames[k // 121], sec_u[at], k // 11 % 11, nano_u[at], k % 11
+        )
+        for r, chunk in zip(at.tolist(), chunks):
+            out[r] = chunk
+    return out
 
 
 def proposal_sign_bytes(
