@@ -226,15 +226,18 @@ class ValsetCombCache:
                 return e
             if _count:
                 _mhub().comb_table_cache.inc(result="miss")
-            base = self._newest()
-            entry = self._build(pubkeys, base)
-            with self._mtx:
-                self._entries[fp] = entry
-                held = sum(e.tables.nbytes for e in self._entries.values())
-                while held > self._max_bytes and len(self._entries) > 1:
-                    _, oldest = self._entries.popitem(last=False)
-                    held -= oldest.tables.nbytes
-                self._building.pop(fp, None)
+            bind: dict = {}  # the span's labels; _build says what it did
+            with tracing.span("verify.table_bind", bind):
+                base = self._newest()
+                entry = self._build(pubkeys, base, bind)
+                with self._mtx:
+                    self._entries[fp] = entry
+                    held = sum(e.tables.nbytes for e in self._entries.values())
+                    while held > self._max_bytes and len(self._entries) > 1:
+                        _, oldest = self._entries.popitem(last=False)
+                        held -= oldest.tables.nbytes
+                        _mhub().comb_table_evictions.inc()
+                    self._building.pop(fp, None)
             return entry
 
     def ensure_async(self, pubkeys: list[bytes]) -> _CacheEntry | None:
@@ -282,8 +285,12 @@ class ValsetCombCache:
 
     @staticmethod
     def _build(
-        pubkeys: list[bytes], base: _CacheEntry | None = None
+        pubkeys: list[bytes], base: _CacheEntry | None = None,
+        bind: dict | None = None,
     ) -> _CacheEntry:
+        """One bind.  ``bind`` takes what it came to for the caller's
+        span: ``kind`` (full | incremental), ``fresh`` (keys built) and
+        ``lanes``; the hub counts the same."""
         import jax.numpy as jnp
 
         mesh = active_mesh()
@@ -306,7 +313,13 @@ class ValsetCombCache:
                 else:
                     reuse.append((i, j))
         pub_arr = np.frombuffer(b"".join(pubkeys), dtype=np.uint8).reshape(-1, 32)
+        if bind is None:
+            bind = {}
+        bind["lanes"] = len(pubkeys)
         if base is None or not reuse:
+            bind.update(kind="full", fresh=len(index))
+            _mhub().comb_table_bind.inc(kind="full")
+            _mhub().comb_fresh_keys.inc(len(index))
             tables, valid = _build_tables(pub_arr)
             return _finish_entry(tables, valid, pub_arr, index, mesh)
 
@@ -320,6 +333,10 @@ class ValsetCombCache:
         # fuses it instead of materializing intermediate full-size copies
         # (an entry is ~1.5 GB at V=10k; transient copies would OOM HBM).
         V = len(pubkeys)
+        # pad lanes repeat a key: fresh lanes can outnumber fresh keys
+        bind.update(kind="incremental", fresh=len({pubkeys[i] for i in fresh}))
+        _mhub().comb_table_bind.inc(kind="incremental")
+        _mhub().comb_fresh_keys.inc(bind["fresh"])
         if fresh:
             bucket = 1 << (len(fresh) - 1).bit_length()
             padded = [pubkeys[i] for i in fresh]
@@ -330,17 +347,18 @@ class ValsetCombCache:
         else:
             t_new = base.tables[..., :0]
             v_new = base.valid[:0]
-        tables, valid = _assemble_churn_jit(
-            base.tables,
-            base.valid,
-            t_new,
-            v_new,
-            jnp.asarray(np.asarray([i for i, _ in reuse], np.int32)),
-            jnp.asarray(np.asarray([j for _, j in reuse], np.int32)),
-            jnp.asarray(np.asarray(fresh, np.int32)),
-            V,
-        )
-        return _finish_entry(tables, valid, pub_arr, index, mesh)
+        with tracing.span("verify.table_assemble"):
+            tables, valid = _assemble_churn_jit(
+                base.tables,
+                base.valid,
+                t_new,
+                v_new,
+                jnp.asarray(np.asarray([i for i, _ in reuse], np.int32)),
+                jnp.asarray(np.asarray([j for _, j in reuse], np.int32)),
+                jnp.asarray(np.asarray(fresh, np.int32)),
+                V,
+            )
+            return _finish_entry(tables, valid, pub_arr, index, mesh)
 
 
 def _build_tables(pub_arr: np.ndarray):

@@ -423,6 +423,24 @@ class Hub:
             "building; building = async build in flight, batch routed "
             "to the uncached kernel)",
         )
+        self.comb_table_bind = r.counter(
+            "verify_comb_table_bind_total",
+            "Comb-table binds (label kind=full|incremental: full = every "
+            "key of the set built, incremental = the rows that stay "
+            "gathered from the newest entry and the fresh keys built; "
+            "one bind a miss of verify_comb_table_cache_total)",
+        )
+        self.comb_fresh_keys = r.counter(
+            "verify_comb_fresh_keys_total",
+            "Distinct keys whose comb tables a bind built (a full bind "
+            "builds the set's, an incremental one those the newest "
+            "entry does not hold)",
+        )
+        self.comb_table_evictions = r.counter(
+            "verify_comb_table_evictions_total",
+            "Comb-table entries the cache dropped, oldest first, to "
+            "stay within its bytes bound",
+        )
         self.comb_program_cache = r.counter(
             "verify_comb_program_cache_total",
             "Look-ups of the single-device comb verify program (label "
