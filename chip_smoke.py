@@ -37,6 +37,13 @@ rejected or timed out, the span ring holds no fallback span, and every
 batch the scheduler dispatched was waited for on the device exactly
 once.
 
+The exponentiation of point decompression (ops/field.pow_p58) runs on a
+TPU as one on-chip kernel, whose Mosaic lowering no CPU test sees: at
+both lane counts the run compares it (and F.invert) on the device with
+the array form, limb for limb, on adversarial inputs, and fails unless
+the gauge cometbft_verify_comb_pow_form names ``kernel`` for each
+compiled comb program (check_pow_kernel, PR 35).
+
 Keys, transactions and timestamps come from SEED.  A run in which every
 check held writes two lines to stdout and exits 0: the facts it
 gathered (versions, widths, batches per program, the counters, set-up
@@ -416,6 +423,49 @@ def blocksync_apply(chain: Chain, timeout_s: float) -> None:
 # ------------------------------------------------ which route served what
 
 
+def check_pow_kernel(lanes: int) -> None:
+    """ops/field's exponentiations as the device programs run them
+    (F.pow_form(): on a TPU one on-chip kernel, the Mosaic lowering no
+    CPU test sees) against the array form on the same device, limb for
+    limb, and against Python's pow, on adversarial inputs: 0, 1, p - 1,
+    non-canonical values up to 2^264, signed limbs at the MULIN bounds,
+    random elements for the rest."""
+    import jax
+    import numpy as np
+
+    from cometbft_tpu.ops import field as F
+
+    rng = np.random.default_rng(SEED + lanes)
+    top = np.full(F.NLIMBS, 8204, dtype=np.int64)
+    top[0] = 14336
+    zigzag = top * np.where(np.arange(F.NLIMBS) % 2, -1, 1)
+    values = [0, 1, F.P - 1, F.P, F.P + 3, (1 << 255) - 1, (1 << 256) - 1,
+              (1 << 264) - 1, (1 << 264) - 9728]
+    cols = [[(v >> (F.BITS * i)) & F.MASK for i in range(F.NLIMBS)]
+            for v in values] + [top, -top, zigzag, -zigzag]
+    cols += [F.to_limbs(int.from_bytes(rng.bytes(32), "little"))
+             for _ in range(lanes - len(cols))]
+    x = np.stack(cols, axis=-1).astype(np.int32)  # (22, lanes)
+    freeze = jax.jit(F.freeze)
+    for name, shipped, array, exponent in (
+        ("pow_p58", F.pow_p58, F._pow_p58_chain, (F.P - 5) // 8),
+        ("invert", F.invert, F._invert_chain, F.P - 2),
+    ):
+        got = np.asarray(jax.jit(shipped)(x))
+        require(
+            np.array_equal(got, np.asarray(jax.jit(array)(x))),
+            f"{name} at {lanes} lanes: the {F.pow_form()} form and the "
+            "array form differ",
+        )
+        canon = np.asarray(freeze(got))
+        for i in list(range(16)) + [lanes // 2, lanes - 1]:
+            require(
+                F.from_limbs(canon[:, i])
+                == pow(F.from_limbs(x[:, i]), exponent, F.P),
+                f"{name} at {lanes} lanes: lane {i} is not x^e mod p",
+            )
+
+
 def _counter_total(counter) -> float:
     return sum(float(line.rsplit(" ", 1)[1]) for line in counter.expose())
 
@@ -681,6 +731,24 @@ def run(width_large: int, width_small: int, facts: dict) -> dict:
     )
     if not all(facts["comb_fold_chains"].values()):
         problems.append("a compiled comb program set no fold-chains gauge")
+    # the exponentiation of decompress: one on-chip kernel on a TPU
+    # (what the gauge has to say, so not asked of ops/field.pow_form)
+    form = "kernel" if jax.default_backend() == "tpu" else "array"
+    facts["comb_pow_form"] = {
+        str(n): [f for f in ("kernel", "array")
+                 if hub().comb_pow_form.value(lanes=str(n), form=f)]
+        for n in sorted(lanes)
+    }
+    print(
+        f"chip_smoke: comb_pow_form {facts['comb_pow_form']}",
+        file=sys.stderr, flush=True,
+    )
+    if any(got != [form] for got in facts["comb_pow_form"].values()):
+        problems.append(f"a compiled comb program's pow form is not {form}")
+    t0 = time.monotonic()
+    for n in sorted(lanes):
+        check_pow_kernel(n)
+    timed("pow_kernel_check", t0)
     compiled_comb = rep["comb_program_cache"]["compile"] - comb_compiles_0
     if compiled_comb > len(lanes):
         problems.append(

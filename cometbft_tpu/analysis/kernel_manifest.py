@@ -129,6 +129,15 @@ DIGITS = (0, 4095)  # canonical 12-bit limb digit, ops/field.py freeze()
 FLAG = (0, 1)  # bit-packed / boolean-as-int payload field
 
 
+# ops/field.py's bound contract, one (lo, hi) per limb row: limb 0 takes
+# the top carry's fold and is bounded wider than limbs 1..21.  MULIN is
+# what mul/square accept.  CARRIED is what the interval proof certifies
+# of their output: a carried limb in [-2048, 2047] plus its carry-in,
+# and above limb 0 the fold's (19q mod 8) * 2^9 <= 3584
+MULIN = ((-14336, 14336),) + ((-8204, 8204),) * 21
+CARRIED = ((-2048, 5631),) + ((-2051, 2051),) * 21
+
+
 KERNELS: tuple[Kernel, ...] = (
     # ---- ops/comb.py — the validator-set fast path
     Kernel(
@@ -151,6 +160,31 @@ KERNELS: tuple[Kernel, ...] = (
         # scan (K = 8 at the 4-lane trace)
         max_eqns=39_000,  # measured 29,892
         arg_ranges=(DIGITS, None, None, None, None, DIGITS),
+    ),
+    # ---- ops/field.py — the exponentiation chains in row form: the body
+    # of the on-chip kernel that F.pow_p58 / F.invert run as on a TPU
+    # (ops/field._on_chip), traced here as the plain JAX it is.  No
+    # program of its own: it rides inside every program that
+    # decompresses a point or normalizes a table.  Trace shape: rows of
+    # (1, V) lanes; the chip's are (S, 128).
+    Kernel(
+        name="field_pow_p58_rows",
+        fn="cometbft_tpu.ops.field:pow_p58_rows",
+        args=(i32(22, 1, V),),
+        out=(i32(22, 1, V),),
+        max_eqns=4_000,  # measured 2,957: one squaring and one multiplication
+        # body (every limb row its own equation), jitted and called 20 times
+        arg_ranges=(MULIN,),
+        out_ranges=(CARRIED,),
+    ),
+    Kernel(
+        name="field_invert_rows",
+        fn="cometbft_tpu.ops.field:invert_rows",
+        args=(i32(22, 1, V),),
+        out=(i32(22, 1, V),),
+        max_eqns=4_000,  # measured 2,958
+        arg_ranges=(MULIN,),
+        out_ranges=(CARRIED,),
     ),
     # ---- ops/ed25519.py — the uncached Straus kernel
     Kernel(
@@ -454,6 +488,10 @@ KERNELS: tuple[Kernel, ...] = (
 # any site missing here; kernelcheck fails any value naming no kernel.
 
 JIT_SITES: dict[str, str] = {
+    # the row form's two bodies, jitted so that a chain traces each once
+    # (calls inside the on-chip kernel's body, not programs of their own)
+    "cometbft_tpu/ops/field.py::_rows_square": "field_pow_p58_rows",
+    "cometbft_tpu/ops/field.py::_rows_mul": "field_pow_p58_rows",
     "cometbft_tpu/ops/comb.py::build_a_tables": "comb_build_a_tables",
     "cometbft_tpu/ops/bls381.py::aggregate_g1": "bls381_aggregate_g1",
     "cometbft_tpu/ops/bls381.py::validate_g1": "bls381_validate_g1",

@@ -1868,15 +1868,27 @@ def _input_ivals(kernel) -> list[IVal]:
     out = []
     for arg, rng in zip(kernel.args, ranges):
         dt = np.dtype(arg.dtype)
-        lo, hi = rng if rng is not None else _dtype_range(dt)
-        out.append(
-            IVal(
-                np.full(arg.shape, lo, np.int64),
-                np.full(arg.shape, hi, np.int64),
-                dt,
-            )
+        lo, hi = _range_bounds(
+            rng if rng is not None else _dtype_range(dt), arg.shape
         )
+        out.append(IVal(lo, hi, dt))
     return out
+
+
+def _range_bounds(rng, shape) -> tuple[np.ndarray, np.ndarray]:
+    """A declared range as (lo, hi) arrays of ``shape``: one ``(lo, hi)``
+    pair for the whole array, or one pair per index of the leading axis
+    (a field element's limb rows, whose first limb is bounded wider)."""
+    pairs = np.asarray(rng, dtype=np.int64)
+    if pairs.ndim == 2:
+        if len(pairs) != shape[0]:
+            raise ValueError(
+                f"{len(pairs)} per-row ranges for a leading axis of "
+                f"{shape[0]}"
+            )
+        pairs = pairs.T.reshape((2, shape[0]) + (1,) * (len(shape) - 1))
+    return (np.broadcast_to(pairs[0], shape).copy(),
+            np.broadcast_to(pairs[1], shape).copy())
 
 
 # ---------------------------------------------------------- kernel check
@@ -1951,13 +1963,14 @@ def check_kernel(kernel) -> RangeReport:
             for i, (rng, v) in enumerate(zip(out_ranges, outs)):
                 if rng is None:
                     continue
-                lo, hi = rng
-                vlo = int(v.lo.min()) if v.lo.size else lo
-                vhi = int(v.hi.max()) if v.hi.size else hi
-                if vlo < lo or vhi > hi:
+                lo, hi = _range_bounds(rng, v.shape)
+                outside = (v.lo < lo) | (v.hi > hi)
+                if outside.any():
+                    where = np.argmax(outside)
                     ctx.finding(
-                        f"output {i} range [{vlo}, {vhi}] escapes the "
-                        f"declared [{lo}, {hi}]"
+                        f"output {i} range [{int(v.lo.min())}, "
+                        f"{int(v.hi.max())}] escapes the declared "
+                        f"[{int(lo.flat[where])}, {int(hi.flat[where])}]"
                     )
 
     messages: list[str] = []

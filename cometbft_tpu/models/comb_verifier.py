@@ -843,11 +843,14 @@ class CombBatchVerifier:
         hub = _mhub()
         hub.comb_program_cache.inc(result="compile" if compiled else "hit")
         if compiled:
-            from ..ops import comb
+            from ..ops import comb, field
 
-            # the schedule is fixed when the program is traced
+            # both are fixed when the program is traced
             hub.comb_fold_chains.set(
                 comb.fold_chains(e.vpad), lanes=str(e.vpad)
+            )
+            hub.comb_pow_form.set(
+                1, lanes=str(e.vpad), form=field.pow_form()
             )
         return prog
 

@@ -29,13 +29,24 @@ Radix 2^12 ⇒ 22 limbs span 264 bits; 2^264 ≡ 19·2^9 = 9728 (mod p).  The
 top-limb carry (weight 2^264) folds back as q·19·2^9, decomposed as
 (19q mod 8)·2^9 into limb 0 plus (19q div 8) into limb 1 so the addend never
 exceeds int32 range even for large q.
+
+Two forms of the exponentiation chains (invert, pow_p58).  The array form
+is what every backend but a TPU traces: each mul/square some nineteen XLA
+fusions over whole (22, L) arrays.  On a TPU the 263 dependent steps of a
+chain run as ONE on-chip kernel tiled over the lanes ("the row form",
+below the chains): the same arithmetic, limb for limb, with every limb a
+row of lanes that stays on the chip from the first squaring to the last
+multiplication.  Which form a program takes is read off the backend it
+is traced for (_for_tpu); no caller chooses.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -212,58 +223,238 @@ def mul_small(a, k: int):
     return carry(a * jnp.int32(k), rounds=3)
 
 
+def _pow2k(a, k: int, square_fn):
+    if k <= 4:
+        for _ in range(k):
+            a = square_fn(a)
+        return a
+    return lax.fori_loop(0, k, lambda _, x: square_fn(x), a)
+
+
 def pow2k(a, k: int):
     """a^(2^k) by k squarings.
 
     Long runs use lax.fori_loop so the traced graph stays one square body
     regardless of k (XLA compiles once, loops on device).
     """
-    if k <= 4:
-        for _ in range(k):
-            a = square(a)
-        return a
-    return lax.fori_loop(0, k, lambda _, x: square(x), a)
+    return _pow2k(a, k, square)
 
 
-def _chain_250(x):
+class _Form(NamedTuple):
+    """The three operations an exponentiation chain is written over."""
+
+    square: Callable
+    mul: Callable
+    pow2k: Callable
+
+
+_ARRAY = _Form(square, mul, pow2k)
+
+
+def _chain_250(x, f: _Form = _ARRAY):
     """x^(2^250 - 1) — shared prefix of the invert and sqrt chains.
 
     Classic curve25519 square-and-multiply ladder (public-domain structure).
     Returns (x^(2^250-1), x^11).
     """
-    z2 = square(x)                        # 2
-    z8 = pow2k(z2, 2)                     # 8
-    z9 = mul(x, z8)                       # 9
-    z11 = mul(z2, z9)                     # 11
-    z22 = square(z11)                     # 22
-    z_5_0 = mul(z9, z22)                  # 2^5 - 1 = 31
-    z_10_5 = pow2k(z_5_0, 5)
-    z_10_0 = mul(z_10_5, z_5_0)           # 2^10 - 1
-    z_20_10 = pow2k(z_10_0, 10)
-    z_20_0 = mul(z_20_10, z_10_0)         # 2^20 - 1
-    z_40_20 = pow2k(z_20_0, 20)
-    z_40_0 = mul(z_40_20, z_20_0)         # 2^40 - 1
-    z_50_10 = pow2k(z_40_0, 10)
-    z_50_0 = mul(z_50_10, z_10_0)         # 2^50 - 1
-    z_100_50 = pow2k(z_50_0, 50)
-    z_100_0 = mul(z_100_50, z_50_0)       # 2^100 - 1
-    z_200_100 = pow2k(z_100_0, 100)
-    z_200_0 = mul(z_200_100, z_100_0)     # 2^200 - 1
-    z_250_50 = pow2k(z_200_0, 50)
-    z_250_0 = mul(z_250_50, z_50_0)       # 2^250 - 1
+    z2 = f.square(x)                          # 2
+    z8 = f.pow2k(z2, 2)                       # 8
+    z9 = f.mul(x, z8)                         # 9
+    z11 = f.mul(z2, z9)                       # 11
+    z22 = f.square(z11)                       # 22
+    z_5_0 = f.mul(z9, z22)                    # 2^5 - 1 = 31
+    z_10_5 = f.pow2k(z_5_0, 5)
+    z_10_0 = f.mul(z_10_5, z_5_0)             # 2^10 - 1
+    z_20_10 = f.pow2k(z_10_0, 10)
+    z_20_0 = f.mul(z_20_10, z_10_0)           # 2^20 - 1
+    z_40_20 = f.pow2k(z_20_0, 20)
+    z_40_0 = f.mul(z_40_20, z_20_0)           # 2^40 - 1
+    z_50_10 = f.pow2k(z_40_0, 10)
+    z_50_0 = f.mul(z_50_10, z_10_0)           # 2^50 - 1
+    z_100_50 = f.pow2k(z_50_0, 50)
+    z_100_0 = f.mul(z_100_50, z_50_0)         # 2^100 - 1
+    z_200_100 = f.pow2k(z_100_0, 100)
+    z_200_0 = f.mul(z_200_100, z_100_0)       # 2^200 - 1
+    z_250_50 = f.pow2k(z_200_0, 50)
+    z_250_0 = f.mul(z_250_50, z_50_0)         # 2^250 - 1
     return z_250_0, z11
+
+
+def _invert_chain(x, f: _Form = _ARRAY):
+    z_250_0, z11 = _chain_250(x, f)
+    return f.mul(f.pow2k(z_250_0, 5), z11)
+
+
+def _pow_p58_chain(x, f: _Form = _ARRAY):
+    z_250_0, _ = _chain_250(x, f)
+    return f.mul(f.pow2k(z_250_0, 2), x)
 
 
 def invert(x):
     """x^(p-2);  p-2 = 2^255 - 21 = (2^250-1)·2^5 + 11."""
-    z_250_0, z11 = _chain_250(x)
-    return mul(pow2k(z_250_0, 5), z11)
+    return _on_chip(_invert_chain, x) if _for_tpu() else _invert_chain(x)
 
 
 def pow_p58(x):
     """x^((p-5)/8);  (p-5)/8 = 2^252 - 3 = (2^250-1)·2^2 + 1."""
-    z_250_0, _ = _chain_250(x)
-    return mul(pow2k(z_250_0, 2), x)
+    return _on_chip(_pow_p58_chain, x) if _for_tpu() else _pow_p58_chain(x)
+
+
+# ---------------------------------------------------------- the row form
+#
+# The same arithmetic over an element held as a list of its 22 limb ROWS,
+# each a (S, 128) tile of lanes (one vector register at S = 8): products
+# and carries are element-wise operations on whole rows, and nothing
+# indexes, pads or scatters along the limb axis.  This is the body of the
+# on-chip kernel (_on_chip): between its first squaring and its last
+# multiplication no limb leaves the chip, where the array form above
+# compiles to some nineteen fusions a multiplication, each streaming
+# (22, L) arrays through HBM.  It is written with operators alone, so on
+# plain arrays it is ordinary JAX: analysis/rangecheck proves its bounds
+# (manifest kernels field_pow_p58_rows / field_invert_rows); and on numpy
+# int32 rows it is numpy, which is how tests/test_field.py runs whole
+# chains in a second (XLA:CPU takes a quarter of an hour to compile the
+# 34,000 unrolled operations of one chain).  Limb for limb it returns
+# what mul/square return: the conv sums are the same integers and the
+# reduction is _reduce_conv's, row by row.
+
+
+def _rows_carry_round(c):
+    """_carry_round over rows: (rows', top carry)."""
+    q = [(x + (RADIX >> 1)) >> BITS for x in c]
+    r = [x - (qi << BITS) for x, qi in zip(c, q)]
+    return r[:1] + [x + qi for x, qi in zip(r[1:], q[:-1])], q[-1]
+
+
+def _rows_carry(c, rounds: int = 3):
+    for _ in range(rounds):
+        c, top = _rows_carry_round(c)
+        v = top * 19  # _fold_top
+        c[0] = c[0] + (v & 7) * (1 << 9)
+        c[1] = c[1] + (v >> 3)
+    return c
+
+
+def _rows_reduce_conv(c):
+    """_reduce_conv over the 43 rows of a conv."""
+    hi = c[NLIMBS:]
+    # _reduce_conv's three pad limbs are zero until a round's top carry
+    # lands in them: append each round's top carry instead
+    for _ in range(3):
+        hi, top = _rows_carry_round(hi)
+        hi.append(top)
+    lo = [x + h * FOLD for x, h in zip(c[:NLIMBS], hi)]
+    lo[1] = lo[1] + hi[NLIMBS] * FOLD2_SHIFTED
+    lo[2] = lo[2] + hi[NLIMBS + 1] * FOLD2_SHIFTED
+    return _rows_carry(lo)
+
+
+def _rows_conv(terms):
+    """Sum (k, product) terms into the 43 rows of a conv."""
+    c = [None] * (2 * NLIMBS - 1)
+    for k, p in terms:
+        c[k] = p if c[k] is None else c[k] + p
+    return c
+
+
+def _rows_mul(a, b):
+    return _rows_reduce_conv(_rows_conv(
+        (i + j, x * y) for i, x in enumerate(a) for j, y in enumerate(b)
+    ))
+
+
+def _rows_square(a):
+    """The 253 distinct products, the off-diagonal ones against 2a: the
+    conv sums are those of _rows_mul(a, a)."""
+    a2 = [x + x for x in a]
+    return _rows_reduce_conv(_rows_conv(
+        (i + j, (a[i] if i == j else a2[i]) * a[j])
+        for i in range(NLIMBS) for j in range(i, NLIMBS)
+    ))
+
+
+# jitted, so that a chain's trace holds ONE squaring and ONE multiplication
+# body, called 20 times, and not 34,000 row operations: tracing those in
+# Python took 6 s a kernel at every start of every program, with a warm
+# compile cache too (lowering inlines the calls: the kernel is the same)
+_rows_square_j = jax.jit(_rows_square)
+_ROWS = _Form(
+    _rows_square_j, jax.jit(_rows_mul),
+    lambda a, k: _pow2k(a, k, _rows_square_j),
+)
+
+LANES = 128  # a row's minor axis: the lanes of one vector register
+TILE_ROWS = 8  # a row's second-minor axis at most: its sublanes
+
+
+def _over_rows(chain):
+    """chain as a function of the stacked rows (22, S, LANES)."""
+    def stacked(x):
+        return jnp.stack(chain([x[i] for i in range(NLIMBS)], _ROWS))
+    return stacked
+
+
+pow_p58_rows = _over_rows(_pow_p58_chain)
+invert_rows = _over_rows(_invert_chain)
+
+
+def _for_tpu() -> bool:
+    """Whether the program being traced is compiled for a TPU (read when
+    it is traced, like ops/comb.fold_chains: nothing a caller sets).  On
+    every other backend (the CPU tests, the static gates, the host
+    oracle) the array form runs and every traced program stays as it was."""
+    return jax.default_backend() == "tpu"
+
+
+def pow_form() -> str:
+    """The exponentiation's form in programs traced now: "kernel" or
+    "array" (the gauge cometbft_verify_comb_pow_form)."""
+    return "kernel" if _for_tpu() else "array"
+
+
+def _tiles(chain, rows, s: int, interpret: bool = False):
+    """chain over rows (22, R, 128) as one pallas_call whose grid walks
+    tiles of s rows; R is a multiple of s.  interpret is the CPU tests'."""
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        out = chain([x_ref[i] for i in range(NLIMBS)], _ROWS)
+        for i, row in enumerate(out):
+            o_ref[i] = row
+
+    block = pl.BlockSpec((NLIMBS, s, LANES), lambda t: (0, t, 0))
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.int32),
+        grid=(rows.shape[1] // s,),
+        in_specs=[block],
+        out_specs=block,
+        interpret=interpret,
+    )(rows)
+
+
+def tile_rule(lanes: int) -> tuple[int, int]:
+    """(rows, tile) of the kernel's layout for a lane count: the lanes as
+    rows of 128, in tiles of S rows.  S is every row up to 8 (256 lanes:
+    one (22, 2, 128) tile, nothing padded), else 8 with the rows padded
+    up to a multiple (10,112 lanes: 80 rows in ten tiles, one of padding)."""
+    rows = -(-lanes // LANES)
+    tile = min(rows, TILE_ROWS)
+    return -(-rows // tile) * tile, tile
+
+
+def _on_chip(chain, x):
+    """chain(x) as ONE on-chip kernel, tiled over the lane axis by
+    tile_rule; leading batch axes of x (..., 22, L) fold into the lanes.
+    Lanes never interact, so what the padding computes is dropped unread."""
+    rows_first = jnp.moveaxis(x, -2, 0)  # (22, ..., L)
+    flat = rows_first.reshape(NLIMBS, -1)
+    n = flat.shape[1]
+    rows, tile = tile_rule(n)
+    flat = jnp.pad(flat, ((0, 0), (0, rows * LANES - n)))
+    out = _tiles(chain, flat.reshape(NLIMBS, rows, LANES), tile)
+    out = out.reshape(NLIMBS, -1)[:, :n].reshape(rows_first.shape)
+    return jnp.moveaxis(out, 0, -2)
 
 
 def freeze(a):

@@ -455,6 +455,14 @@ class Hub:
             "ops/comb.fold_chains reads K off the lane count when the "
             "program is traced)",
         )
+        self.comb_pow_form = r.gauge(
+            "verify_comb_pow_form",
+            "1 under the form the comb verify program of a lane count "
+            "runs decompress's exponentiation in (labels lanes, "
+            "form=kernel|array; ops/field.pow_form reads it off the "
+            "backend when the program is traced: one on-chip kernel on "
+            "a TPU, the array form elsewhere)",
+        )
         self.secp_pubkey_cache = r.counter(
             "verify_svc_secp_pubkey_cache_total",
             "Decoded-secp256k1-pubkey cache lookups in the MODE_SECP "

@@ -156,6 +156,9 @@ def test_the_program_key_is_lanes_and_payload_width(marker_tables, monkeypatch):
     (key,) = verifier._COMB_PROGRAMS
     assert key == (256, cv._payload_width([(pubs[0], b"v" * mlen, bytes(64))]))
     assert hub().comb_fold_chains.value(lanes="256") == 8
+    # the exponentiation's form, read off the backend: no TPU here
+    assert hub().comb_pow_form.value(lanes="256", form="array") == 1
+    assert hub().comb_pow_form.value(lanes="256", form="kernel") == 0
 
 
 # ------------------------------------------------- the cache, by bytes

@@ -487,6 +487,41 @@ def test_range_gate_fast_hash_plane_clean():
     assert all(r.ok for r in reports)
 
 
+@pytest.mark.parametrize("name", ["field_pow_p58_rows", "field_invert_rows"])
+def test_field_row_kernels_prove_finding_free(name):
+    """The body of the on-chip exponentiation kernel (ops/field, PR 35),
+    every limb row its own variable: MULIN rows in, no int32 overflow in
+    any of a chain's 263 steps, CARRIED rows out, and the certificate agrees."""
+    kernel = manifest.by_name()[name]
+    assert kernel.arg_ranges == (manifest.MULIN,)
+    assert kernel.out_ranges == (manifest.CARRIED,)
+    findings, (report,) = rc.run_check(
+        kernels=[kernel], allowlist=rc.default_allowlist()
+    )
+    assert not findings, "\n".join(f.render() for f in findings)
+    assert report.ok and report.peak_int32 < 2**31
+
+
+def test_per_row_ranges_bound_each_limb_row():
+    """A declared range may be one (lo, hi) per index of the leading
+    axis; an output row outside its own pair is a finding even when it
+    lies inside a wider row's."""
+    m = _fixture_module()
+    m.identity = lambda x: x + jnp.int32(0)
+    m.swap_rows = lambda x: x[::-1]
+    rows = ((-7, 7), (-1, 1))
+    clean = _kernel("identity", (manifest.i32(2, 3),), (manifest.i32(2, 3),),
+                    arg_ranges=(rows,), out_ranges=(rows,))
+    assert rc.check_kernel(clean).ok
+    swapped = _kernel("swap_rows", (manifest.i32(2, 3),), (manifest.i32(2, 3),),
+                      arg_ranges=(rows,), out_ranges=(rows,))
+    report = rc.check_kernel(swapped)
+    assert not report.ok and "escapes the declared [-1, 1]" in report.messages[0]
+    ragged = _kernel("identity", (manifest.i32(3, 3),), (manifest.i32(3, 3),),
+                     arg_ranges=(rows,))
+    assert "per-row ranges" in rc.check_kernel(ragged).messages[0]
+
+
 @pytest.mark.slow
 def test_range_certificates_match_full_manifest():
     """The acceptance gate, in-process: interpret every manifest kernel
