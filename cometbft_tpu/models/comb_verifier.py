@@ -226,7 +226,9 @@ class ValsetCombCache:
                 return e
             if _count:
                 _mhub().comb_table_cache.inc(result="miss")
-            bind: dict = {}  # the span's labels; _build says what it did
+            # the span's labels; _build says what the bind came to
+            bind: dict = {"thread": "background" if threading.current_thread()
+                          .name == "comb-build" else "caller"}
             with tracing.span("verify.table_bind", bind):
                 base = self._newest()
                 entry = self._build(pubkeys, base, bind)
@@ -295,12 +297,10 @@ class ValsetCombCache:
 
         mesh = active_mesh()
         index = {pk: i for i, pk in enumerate(pubkeys)}
-        # pad the lane count to the chip's lane bucket (to the mesh's
-        # width when sharded); pad lanes carry a repeated real key but
-        # are never scattered into (valid rows only come from `index`),
-        # so they do dead-but-defined work
-        d = LANE_BUCKET if mesh is None else mesh.devices.size
-        pad = (-len(pubkeys)) % d
+        # pad lanes carry a repeated real key but are never scattered
+        # into (valid rows only come from `index`), so they do
+        # dead-but-defined work
+        pad = lane_count(len(pubkeys), mesh) - len(pubkeys)
         if pad:
             pubkeys = list(pubkeys) + [pubkeys[0]] * pad
         reuse: list[tuple[int, int]] = []  # (new row, base row)
@@ -884,6 +884,13 @@ def _device_verify(tables, valid, pubs, payload):
         bits = jnp.packbits(ok & live)
         all_ok = jnp.all(ok | ~live).astype(jnp.uint8)
         return jnp.concatenate([bits, all_ok[None]])
+
+
+def lane_count(n_keys: int, mesh) -> int:
+    """The lanes a set of ``n_keys`` binds at: padded to the chip's lane
+    bucket, or to the mesh's width when sharded."""
+    d = LANE_BUCKET if mesh is None else mesh.devices.size
+    return n_keys + (-n_keys) % d
 
 
 def _bucket_mlen(mlen: int) -> int:

@@ -441,6 +441,14 @@ class Hub:
             "Comb-table entries the cache dropped, oldest first, to "
             "stay within its bytes bound",
         )
+        self.comb_warming = r.counter(
+            "verify_comb_warming_total",
+            "Requests of a named set answered by the uncached program "
+            "because the set's comb tables were not resident yet (label "
+            "lanes: the lane count the set binds at; ensure_async "
+            "answered None: its miss, which starts the background bind, "
+            "or a building while that bind runs)",
+        )
         self.comb_program_cache = r.counter(
             "verify_comb_program_cache_total",
             "Look-ups of the single-device comb verify program (label "

@@ -20,6 +20,7 @@ from __future__ import annotations
 import time
 
 from ..utils import tracing
+from ..utils.metrics import hub as _metrics_hub
 from .service import (
     MODE_BLS,
     MODE_PLAIN,
@@ -79,11 +80,13 @@ def resolve_mode(pubkeys: list[bytes] | None, key_type: str = "ed25519"):
 
     if len(pubkeys) < crypto_batch.comb_min():
         return MODE_PLAIN
-    from ..models.comb_verifier import global_cache
+    from ..models.comb_verifier import active_mesh, global_cache, lane_count
 
     if len(pubkeys) >= crypto_batch.comb_async_min():
         entry = global_cache().ensure_async(list(pubkeys))
         if entry is None:
+            _metrics_hub().comb_warming.inc(
+                lanes=str(lane_count(len(pubkeys), active_mesh())))
             return MODE_PLAIN  # tables still warming: uncached kernel
         return ("comb", entry)
     return ("comb", global_cache().ensure(list(pubkeys)))
