@@ -42,7 +42,11 @@ TPU as one on-chip kernel, whose Mosaic lowering no CPU test sees: at
 both lane counts the run compares it (and F.invert) on the device with
 the array form, limb for limb, on adversarial inputs, and fails unless
 the gauge cometbft_verify_comb_pow_form names ``kernel`` for each
-compiled comb program (check_pow_kernel, PR 35).
+compiled comb program (check_pow_kernel, PR 35).  So do the comb's
+add_niels chains and their fold (ops/comb._chains_on_chip): the run
+compares them with the array form on the device at both lane counts and
+fails unless cometbft_verify_comb_chain_form names ``kernel``
+(check_chain_kernel, PR 37).
 
 Keys, transactions and timestamps come from SEED.  A run in which every
 check held writes two lines to stdout and exits 0: the facts it
@@ -466,6 +470,59 @@ def check_pow_kernel(lanes: int) -> None:
             )
 
 
+def check_chain_kernel(lanes: int) -> None:
+    """ops/comb's chains and their fold as the device programs run them
+    (comb.chain_form(): on a TPU one on-chip kernel, K from
+    F.chain_rule) against the array form's scan and fold on the same
+    device and the same K, limb for limb: the 86 partials at the bounds
+    the program feeds them (canonical limbs, sign-flipped entries with a
+    negated t2d, lanes of identity partials, limbs at 4095) and -R with
+    TIGHT limbs of either sign."""
+    import jax
+    import numpy as np
+
+    from cometbft_tpu.ops import comb, ed25519 as E, field as F
+
+    rng = np.random.default_rng(SEED + 7 * lanes)
+    k = comb.chains_for(lanes)
+    steps = -(-comb.NPART // k)
+
+    def limbs(shape, lo, hi):
+        return rng.integers(lo, hi + 1, size=shape, dtype=np.int32)
+
+    shape = (steps * k, F.NLIMBS, lanes)
+    yp, ym, t2d = (limbs(shape, 0, F.MASK) for _ in range(3))
+    flip = rng.random((steps * k, 1, lanes)) < 0.5
+    yp, ym, t2d = (np.where(flip, ym, yp), np.where(flip, yp, ym),
+                   np.where(flip, -t2d, t2d))
+    for a, v in ((yp, 1), (ym, 1), (t2d, 0)):
+        a[:, :, 1] = F.MASK
+        a[:, :, 2] = 0  # lane 2: every partial the identity
+        a[:, 0, 2] = v
+    t2d[:, :, 3] = -F.MASK
+    partials = E.Niels(yp, ym, t2d)
+    nr = E.Point(*(limbs((F.NLIMBS, lanes), -2048, 2047) for _ in range(4)))
+
+    def array(partials, nr):
+        return comb._chains_array(
+            E.Niels(*(a.reshape(steps, k, F.NLIMBS, lanes) for a in partials)), nr
+        )
+
+    def shipped(partials, nr):
+        if comb.chain_form() == "kernel":
+            return comb._chains_on_chip([partials], nr, k)
+        return array(partials, nr)
+
+    got = jax.jit(shipped)(partials, nr)
+    want = jax.jit(array)(partials, nr)
+    for g, w in zip(got, want):
+        require(
+            np.array_equal(np.asarray(g), np.asarray(w)),
+            f"chains at {lanes} lanes, K = {k}: the {comb.chain_form()} form "
+            "and the array form differ",
+        )
+
+
 def _counter_total(counter) -> float:
     return sum(float(line.rsplit(" ", 1)[1]) for line in counter.expose())
 
@@ -745,10 +802,26 @@ def run(width_large: int, width_small: int, facts: dict) -> dict:
     )
     if any(got != [form] for got in facts["comb_pow_form"].values()):
         problems.append(f"a compiled comb program's pow form is not {form}")
+    # and its add_niels chains (PR 37), the same way
+    facts["comb_chain_form"] = {
+        str(n): [f for f in ("kernel", "array")
+                 if hub().comb_chain_form.value(lanes=str(n), form=f)]
+        for n in sorted(lanes)
+    }
+    print(
+        f"chip_smoke: comb_chain_form {facts['comb_chain_form']}",
+        file=sys.stderr, flush=True,
+    )
+    if any(got != [form] for got in facts["comb_chain_form"].values()):
+        problems.append(f"a compiled comb program's chain form is not {form}")
     t0 = time.monotonic()
     for n in sorted(lanes):
         check_pow_kernel(n)
     timed("pow_kernel_check", t0)
+    t0 = time.monotonic()
+    for n in sorted(lanes):
+        check_chain_kernel(n)
+    timed("chain_kernel_check", t0)
     compiled_comb = rep["comb_program_cache"]["compile"] - comb_compiles_0
     if compiled_comb > len(lanes):
         problems.append(

@@ -136,6 +136,9 @@ FLAG = (0, 1)  # bit-packed / boolean-as-int payload field
 # and above limb 0 the fold's (19q mod 8) * 2^9 <= 3584
 MULIN = ((-14336, 14336),) + ((-8204, 8204),) * 21
 CARRIED = ((-2048, 5631),) + ((-2051, 2051),) * 21
+# a comb table entry's limbs as a lookup hands them over: canonical
+# digits, negated for a sign-flipped t2d
+NIELS = ((-4095, 4095),) * 22
 
 
 KERNELS: tuple[Kernel, ...] = (
@@ -185,6 +188,22 @@ KERNELS: tuple[Kernel, ...] = (
         max_eqns=4_000,  # measured 2,958
         arg_ranges=(MULIN,),
         out_ranges=(CARRIED,),
+    ),
+    # ---- ops/comb.py — the chain step in row form: the body of the
+    # on-chip kernel the comb's add_niels chains run as on a TPU
+    # (ops/comb._chains_on_chip), the array form's E.add_niels row by row.
+    # The accumulator's coordinates are what a multiplication returns
+    # (CARRIED; the identity lies inside), the Niels point's are table
+    # limbs, sign-flipped or not: every sum that reaches a multiplication
+    # stays in MULIN.  Trace shape: rows of (1, V) lanes.
+    Kernel(
+        name="comb_add_niels_rows",
+        fn="cometbft_tpu.ops.comb:add_niels_rows",
+        args=(i32(22, 1, V),) * 7,
+        out=(i32(22, 1, V),) * 4,
+        max_eqns=3_000,  # measured 2,215: one multiplication body, called 7 times
+        arg_ranges=(CARRIED,) * 4 + (NIELS,) * 3,
+        out_ranges=(CARRIED,) * 4,
     ),
     # ---- ops/ed25519.py — the uncached Straus kernel
     Kernel(
@@ -492,6 +511,8 @@ JIT_SITES: dict[str, str] = {
     # (calls inside the on-chip kernel's body, not programs of their own)
     "cometbft_tpu/ops/field.py::_rows_square": "field_pow_p58_rows",
     "cometbft_tpu/ops/field.py::_rows_mul": "field_pow_p58_rows",
+    # the comb kernel's step body, jitted for the same reason (PR 37)
+    "cometbft_tpu/ops/comb.py::_add_niels_rows": "comb_add_niels_rows",
     "cometbft_tpu/ops/comb.py::build_a_tables": "comb_build_a_tables",
     "cometbft_tpu/ops/bls381.py::aggregate_g1": "bls381_aggregate_g1",
     "cometbft_tpu/ops/bls381.py::validate_g1": "bls381_validate_g1",

@@ -845,12 +845,15 @@ class CombBatchVerifier:
         if compiled:
             from ..ops import comb, field
 
-            # both are fixed when the program is traced
+            # all three are fixed when the program is traced
             hub.comb_fold_chains.set(
-                comb.fold_chains(e.vpad), lanes=str(e.vpad)
+                comb.chains_for(e.vpad), lanes=str(e.vpad)
             )
             hub.comb_pow_form.set(
                 1, lanes=str(e.vpad), form=field.pow_form()
+            )
+            hub.comb_chain_form.set(
+                1, lanes=str(e.vpad), form=comb.chain_form()
             )
         return prog
 

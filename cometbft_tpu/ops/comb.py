@@ -22,9 +22,10 @@ HBM for those doublings entirely:
 verify_cached then needs NO doublings and NO per-signature table build:
    acc = sum_i T[i][k_i][v]  +  sum_i B_TAB[i][s_i]  - R,   check [8]acc = 0
 64 + 22 mixed additions in K parallel chains (K from the lane count:
-fold_chains), K unified ones, and one point decompression (R) per signature,
+chains_for), K unified ones, and one point decompression (R) per signature,
 versus 256 doublings + 128 additions + 2 decompressions + table build for
-the uncached kernel.
+the uncached kernel.  On a TPU the chains and their fold run as one
+on-chip kernel over ops/field's row form (_chains_on_chip).
 
 Verification semantics are identical (ZIP-215 / cofactored; see
 ops/ed25519.py module doc); tests/test_comb.py checks agreement against
@@ -573,46 +574,230 @@ def _lookup_partials(tables, k_dig, s_dig, b_tables):
     return na, nb
 
 
+def chains_for(lanes: int) -> int:
+    """K in the comb programs traced now (the gauge
+    cometbft_verify_comb_fold_chains): on a TPU the kernel's rule
+    (ops/field.chain_rule: the chains one tile's sublanes hold), on every
+    other backend the array form's (fold_chains)."""
+    return F.chain_rule(lanes) if F._for_tpu() else fold_chains(lanes)
+
+
+def chain_form() -> str:
+    """The chains' form in programs traced now: "kernel" or "array" (the
+    gauge cometbft_verify_comb_chain_form); read off the backend, as
+    ops/field.pow_form is."""
+    return "kernel" if F._for_tpu() else "array"
+
+
 def _accumulate_chains(tables, k_dig, s_dig, b_tables, r_pt, chains=None):
     """K parallel chains of mixed additions, then a short fold.
 
     The 86 looked-up partials stay in Niels form (no lift), are padded
     with the Niels identity to K * steps and viewed as (steps, K, 22, V);
     K accumulators start at the identity and take steps = ceil(86 / K)
-    dependent E.add_niels steps (7 field muls each, one rolled body),
-    and the K accumulators fold with -R by unified additions
-    (E.tree_reduce_points, ten muls each).  Field muls a lane: K = 1:
-    612 (one add_niels a position and the R fold, the fewest), 2: 622,
-    4: 656, 8: 696.  K
-    comes from the lane count (fold_chains); chains overrides it for
-    tests and sweeps only.
+    dependent E.add_niels steps (7 field muls each), and the K
+    accumulators fold with -R by unified additions (E.tree_reduce_points,
+    ten muls each).  Field muls a lane: K = 1: 612 (one add_niels a
+    position and the R fold, the fewest), 2: 622, 4: 656, 8: 696.  K
+    comes from the lane count (chains_for); chains overrides it for
+    tests and sweeps only.  On a TPU the chains and the fold run as ONE
+    on-chip kernel (_chains_on_chip), elsewhere as one rolled scan.
     """
-    V = k_dig.shape[-1]
-    K = fold_chains(V) if chains is None else chains
-    steps = -(-NPART // K)
     na, nb = _lookup_partials(tables, k_dig, s_dig, b_tables)
-
     # the benchmark's per-layer readers match on this scope's name: the
     # whole accumulation, chains and fold, reads under it
     with jax.named_scope("tree_reduce"):
-        npad = K * steps - NPART
-        pad = E.niels_identity_like(E.Niels(*(c[:npad] for c in na)))
-        xs = E.Niels(
+        return _sum_partials(na, nb, E.neg(r_pt), chains)
+
+
+def _sum_partials(na, nb, nr, chains=None):
+    """The Niels partials na (64, 22, V) and nb (22, 22, V) and the point
+    nr (22, V) summed: the accumulation after the lookups."""
+    V = nr.x.shape[-1]
+    K = chains_for(V) if chains is None else chains
+    steps = -(-NPART // K)
+    pad = E.niels_identity_like(E.Niels(*(c[: K * steps - NPART] for c in na)))
+    if F._for_tpu():
+        return _chains_on_chip((na, nb, pad), nr, K)
+    return _chains_array(
+        E.Niels(
             *(
-                jnp.concatenate([a, b, i], axis=0).reshape(
-                    steps, K, F.NLIMBS, V
-                )
-                for a, b, i in zip(na, nb, pad)
+                jnp.concatenate(c).reshape(steps, K, F.NLIMBS, V)
+                for c in zip(na, nb, pad)
             )
+        ),
+        nr,
+    )
+
+
+def _chains_array(xs, nr):
+    """The chains (steps, K, 22, V) as one rolled scan of E.add_niels,
+    then the fold of the K accumulators and nr by E.tree_reduce_points."""
+    _, K, _, V = xs.yplusx.shape
+    accs, _ = lax.scan(
+        lambda acc, n: (E.add_niels(acc, n), None), E.identity((K, V)), xs
+    )
+    return E.tree_reduce_points(
+        E.Point(*(jnp.concatenate([a, r[None]], axis=0) for a, r in zip(accs, nr)))
+    )
+
+
+# ------------------------------------------ the chains as one on-chip kernel
+#
+# On a TPU the K chains and the fold run as ONE pallas_call over ops/field's
+# row form: a coordinate is a list of its 22 limb rows, each a (S, 128) tile
+# of lanes, and chain j of K holds sublane rows j * S/K .. (j + 1) * S/K of
+# every row.  The grid walks (tiles, steps), the step axis innermost: the
+# tile's K accumulators stay in its output block, on the chip, from the
+# first step to the last, and each grid point reads one step's partials
+# (3 x 22 rows).  Limb for limb the kernel returns what the array form
+# returns: _rows_mul is F.mul row by row, the adds and subs are the same,
+# and the fold pairs the accumulators as E.tree_reduce_points does.
+
+
+def _rows_add(a, b):
+    return [x + y for x, y in zip(a, b)]
+
+
+def _rows_sub(a, b):
+    return [x - y for x, y in zip(a, b)]
+
+
+def _add_niels_rows(p, n, mul=F._ROWS.mul):
+    """E.add_niels over rows: p the point's 4 coordinates, n the Niels
+    point's 3, each a list of 22 limb rows."""
+    x, y, z, t = p
+    yplusx, yminusx, t2d = n
+    a = mul(_rows_sub(y, x), yminusx)
+    b = mul(_rows_add(y, x), yplusx)
+    c = mul(t, t2d)
+    d = _rows_add(z, z)
+    e, f, g, h = _rows_sub(b, a), _rows_sub(d, c), _rows_add(d, c), _rows_add(b, a)
+    return mul(e, f), mul(g, h), mul(f, g), mul(e, h)
+
+
+# jitted, so that the kernel's trace holds one step body (PR 35: an
+# unwrapped body is seconds of Python trace at every process start)
+_add_niels_rows_j = jax.jit(_add_niels_rows)
+
+_D2_ROWS = [int(c) for c in F.to_limbs(ref.D2)]
+
+
+def _point_add_rows(p, q, mul=F._ROWS.mul):
+    """E.add over rows (ten multiplications, 2d among them)."""
+    a = mul(_rows_sub(p[1], p[0]), _rows_sub(q[1], q[0]))
+    b = mul(_rows_add(p[1], p[0]), _rows_add(q[1], q[0]))
+    c = mul(mul(p[3], q[3]), _D2_ROWS)
+    d = mul(p[2], q[2])
+    d = _rows_add(d, d)
+    e, f, g, h = _rows_sub(b, a), _rows_sub(d, c), _rows_add(d, c), _rows_add(b, a)
+    return mul(e, f), mul(g, h), mul(f, g), mul(e, h)
+
+
+def _fold_rows(acc, nr, k: int, mul=F._ROWS.mul):
+    """The K accumulators of a tile (chain j in sublane rows j * S/K ..)
+    and nr summed in E.tree_reduce_points' order: the lower half of the
+    chains plus the upper, until one is left, then nr."""
+    per = acc[0][0].shape[0] // k
+    while k > 1:
+        k //= 2
+        h = k * per
+        acc = _point_add_rows(
+            tuple([r[:h] for r in c] for c in acc),
+            tuple([r[h : 2 * h] for r in c] for c in acc),
+            mul,
         )
-        accs, _ = lax.scan(
-            lambda acc, n: (E.add_niels(acc, n), None),
-            E.identity((K, V)),
-            xs,
-        )
-        nr = E.neg(r_pt)
-        return E.tree_reduce_points(
-            E.Point(
-                *(jnp.concatenate([a, r[None]], axis=0) for a, r in zip(accs, nr))
+    return _point_add_rows(acc, nr, mul)
+
+
+def add_niels_rows(x, y, z, t, yplusx, yminusx, t2d):
+    """The kernel's step as a function of stacked rows (22, S, 128) a
+    coordinate: manifest kernel comb_add_niels_rows (no program of its
+    own: it rides inside every comb verify program traced for a TPU)."""
+    def rows(a):
+        return [a[i] for i in range(F.NLIMBS)]
+
+    out = _add_niels_rows_j(
+        tuple(map(rows, (x, y, z, t))), tuple(map(rows, (yplusx, yminusx, t2d)))
+    )
+    return tuple(jnp.stack(c) for c in out)
+
+
+def _chain_tiles(x, nr, k: int, tile: int, interpret: bool = False):
+    """The kernel: x the partials (3, 22, steps, rows, 128), nr the
+    point -R (4, 22, rows / k, 128); returns the sum (4, 22, rows / k,
+    128).  A tile of `tile` rows holds k chains (k > 1 only where one
+    tile holds every lane); at k = 1 the last tile may be ragged.
+    interpret is the CPU tests'."""
+    from jax.experimental import pallas as pl
+
+    _, _, steps, rows, _ = x.shape
+
+    def kernel(x_ref, r_ref, o_ref):
+        s = pl.program_id(1)
+
+        @pl.when(s == 0)
+        def _():
+            o_ref[...] = jnp.zeros(o_ref.shape, jnp.int32)
+            one = jnp.ones(o_ref.shape[2:], jnp.int32)
+            o_ref[1, 0] = one  # the identity (0 : 1 : 1 : 0)
+            o_ref[2, 0] = one
+
+        def coords(ref, n, *tail):
+            return tuple(
+                [ref[(c, i, *tail)] for i in range(F.NLIMBS)] for c in range(n)
             )
-        )
+
+        acc = _add_niels_rows_j(coords(o_ref, 4), coords(x_ref, 3, 0))
+        for c, rows_ in enumerate(acc):
+            for i, row in enumerate(rows_):
+                o_ref[c, i] = row
+
+        @pl.when(s == steps - 1)
+        def _():
+            out = _fold_rows(acc, coords(r_ref, 4), k)
+            for c, rows_ in enumerate(out):
+                for i, row in enumerate(rows_):
+                    o_ref[c, i, : tile // k] = row
+
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((4, F.NLIMBS, rows, F.LANES), jnp.int32),
+        grid=(-(-rows // tile), steps),
+        in_specs=[
+            pl.BlockSpec((3, F.NLIMBS, 1, tile, F.LANES), lambda t, s: (0, 0, s, t, 0)),
+            pl.BlockSpec((4, F.NLIMBS, tile // k, F.LANES), lambda t, s: (0, 0, t, 0)),
+        ],
+        out_specs=pl.BlockSpec((4, F.NLIMBS, tile, F.LANES), lambda t, s: (0, 0, t, 0)),
+        interpret=interpret,
+    )(x, nr)
+    # a tile's sum lies in its first tile / k rows: every row at k = 1,
+    # the one tile's first rows / k above
+    return out[:, :, : rows // k]
+
+
+def _chains_on_chip(parts, nr, k: int):
+    """The partials (Niels pieces whose positions, concatenated, are K x
+    steps) summed in K chains and folded with nr, as one kernel: partial
+    j of chain k at step j // K, a chain's lanes in rows of 128, the K
+    chains side by side on the sublanes of one tile (k > 1) or the rows
+    in tiles of 8, the last one ragged (k = 1)."""
+    v = nr.x.shape[-1]
+    per = -(-v // F.LANES)
+    rows, tile = k * per, min(k * per, F.TILE_ROWS)
+    assert k == 1 or rows == tile, "K > 1 chains must share one tile"
+
+    def as_rows(a):  # (..., V) -> (..., per, 128), the lanes padded
+        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, per * F.LANES - v)])
+        return a.reshape(a.shape[:-1] + (per, F.LANES))
+
+    # one copy of the partials into the kernel's layout (3, 22, steps,
+    # rows, 128), and one relayout: XLA fuses the stacks, the
+    # concatenation and the transposes into the first
+    x = jnp.concatenate([jnp.stack(p) for p in parts], axis=1)
+    steps = x.shape[1] // k
+    x = x.reshape(3, steps, k, F.NLIMBS, v)
+    x = as_rows(jnp.transpose(x, (0, 3, 1, 2, 4)))
+    x = x.reshape(3, F.NLIMBS, steps, rows, F.LANES)
+    out = _chain_tiles(x, as_rows(jnp.stack(nr)), k, tile)
+    return E.Point(*out.reshape(4, F.NLIMBS, -1)[..., :v])

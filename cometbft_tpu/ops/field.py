@@ -443,6 +443,19 @@ def tile_rule(lanes: int) -> tuple[int, int]:
     return -(-rows // tile) * tile, tile
 
 
+def chain_rule(lanes: int) -> int:
+    """K, the independent chains a kernel tile holds at a lane count: the
+    largest power of two with K x rows <= TILE_ROWS, at least 1.  A tile
+    costs the same whether it holds 128 lanes or 1,024 (one vector
+    register either way), so chains fill the sublanes a lane count
+    leaves empty: 4 at 256 lanes, 1 at 10,112 (ops/comb's chains)."""
+    rows = -(-lanes // LANES)
+    k = 1
+    while 2 * k * rows <= TILE_ROWS:
+        k *= 2
+    return k
+
+
 def _on_chip(chain, x):
     """chain(x) as ONE on-chip kernel, tiled over the lane axis by
     tile_rule; leading batch axes of x (..., 22, L) fold into the lanes.

@@ -460,7 +460,7 @@ class Hub:
             "verify_comb_fold_chains",
             "Parallel add_niels chains the comb verify program of a "
             "lane count sums its 86 partial points in (label lanes; "
-            "ops/comb.fold_chains reads K off the lane count when the "
+            "ops/comb.chains_for reads K off the lane count when the "
             "program is traced)",
         )
         self.comb_pow_form = r.gauge(
@@ -470,6 +470,14 @@ class Hub:
             "form=kernel|array; ops/field.pow_form reads it off the "
             "backend when the program is traced: one on-chip kernel on "
             "a TPU, the array form elsewhere)",
+        )
+        self.comb_chain_form = r.gauge(
+            "verify_comb_chain_form",
+            "1 under the form the comb verify program of a lane count "
+            "runs its add_niels chains and their fold in (labels lanes, "
+            "form=kernel|array; ops/comb.chain_form reads it off the "
+            "backend when the program is traced: one on-chip kernel on "
+            "a TPU, a rolled scan elsewhere)",
         )
         self.secp_pubkey_cache = r.counter(
             "verify_svc_secp_pubkey_cache_total",

@@ -159,6 +159,10 @@ def test_the_program_key_is_lanes_and_payload_width(marker_tables, monkeypatch):
     # the exponentiation's form, read off the backend: no TPU here
     assert hub().comb_pow_form.value(lanes="256", form="array") == 1
     assert hub().comb_pow_form.value(lanes="256", form="kernel") == 0
+    # and the chains' (PR 37): the array form's K, 8 at 256 lanes, where
+    # a TPU's kernel takes F.chain_rule's 4
+    assert hub().comb_chain_form.value(lanes="256", form="array") == 1
+    assert hub().comb_chain_form.value(lanes="256", form="kernel") == 0
 
 
 # ------------------------------------------------- the cache, by bytes

@@ -63,3 +63,33 @@ def test_exponentiation_kernel_compiles_for_the_chip(one_chip, chain, lanes):
     assert f"s32[{F.NLIMBS},{rows},{F.LANES}]" in calls[0]
     (op_name,) = re.findall(r'op_name="([^"]*)"', calls[0])
     assert "decompress" in op_name.split("/")
+
+
+@pytest.mark.parametrize("lanes", [256, 10_112])
+def test_chain_kernel_compiles_for_the_chip(one_chip, lanes):
+    """The comb's chains and their fold at the cells' lane counts (K = 4
+    chains in one (8, 128) tile; K = 1 in ten tiles of eight rows, the
+    last one ragged): one custom call, under the scope the benchmark's
+    reader counts as tree_reduce."""
+    from cometbft_tpu.ops import comb, ed25519 as E
+
+    k = F.chain_rule(lanes)
+    steps = -(-comb.NPART // k)
+
+    def program(partials, nr):
+        with jax.named_scope("tree_reduce"):
+            return comb._chains_on_chip([E.Niels(*partials)], E.Point(*nr), k)
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    hlo = jax.jit(program).lower(
+        tuple(arg(steps * k, F.NLIMBS, lanes) for _ in range(3)),
+        tuple(arg(F.NLIMBS, lanes) for _ in range(4)),
+    ).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    rows = k * -(-lanes // F.LANES)  # 8 at 256 lanes, 79 at 10,112
+    assert f"s32[3,{F.NLIMBS},{steps},{rows},{F.LANES}]" in calls[0]
+    (op_name,) = re.findall(r'op_name="([^"]*)"', calls[0])
+    assert "tree_reduce" in op_name.split("/")
