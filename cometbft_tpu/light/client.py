@@ -27,6 +27,7 @@ from .provider import (
     ErrLightBlockNotFound,
     Provider,
     ProviderError,
+    fetch,
 )
 from .store import LightStore
 from .verifier import (
@@ -115,7 +116,7 @@ class Client:
                 "the new trust options; purging the light store"
             )
             self.store.delete_after(0)
-        lb = self.primary.light_block(opts.height)
+        lb = fetch(self.primary, opts.height)
         if lb.hash != opts.hash:
             raise LightClientError(
                 f"expected header hash {opts.hash.hex()} at height "
@@ -134,7 +135,8 @@ class Client:
             lb.signed_header.commit,
             klass=Klass.BACKGROUND,
         )
-        self.store.save_light_block(lb)
+        with tracing.span("light.store"):
+            self.store.save_light_block(lb)
 
     # --------------------------------------------------------------- queries
 
@@ -153,7 +155,7 @@ class Client:
         latest_trusted = self.store.latest_light_block()
         if latest_trusted is None:
             raise LightClientError("no trusted state — initialize first")
-        latest = self.primary.light_block(0)
+        latest = fetch(self.primary, 0)
         if latest.height <= latest_trusted.height:
             return None
         self._verify_light_block(latest, now_ns)
@@ -167,10 +169,10 @@ class Client:
         if height <= 0:
             raise LightClientError("height must be positive")
         now_ns = self.now_ns() if now_ns is None else now_ns
-        with tracing.span("light.walk"):
+        with tracing.span("light.walk", wait=True):
             lb = self.store.light_block(height)
             if lb is None:
-                lb = self.primary.light_block(height)
+                lb = fetch(self.primary, height)
                 self._verify_light_block(lb, now_ns)
         return lb
 
@@ -183,7 +185,7 @@ class Client:
         )
         result = "refused"
         # a span reads its labels when it closes: the result goes in then
-        with tracing.span("light.hop", labels):
+        with tracing.span("light.hop", labels, wait=True):
             try:
                 verify(
                     trusted.signed_header,
@@ -216,7 +218,8 @@ class Client:
             if first is None:
                 raise LightClientError("no trusted state")
             self._backwards(first, new_lb)
-            self.store.save_light_block(new_lb)
+            with tracing.span("light.store"):
+                self.store.save_light_block(new_lb)
             return
 
         if self.mode == SEQUENTIAL:
@@ -232,10 +235,11 @@ class Client:
                 "no witnesses configured: a lying primary cannot be detected"
             )
 
-        for lb in trace[1:]:
-            self.store.save_light_block(lb)
-        if self.pruning_size > 0:
-            self.store.prune(self.pruning_size)
+        with tracing.span("light.store"):
+            for lb in trace[1:]:
+                self.store.save_light_block(lb)
+            if self.pruning_size > 0:
+                self.store.prune(self.pruning_size)
 
     def _verify_sequential(
         self, trusted: LightBlock, new_lb: LightBlock, now_ns: int
@@ -244,7 +248,7 @@ class Client:
         trace = [trusted]
         verified = trusted
         for h in range(trusted.height + 1, new_lb.height + 1):
-            lb = new_lb if h == new_lb.height else self.primary.light_block(h)
+            lb = new_lb if h == new_lb.height else fetch(self.primary, h)
             self._hop(verified, lb, now_ns)
             verified = lb
             trace.append(lb)
@@ -271,7 +275,7 @@ class Client:
                         // SKIP_DENOMINATOR
                     )
                     try:
-                        interim = source.light_block(pivot)
+                        interim = fetch(source, pivot)
                     except (ErrLightBlockNotFound, ErrHeightTooHigh) as e:
                         raise ErrNewValSetCantBeTrusted(str(e)) from e
                     except ProviderError as e:
@@ -298,7 +302,7 @@ class Client:
             interim = (
                 new_lb
                 if h == new_lb.height
-                else self.primary.light_block(h)
+                else fetch(self.primary, h)
             )
             verify_backwards(interim.signed_header.header, verified_header)
             verified_header = interim.signed_header.header

@@ -12,7 +12,12 @@ from __future__ import annotations
 
 from ..types.evidence import LightClientAttackEvidence
 from ..utils.log import get_logger
-from .provider import ErrHeightTooHigh, ErrLightBlockNotFound, ProviderError
+from .provider import (
+    ErrHeightTooHigh,
+    ErrLightBlockNotFound,
+    ProviderError,
+    fetch,
+)
 
 logger = get_logger("light-detector")
 
@@ -48,7 +53,7 @@ def detect_divergence(client, primary_trace, now_ns: int) -> None:
     to_remove = []
     for i, witness in enumerate(client.witnesses):
         try:
-            w_lb = witness.light_block(last.height)
+            w_lb = fetch(witness, last.height)
         except (ErrLightBlockNotFound, ErrHeightTooHigh):
             continue  # benign: witness is behind
         except ProviderError as e:
@@ -128,7 +133,7 @@ def _examine_trace(client, trace, target_block, source, now_ns: int):
             source_block = target_block
         else:
             try:
-                source_block = source.light_block(trace_block.height)
+                source_block = fetch(source, trace_block.height)
             except ProviderError as e:
                 raise DivergenceError(f"examining trace: {e}") from e
         if idx == 0:

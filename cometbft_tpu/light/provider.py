@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Protocol
 
 from ..types.light_block import LightBlock, SignedHeader
+from ..utils import tracing
 
 
 class ProviderError(Exception):
@@ -38,6 +39,13 @@ class Provider(Protocol):
         ...
 
     def report_evidence(self, ev) -> None: ...
+
+
+def fetch(provider: Provider, height: int) -> LightBlock:
+    """``provider.light_block(height)`` as the span ``light.fetch``: the
+    light client's fetches from its primary and its witnesses."""
+    with tracing.span("light.fetch"):
+        return provider.light_block(height)
 
 
 class BlockStoreProvider:

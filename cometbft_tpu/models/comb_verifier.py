@@ -595,7 +595,6 @@ class CombBatchVerifier:
         self._row_set: set[int] = set()
         self._items: list[tuple[bytes, bytes, bytes]] = []
         self._fallback = None
-        self.last_timings: dict[str, float] = {}  # ms per phase, set by verify()
 
     def __len__(self) -> int:
         return len(self._items)
@@ -677,11 +676,13 @@ class CombBatchVerifier:
             on_compile(True)
         m = _mhub()
         m.verify_submit_queue_depth.add(1)
+        # the staging executor's queue, as an interval on its thread
+        queued_ns = tracing.stamp()
 
         def stage():
             import jax.numpy as jnp
 
-            timings = {}
+            tracing.interval("verify.staging.queued", queued_ns)
             slab = None
             try:
                 try:
@@ -695,23 +696,16 @@ class CombBatchVerifier:
                 # ops/sha2.ram_blocks_from_parts), no pubkeys (device-resident
                 # in the cache entry), no zero blocks.  The slab is recycled
                 # host memory — steady state allocates nothing.
-                with tracing.phase(
-                    "verify.slab_fill", "assembly", timings, "assembly_ms"
-                ):
+                with tracing.phase("verify.slab_fill", "assembly") as fill:
                     slab = entry.acquire_slab(width)
                     payload = _fill_payload(slab, items, idx)
-                with tracing.phase(
-                    "verify.h2d_dispatch", "h2d_dispatch", timings,
-                    "h2d_dispatch_ms",
-                ):
+                with tracing.phase("verify.h2d_dispatch", "h2d_dispatch") as h2d:
                     out = fn(
                         entry.tables, entry.valid, entry.pubs,
                         jnp.asarray(payload),
                     )
-                m.verify_staging_busy.inc(
-                    (timings["assembly_ms"] + timings["h2d_dispatch_ms"]) / 1e3
-                )
-                return out, slab, timings
+                m.verify_staging_busy.inc((fill.ns + h2d.ns) / 1e9)
+                return out, slab
             except BaseException:
                 # a failed fill/dispatch must not leak the pooled slab —
                 # each loss would put steady state back on fresh
@@ -752,15 +746,10 @@ class CombBatchVerifier:
         # dispatch — in the submit-then-collect-immediately pattern this
         # covers the whole staging pass, which must not be billed to the
         # device), then np.asarray blocks until the KERNEL's result lands.
-        waits: dict[str, float] = {}
-        with tracing.phase(
-            "verify.staging_wait", "staging_wait", waits, "staging_wait_ms"
-        ):
-            out, slab, timings = fut.result()
+        with tracing.phase("verify.staging_wait", "staging_wait", wait=True):
+            out, slab = fut.result()
         try:
-            with tracing.phase(
-                "verify.device_wait", "device_wait", waits, "device_wait_ms"
-            ):
+            with tracing.phase("verify.device_wait", "device_wait", wait=True):
                 host = np.asarray(out)  # the one blocking device fetch
         except BaseException:
             # async dispatch errors surface at this fetch (a lost
@@ -770,8 +759,6 @@ class CombBatchVerifier:
             raise
         # the kernel has consumed the staged payload; recycle the slab
         self._entry.release_slab(slab)
-        self.last_timings.update(timings)
-        self.last_timings.update(waits)
         with tracing.span("verify.blame_unpack"):
             all_ok = bool(host[-1])
             picked = (
@@ -781,25 +768,7 @@ class CombBatchVerifier:
             return all_ok, picked.tolist()
 
     def verify(self) -> tuple[bool, list[bool]]:
-        self.last_timings = {}
-        outer: dict[str, float] = {}
-        with tracing.phase("verify.submit", None, outer, "submit_ms"):
-            ticket = self.submit()
-        # no span of its own: collect()'s waits and the blame unpack are
-        # the spans of these lines
-        with tracing.phase(None, None, outer, "kernel_ms"):
-            result = self.collect(ticket)
-        if ticket[0] == "sync":
-            # host-routed (small batch / fallback): all work happened
-            # inside submit(); labeling it assembly_ms would corrupt the
-            # phase breakdowns the measurement scripts record
-            self.last_timings = {"host_ms": outer["submit_ms"]}
-        else:
-            # collect() merged the staging thread's assembly_ms /
-            # h2d_dispatch_ms into last_timings already; kernel_ms is the
-            # caller-visible wait (device execution minus what overlapped)
-            self.last_timings.update(outer)
-        return result
+        return self.collect(self.submit())
 
     def _program(self, width: int):
         """The verify program for this entry's lane count and payload

@@ -190,7 +190,7 @@ class TpuEd25519BatchVerifier:
         if kind == "sync":
             return payload
         out, n = payload
-        with tracing.phase("verify.device_wait", "device_wait"):
+        with tracing.phase("verify.device_wait", "device_wait", wait=True):
             ok = np.asarray(out)[:n]  # blocks until the device result lands
         res = [bool(x) for x in ok]
         return all(res), res

@@ -101,13 +101,15 @@ def verify_commit(
     """+2/3 of the set signed this commit; checks ALL signatures (the ABCI
     app's incentive logic depends on every flag being right)
     (validation.go:30)."""
-    _verify_basic_vals_and_commit(vals, commit, height, block_id)
-    voting_power_needed = vals.total_voting_power() * 2 // 3
-    if should_batch_verify(vals, commit):
+    with tracing.span("commit.prepare"):
+        _verify_basic_vals_and_commit(vals, commit, height, block_id)
+        voting_power_needed = vals.total_voting_power() * 2 // 3
+        bv = _batch_verifier(vals, commit, klass)
+    if bv is not None:
         _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, commit_only=False,
-            count_all_signatures=True, lookup_by_index=True, cache=None,
-            klass=klass,
+            bv, chain_id, vals, commit, voting_power_needed,
+            commit_only=False, count_all_signatures=True,
+            lookup_by_index=True, cache=None,
         )
     else:
         _verify_commit_single(
@@ -128,13 +130,15 @@ def verify_commit_light(
 ) -> None:
     """+2/3 check that may exit early — the light-client / blocksync path
     (validation.go:65-147)."""
-    _verify_basic_vals_and_commit(vals, commit, height, block_id)
-    voting_power_needed = vals.total_voting_power() * 2 // 3
-    if should_batch_verify(vals, commit):
+    with tracing.span("commit.prepare"):
+        _verify_basic_vals_and_commit(vals, commit, height, block_id)
+        voting_power_needed = vals.total_voting_power() * 2 // 3
+        bv = _batch_verifier(vals, commit, klass)
+    if bv is not None:
         _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, commit_only=True,
+            bv, chain_id, vals, commit, voting_power_needed, commit_only=True,
             count_all_signatures=count_all_signatures, lookup_by_index=True,
-            cache=cache, klass=klass,
+            cache=cache,
         )
     else:
         _verify_commit_single(
@@ -155,19 +159,23 @@ def verify_commit_light_trusting(
 ) -> None:
     """trustLevel of a *trusted* set signed this commit; validators are
     looked up by address since the sets differ (validation.go:150-253)."""
-    if vals is None:
-        raise CommitVerificationError("nil validator set")
-    if commit is None:
-        raise CommitVerificationError("nil commit")
-    if trust_level.denominator == 0:
-        raise CommitVerificationError("trustLevel has zero Denominator")
-    total = vals.total_voting_power()
-    voting_power_needed = total * trust_level.numerator // trust_level.denominator
-    if should_batch_verify(vals, commit):
+    with tracing.span("commit.prepare"):
+        if vals is None:
+            raise CommitVerificationError("nil validator set")
+        if commit is None:
+            raise CommitVerificationError("nil commit")
+        if trust_level.denominator == 0:
+            raise CommitVerificationError("trustLevel has zero Denominator")
+        total = vals.total_voting_power()
+        voting_power_needed = (
+            total * trust_level.numerator // trust_level.denominator
+        )
+        bv = _batch_verifier(vals, commit, klass)
+    if bv is not None:
         _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, commit_only=True,
+            bv, chain_id, vals, commit, voting_power_needed, commit_only=True,
             count_all_signatures=count_all_signatures, lookup_by_index=False,
-            cache=cache, klass=klass,
+            cache=cache,
         )
     else:
         _verify_commit_single(
@@ -178,6 +186,18 @@ def verify_commit_light_trusting(
 
 
 # ------------------------------------------------------------------ internal
+
+
+def _batch_verifier(vals: ValidatorSet, commit: Commit, klass):
+    """The batch verifier of a commit that takes the batch path (None
+    where it does not), bound to the set's keys: resolve_mode binds the
+    comb tables here, in the caller's thread."""
+    if not should_batch_verify(vals, commit):
+        return None
+    return crypto_batch.create_batch_verifier(
+        vals.get_proposer().pub_key.type, pubkeys=vals.pub_keys_bytes(),
+        klass=klass,
+    )
 
 
 def _verify_basic_vals_and_commit(vals, commit, height, block_id):
@@ -382,6 +402,7 @@ def _judge_batch_result(
 
 
 def _verify_commit_batch(
+    bv,
     chain_id: str,
     vals: ValidatorSet,
     commit: Commit,
@@ -390,14 +411,9 @@ def _verify_commit_batch(
     count_all_signatures: bool,
     lookup_by_index: bool,
     cache: SignatureCache | None,
-    klass=None,
 ) -> None:
     """(validation.go:265) — batch assembly, power tally, verify-service
-    dispatch (TPU), blame."""
-    proposer = vals.get_proposer()
-    bv = crypto_batch.create_batch_verifier(
-        proposer.pub_key.type, pubkeys=vals.pub_keys_bytes(), klass=klass
-    )
+    dispatch (TPU), blame, through ``bv`` (``_batch_verifier``)."""
     batch_sig_idxs, msgs = _assemble_commit_batch(
         bv, chain_id, vals, commit, voting_power_needed, commit_only,
         count_all_signatures, lookup_by_index, cache,
@@ -405,7 +421,7 @@ def _verify_commit_batch(
     if not batch_sig_idxs:
         return  # everything came from the cache
 
-    with tracing.span("commit.verify"):
+    with tracing.span("commit.verify", wait=True):
         if VERIFY_LATENCY_OBSERVER is not None:
             import time as _time
 
@@ -438,7 +454,7 @@ class PendingCommitVerification:
     def collect(self) -> None:
         if self._bv is None:
             return  # everything came from the signature cache
-        with tracing.span("commit.verify"):
+        with tracing.span("commit.verify", wait=True):
             ok, valid_sigs = self._bv.collect(self._ticket)
         with tracing.span("commit.judge"):
             _judge_batch_result(
@@ -469,13 +485,11 @@ def submit_verify_commit_light(
     doesn't take a device batch path at all (small set, heterogeneous
     keys, cpu backend): the caller must then run verify_commit_light
     synchronously."""
-    _verify_basic_vals_and_commit(vals, commit, height, block_id)
-    if not should_batch_verify(vals, commit):
+    with tracing.span("commit.prepare"):
+        _verify_basic_vals_and_commit(vals, commit, height, block_id)
+        bv = _batch_verifier(vals, commit, klass)
+    if bv is None:
         return None
-    proposer = vals.get_proposer()
-    bv = crypto_batch.create_batch_verifier(
-        proposer.pub_key.type, pubkeys=vals.pub_keys_bytes(), klass=klass
-    )
     if not hasattr(bv, "submit"):
         return None  # host verifier: no async seam, caller runs sync
     voting_power_needed = vals.total_voting_power() * 2 // 3
@@ -488,7 +502,7 @@ def submit_verify_commit_light(
     )
     if not batch_sig_idxs:
         return PendingCommitVerification(None, None, commit, [], [], cache)
-    with tracing.span("commit.verify"):
+    with tracing.span("commit.verify", wait=True):
         ticket = bv.submit()
     return PendingCommitVerification(
         bv, ticket, commit, batch_sig_idxs, msgs, cache

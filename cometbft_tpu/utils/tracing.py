@@ -17,17 +17,34 @@ production builds.  Enabled, a span is two perf_counter_ns reads plus a
 tuple append; the ring bounds total memory however long the run.
 
 On the profiler's clock: a recording span also enters a
-``jax.profiler.TraceAnnotation`` of its name (name only; instants are
-not mirrored), so while ``jax.profiler`` runs every program span lands
-on ``/host:CPU`` of the profile, on the line of the thread that ran it
-and on the same clock as the device's ``XLA Ops``.  JAX is imported
-when tracing is turned on, never at this module's import, and a process
-without JAX keeps the ring alone.
+``jax.profiler.TraceAnnotation`` of its name (name only; instants and
+intervals are not mirrored), so while ``jax.profiler`` runs every
+program span lands on ``/host:CPU`` of the profile, on the line of the
+thread that ran it and on the same clock as the device's ``XLA Ops``.
+JAX is imported when tracing is turned on, never at this module's
+import, and a process without JAX keeps the ring alone.  The ring's
+clock is ``perf_counter_ns``; the profile's is the profiler's own, offset
+from it by a constant the program cannot read.  So the tracer writes a
+clock anchor, ``verify.clock_anchor``, while the profiler records: a
+zero-length ring event whose mirrored annotation lands in the profile,
+carrying the perf and wall readings as args, written when the first
+recording span ends after the profiler started and then at most once a
+second.  A reader pairs an anchor's ring ``ts`` with its annotation's
+start and so places every ring event on the profile's clock.
+
+What caused what: every recorded span carries its own id (arg ``sid``)
+and its parent's (``parent``): the enclosing span on the thread, or the
+span that was current where :func:`carry_context` or a context from
+:func:`handoff` passed the work to another thread.  A span marked
+``wait=True`` (arg ``wait``) says the thread only waited in it.  An
+:func:`interval` records a span whose start was stamped earlier,
+possibly on another thread: a queue wait or a wake-up.
 
 One timing convention: :func:`phase` takes ONE pair of clock reads and
-feeds the ``verify_phase_seconds`` histogram (always), the ring and the
-annotation (tracing on) and the caller's ``timings`` dict from it.  The
-verifiers time their phases through it and in no other way.
+feeds the ``verify_phase_seconds`` histogram (always) and the ring and
+the annotation (tracing on) from it; the duration stays on the returned
+object (``ns``).  The verifiers time their phases through it and in no
+other way.
 
 Enable with COMETBFT_TPU_TRACE=1 (drain via export_chrome_trace / the
 API) or COMETBFT_TPU_TRACE=/path/to/out.trace.json to also auto-export
@@ -47,6 +64,7 @@ COMETBFT_TPU_TRACE_CTX=0 turns propagation off (events stay local).
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -73,6 +91,16 @@ _UNRESOLVED = object()
 _ANNOTATION = _UNRESOLVED
 _CTX_ENABLED = True  # COMETBFT_TPU_TRACE_CTX — span-context propagation
 _EXPORT_PATH: str | None = None
+# TraceAnnotation.is_enabled (whether the profiler records), or None
+_PROFILING = None
+
+# span ids: next() of a count is atomic under the GIL
+_ids = itertools.count(1)
+
+ANCHOR = "verify.clock_anchor"
+_ANCHOR_EVERY_NS = 1_000_000_000
+_anchor_at = 0  # perf ns of the last anchor
+_anchor_profiling = False  # whether an anchor was written in this profile
 
 _ring_mtx = threading.Lock()
 _ring: list = []  # bounded manually (deque has no atomic bulk-swap)
@@ -111,19 +139,22 @@ def set_enabled(on: bool, ring_capacity: int | None = None) -> None:
 def _annotation():
     """``jax.profiler.TraceAnnotation``, or None without JAX; looked up
     once, when tracing is first on."""
-    global _ANNOTATION
+    global _ANNOTATION, _PROFILING
     if _ANNOTATION is _UNRESOLVED:
         try:
             from jax.profiler import TraceAnnotation
         except Exception:  # noqa: BLE001 - no JAX, or one that cannot load
             TraceAnnotation = None
         _ANNOTATION = TraceAnnotation
+        _PROFILING = getattr(TraceAnnotation, "is_enabled", None)
     return _ANNOTATION
 
 
 def reset() -> None:
-    """Drop every buffered event (thread-local and ring)."""
-    global _dropped
+    """Drop every buffered event (thread-local and ring); the next
+    recording span writes a fresh clock anchor."""
+    global _dropped, _anchor_profiling
+    _anchor_profiling = False
     with _bufs_mtx:
         entries = list(_bufs)
     for _tref, buf, _tid in entries:
@@ -146,13 +177,16 @@ class SpanContext:
     shared by every span of the trace (across processes) and an 8-byte
     span_id naming this hop.  Shaped after the W3C traceparent header
     (version 00, sampled flag always 01) so the wire form is a plain
-    printable string any tracing stack recognizes."""
+    printable string any tracing stack recognizes.  ``parent``, set by
+    :func:`handoff` and never on the wire, is the ``sid`` of the span
+    that handed the work over: the parent of the receiver's spans."""
 
-    __slots__ = ("trace_id", "span_id")
+    __slots__ = ("trace_id", "span_id", "parent")
 
-    def __init__(self, trace_id: str, span_id: str):
+    def __init__(self, trace_id: str, span_id: str, parent: int | None = None):
         self.trace_id = trace_id
         self.span_id = span_id
+        self.parent = parent
 
     def child(self) -> "SpanContext":
         """Same trace, fresh hop id — what a server installs so its
@@ -204,7 +238,7 @@ def current_context() -> SpanContext | None:
 
 
 class _CtxScope:
-    __slots__ = ("_ctx", "_prev", "_installed")
+    __slots__ = ("_ctx", "_prev", "_prev_cur", "_installed")
 
     def __init__(self, ctx):
         self._ctx = ctx
@@ -213,35 +247,60 @@ class _CtxScope:
     def __enter__(self):
         if self._installed:
             self._prev = getattr(_tls, "ctx", None)
+            self._prev_cur = getattr(_tls, "cur", None)
             _tls.ctx = self._ctx
+            if self._ctx.parent is not None:
+                _tls.cur = self._ctx.parent
         return self._ctx
 
     def __exit__(self, *exc) -> bool:
         if self._installed:
             _tls.ctx = self._prev
+            _tls.cur = self._prev_cur
         return False
 
 
 def context_scope(ctx: SpanContext | None):
     """Install ``ctx`` as the thread's current context for the block:
     every span/instant recorded inside carries its trace_id/span_id
-    args.  ``None`` leaves the current context untouched (so call sites
-    can pass an optional context unconditionally)."""
+    args, and, where ``ctx`` came from :func:`handoff`, the handing
+    span as its parent.  ``None`` leaves the current context untouched
+    (so call sites can pass an optional context unconditionally)."""
     return _CtxScope(ctx if propagation_enabled() else None)
+
+
+def handoff() -> SpanContext | None:
+    """The calling thread's context for work another thread picks up
+    later (a queued request): the same trace identity, and the span
+    current here as the parent of the spans recorded under it there.
+    None where no context is installed."""
+    ctx = current_context()
+    cur = getattr(_tls, "cur", None)
+    if ctx is None or cur is None:
+        return ctx
+    return SpanContext(ctx.trace_id, ctx.span_id, cur)
 
 
 def carry_context(fn):
     """``fn`` as a callable that runs under the CALLING thread's current
-    context: for work handed to another thread, whose spans then carry
-    the trace identity of the request they serve.  ``fn`` itself where
-    no context is installed."""
+    context and with its current span as the parent: for work handed to
+    another thread, whose spans then carry the trace identity of the
+    request they serve and say which span handed them over.  ``fn``
+    itself where tracing is off or there is nothing to carry."""
+    if not _ENABLED:
+        return fn
     ctx = current_context()
-    if ctx is None:
+    cur = getattr(_tls, "cur", None)
+    if ctx is None and cur is None:
         return fn
 
     def run(*args, **kwargs):
-        with context_scope(ctx):
+        prev = getattr(_tls, "ctx", None), getattr(_tls, "cur", None)
+        _tls.ctx, _tls.cur = ctx, cur
+        try:
             return fn(*args, **kwargs)
+        finally:
+            _tls.ctx, _tls.cur = prev
 
     return run
 
@@ -306,64 +365,89 @@ def _flush(b: list) -> None:
             _dropped += overflow
 
 
-def _emit(ph: str, name: str, ts_ns: int, dur_ns: int, labels) -> None:
-    ctx = getattr(_tls, "ctx", None)
-    if ctx is not None:
-        # events recorded under a context scope carry the trace identity
-        # as args — the cross-process link trace_merge.py stitches on
-        merged = dict(labels) if labels else {}
-        merged.setdefault("trace_id", ctx.trace_id)
-        merged.setdefault("span_id", ctx.span_id)
-        labels = merged
+def _emit(ph: str, name: str, ts_ns: int, dur_ns: int, labels,
+          sid: int | None = None, parent: int | None = None,
+          wait: bool = False) -> None:
+    # the context rides along as it is: events recorded under a context
+    # scope carry its trace identity as args (the cross-process link
+    # trace_merge.py stitches on), written out at export
     b = _buf()
-    b.append((ph, name, ts_ns, dur_ns, _tls.tid, labels))
+    b.append((ph, name, ts_ns, dur_ns, _tls.tid, labels,
+              getattr(_tls, "ctx", None), sid, parent, wait))
     if len(b) >= _CHUNK:
         _flush(b)
 
 
+def _maybe_anchor(now_ns: int) -> None:
+    """While the profiler records: a clock anchor at the first span that
+    ends after it started, and then where a second has passed since the
+    last one, so that every profile holds one, however short."""
+    global _anchor_at, _anchor_profiling
+    if _PROFILING is None or not _PROFILING():
+        if _anchor_profiling:
+            _anchor_profiling = False
+        return
+    if _anchor_profiling and now_ns - _anchor_at < _ANCHOR_EVERY_NS:
+        return
+    _anchor_at, _anchor_profiling = now_ns, True
+    ann = _annotation()
+    if ann is not None:
+        ann = ann(ANCHOR)
+        ann.__enter__()
+    # read as a span reads its start: inside the annotation
+    perf = time.perf_counter_ns()
+    wall = time.time_ns()
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    _emit("X", ANCHOR, perf, 0, {"perf_ns": perf, "wall_ns": wall},
+          next(_ids), getattr(_tls, "cur", None))
+
+
 class _Span:
     """One timed block: an 'X' (complete) trace event recorded at
-    __exit__ and the profiler annotation of the same name around it
-    (``record``), and for :func:`phase` the histogram and the caller's
-    ``timings``, all from the one pair of clock reads."""
+    __exit__ with its id and its parent's, the profiler annotation of
+    the same name around it (``record``), and for :func:`phase` the
+    histogram, all from the one pair of clock reads; ``ns`` holds the
+    duration once the block has ended."""
 
-    __slots__ = ("_name", "_labels", "_record", "_hist", "_timings", "_key",
-                 "_t0", "_ann")
+    __slots__ = ("_name", "_labels", "_record", "_hist", "_wait", "_t0",
+                 "_ann", "_sid", "_parent", "ns")
 
-    def __init__(self, name, labels, record=True, hist=None, timings=None,
-                 key=None):
+    def __init__(self, name, labels, record=True, hist=None, wait=False):
         self._name = name
         self._labels = labels
         self._record = record
         self._hist = hist
-        self._timings = timings
-        self._key = key
+        self._wait = wait
 
     def __enter__(self) -> "_Span":
-        ann = _annotation() if self._record else None
-        if ann is not None:
-            ann = ann(self._name)
-            ann.__enter__()
+        ann = None
+        if self._record:
+            self._parent = getattr(_tls, "cur", None)
+            self._sid = _tls.cur = next(_ids)
+            ann = _annotation()
+            if ann is not None:
+                ann = ann(self._name)
+                ann.__enter__()
         self._ann = ann
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, *exc) -> bool:
         t0 = self._t0
-        dur = time.perf_counter_ns() - t0
+        self.ns = dur = time.perf_counter_ns() - t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, *exc)
         if self._record:
-            _emit("X", self._name, t0, dur, self._labels)
-        if exc_type is None:
-            # a phase that raised has no duration worth a histogram
-            # bucket or a place in the caller's breakdown
-            if self._hist is not None:
-                _metrics_hub().verify_phase_seconds.observe(
-                    dur / 1e9, phase=self._hist
-                )
-            if self._timings is not None:
-                self._timings[self._key] = dur / 1e6
+            _tls.cur = self._parent
+            _emit("X", self._name, t0, dur, self._labels, self._sid,
+                  self._parent, self._wait)
+            _maybe_anchor(t0 + dur)
+        if exc_type is None and self._hist is not None:
+            # a phase that raised has no duration worth a histogram bucket
+            _metrics_hub().verify_phase_seconds.observe(
+                dur / 1e9, phase=self._hist
+            )
         return False
 
 
@@ -380,40 +464,63 @@ class _NopSpan:
 _NOP = _NopSpan()
 
 
-def span(name: str, labels: dict | None = None):
+def span(name: str, labels: dict | None = None, wait: bool = False):
     """Context manager timing one pipeline phase.  Disabled: returns a
     shared no-op — the call site pays one bool check and no allocation
-    (pass labels as a prebuilt dict, not kwargs, to keep that true)."""
+    (pass labels as a prebuilt dict, not kwargs, to keep that true).
+    ``wait``: the thread only waits inside (for another thread, the
+    device or a callee's work); exported as the arg ``wait``."""
     if not _ENABLED:
         return _NOP
-    return _Span(name, labels)
+    return _Span(name, labels, True, None, wait)
 
 
 def phase(span_name: str | None, hist_phase: str | None = None,
-          timings: dict | None = None, key: str | None = None,
-          labels: dict | None = None):
+          labels: dict | None = None, wait: bool = False):
     """Context manager timing one verifier phase with ONE pair of clock
     reads, and feeding from it everything that wants the duration:
 
     - ``verify_phase_seconds{phase=hist_phase}`` on the metrics hub,
       always (/metrics is the operator's use, tracing on or off);
     - the span ring and the profiler annotation under ``span_name``,
-      when tracing is on (``labels`` as for :func:`span`);
-    - ``timings[key]`` in milliseconds (the breakdown a verifier keeps
-      as ``last_timings`` and the verify service ships to the client).
+      when tracing is on (``labels`` and ``wait`` as for :func:`span`);
+    - the returned object's ``ns``, once the block has ended.
 
-    Each of the three is left out by passing None.  A phase that raises
-    still closes its span; histogram and ``timings`` take completed
-    phases only."""
+    Either of the first two is left out by passing None.  A phase that
+    raises still closes its span; the histogram takes completed phases
+    only."""
     return _Span(span_name, labels, _ENABLED and span_name is not None,
-                 hist_phase, timings, key)
+                 hist_phase, wait)
+
+
+def stamp() -> int:
+    """``perf_counter_ns`` where tracing is on, else 0 (no clock read):
+    the start of an :func:`interval` that is recorded later."""
+    return time.perf_counter_ns() if _ENABLED else 0
+
+
+def interval(name: str, t0_ns: int, t1_ns: int | None = None,
+             labels: dict | None = None) -> None:
+    """A span from ``t0_ns`` (``perf_counter_ns``, stamped earlier and
+    possibly on another thread) to ``t1_ns`` or now, recorded in the
+    ring only (the profiler cannot be told of a start in the past):
+    a queue wait, a wake-up.  Its parent is the thread's current span.
+    Tracing off, or no start stamped (0), it reads no clock and records
+    nothing: call sites take ``t0_ns`` from :func:`stamp`."""
+    if not _ENABLED or not t0_ns:
+        return
+    if t1_ns is None:
+        t1_ns = time.perf_counter_ns()
+    _emit("X", name, t0_ns, t1_ns - t0_ns, labels, next(_ids),
+          getattr(_tls, "cur", None))
 
 
 def instant(name: str, labels: dict | None = None) -> None:
     """A zero-duration marker (step transitions, timeout fires)."""
     if not _ENABLED:
         return
-    _emit("i", name, time.perf_counter_ns(), 0, labels)
+    _emit("i", name, time.perf_counter_ns(), 0, labels, None,
+          getattr(_tls, "cur", None))
 
 
 # --------------------------------------------------------------- export
@@ -476,9 +583,19 @@ def chrome_trace_events() -> list[dict]:
         }
         for tid, tname in sorted(names.items())
     ]
-    for ph, name, ts_ns, dur_ns, tid, labels in sorted(
+    for ph, name, ts_ns, dur_ns, tid, labels, ctx, sid, parent, wait in sorted(
         events, key=lambda e: e[2]
     ):
+        args = dict(labels) if labels else {}
+        if ctx is not None:
+            args.setdefault("trace_id", ctx.trace_id)
+            args.setdefault("span_id", ctx.span_id)
+        if sid is not None:
+            args["sid"] = sid
+        if parent is not None:
+            args["parent"] = parent
+        if wait:
+            args["wait"] = True
         e = {
             "ph": ph,
             "name": name,
@@ -491,8 +608,8 @@ def chrome_trace_events() -> list[dict]:
             e["dur"] = dur_ns / 1e3
         elif ph == "i":
             e["s"] = "t"  # thread-scoped instant
-        if labels:
-            e["args"] = {k: _jsonable(v) for k, v in labels.items()}
+        if args:
+            e["args"] = {k: _jsonable(v) for k, v in args.items()}
         out.append(e)
     return out
 

@@ -127,7 +127,6 @@ class ServiceBatchVerifier:
         self._svc = service
         self._tenant = tenant if tenant is not None else default_tenant()
         self._items: list[tuple[bytes, bytes, bytes]] = []
-        self.last_timings: dict[str, float] = {}
         # this batch's span context, minted at submit(): the service
         # request inherits it (and carries it to a remote plane), and
         # the host-fallback / collect-stall paths re-install it so a
@@ -212,7 +211,8 @@ class ServiceBatchVerifier:
             # installed one (e.g. an RPC-served verify), which we join
             self._ctx = tracing.current_context() or tracing.new_context()
         try:
-            with tracing.context_scope(self._ctx):
+            with tracing.context_scope(self._ctx), \
+                    tracing.span("verify.svc.submit"):
                 return ("svc", self._service().submit(
                     list(self._items), self._klass, self._mode,
                     tenant=self._tenant,
@@ -247,8 +247,10 @@ class ServiceBatchVerifier:
                 time.monotonic() - t0, service=self._svc,
             )
             return self._host_fallback("verify.collect_stall_fallback")
-        if payload.timings:
-            self.last_timings.update(payload.timings)
+        if payload.resolved_ns:
+            # from the collector's resolution to this thread running again
+            with tracing.context_scope(self._ctx):
+                tracing.interval("verify.svc.wake", payload.resolved_ns)
         return result
 
     def verify(self) -> tuple[bool, list[bool]]:

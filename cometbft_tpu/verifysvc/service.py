@@ -327,7 +327,7 @@ class Ticket:
     (all_ok, per_signature) in the request's own add() order, or raises
     whatever the dispatch/collect path raised."""
 
-    __slots__ = ("_ev", "_mtx", "_result", "_exc", "nsigs", "timings",
+    __slots__ = ("_ev", "_mtx", "_result", "_exc", "nsigs", "resolved_ns",
                  "_on_settle", "compiling_since")
 
     def __init__(self, nsigs: int):
@@ -336,7 +336,9 @@ class Ticket:
         self._result: tuple[bool, list[bool]] | None = None
         self._exc: BaseException | None = None
         self.nsigs = nsigs
-        self.timings: dict[str, float] = {}
+        # perf_counter_ns of the winning resolution, stamped while tracing
+        # is on: where the caller's wake-up interval starts
+        self.resolved_ns = 0
         # fired exactly once, on whichever resolution wins — the
         # service's outstanding-quota release hook (submit() sets it)
         self._on_settle = None
@@ -359,7 +361,7 @@ class Ticket:
         if cb is not None:
             cb()
 
-    def _resolve(self, result, timings=None) -> bool:
+    def _resolve(self, result) -> bool:
         """First resolution wins: a failover host re-verify races the
         wedged device collect it replaced, and whichever settles a
         ticket first is authoritative — the loser's late answer is
@@ -368,8 +370,7 @@ class Ticket:
             if self._ev.is_set():
                 return False
             self._result = result
-            if timings:
-                self.timings = dict(timings)
+            self.resolved_ns = tracing.stamp()
             self._ev.set()
         self._settled()
         return True
@@ -405,7 +406,8 @@ class Ticket:
 
 
 class _Request:
-    __slots__ = ("items", "klass", "mode", "ticket", "enq", "tenant", "ctx")
+    __slots__ = ("items", "klass", "mode", "ticket", "enq", "enq_ns", "tenant",
+                 "ctx")
 
     def __init__(self, items, klass: Klass, mode, tenant: str = DEFAULT_TENANT):
         self.items = items
@@ -414,12 +416,15 @@ class _Request:
         self.tenant = tenant
         self.ticket = Ticket(len(items))
         self.enq = time.monotonic()
+        # where the interval verify.svc.queued starts (tracing on only)
+        self.enq_ns = tracing.stamp()
         # the submitter's span context: the scheduler/worker/collector
         # threads re-install it around their spans, so every hop of this
         # request — including the remote plane's, the context rides the
-        # wire — shares the submitter's trace_id
+        # wire — shares the submitter's trace_id, and their spans name
+        # the submitter's span as their parent
         self.ctx = (
-            tracing.current_context()
+            tracing.handoff()
             if tracing.propagation_enabled() else None
         )
 
@@ -1289,11 +1294,14 @@ class VerifyService:
         self._dispatched[klass.label] += 1
         self._tally_tenant(tlabel, "dispatched_batches")
         self._tally_tenant(tlabel, "dispatched_sigs", nsigs)
-        labels = (
-            {"class": klass.label, "tenant": batch[0].tenant,
-             "reason": reason, "sigs": nsigs, "requests": len(batch)}
-            if tracing.enabled() else None
-        )
+        labels = None
+        if tracing.enabled():
+            labels = {"class": klass.label, "tenant": batch[0].tenant,
+                      "reason": reason, "sigs": nsigs, "requests": len(batch)}
+            t_ns = tracing.stamp()
+            for r in batch:
+                with tracing.context_scope(r.ctx):
+                    tracing.interval("verify.svc.queued", r.enq_ns, t_ns)
         bv = None
         with tracing.context_scope(_batch_ctx(batch)), \
                 tracing.span("verify.sched.dispatch", labels):
@@ -1306,9 +1314,10 @@ class VerifyService:
                     # remote verifiers carry (tenant, class) on the wire
                     # — the plane schedules remote submitters server-side
                     bind(klass, batch[0].tenant)
-                for r in batch:
-                    for pub, msg, sig in r.items:
-                        bv.add(pub, msg, sig)
+                with tracing.span("verify.sched.add"):
+                    for r in batch:
+                        for pub, msg, sig in r.items:
+                            bv.add(pub, msg, sig)
                 if hasattr(bv, "on_compile"):
                     # the device verifiers say when the batch waits for
                     # a first-shape compile, wherever it runs
@@ -1321,7 +1330,8 @@ class VerifyService:
                     # to dispatch the next, possibly higher-class, batch
                     self._track_inflight(batch, "host")
                     self._hostq.put(
-                        (int(klass), next(self._hostseq), (bv, batch))
+                        (int(klass), next(self._hostseq),
+                         (bv, batch, tracing.stamp()))
                     )
                     return
                 ticket = bv.submit()  # comb staging seam: cheap dispatch
@@ -1359,7 +1369,7 @@ class VerifyService:
             if payload is None:
                 healthmon.retire("verifysvc-host")
                 return
-            bv, batch = payload
+            bv, batch, queued_ns = payload
             if all(r.ticket.done() for r in batch):
                 # a failover trip already host-re-verified this batch
                 # while it sat queued: submitting its stale device-bound
@@ -1385,6 +1395,8 @@ class VerifyService:
                 {"class": klass.label, "requests": len(batch)}
                 if tracing.enabled() else None
             )
+            with tracing.context_scope(_batch_ctx(batch)):
+                tracing.interval("verify.host.queued", queued_ns)
             with tracing.context_scope(_batch_ctx(batch)), \
                     tracing.span("verify.sched.hostwork", labels):
                 try:
@@ -1472,7 +1484,7 @@ class VerifyService:
         )
         t_collect = time.monotonic()
         with tracing.context_scope(_batch_ctx(batch)), \
-                tracing.span("verify.sched.collect", labels):
+                tracing.span("verify.sched.collect", labels, wait=True):
             try:
                 if not (isinstance(ticket, tuple) and ticket and ticket[0] == "sync"):
                     # injected-fault seams, in the same place a real
@@ -1509,15 +1521,16 @@ class VerifyService:
             for r in batch:
                 r.ticket._fail(err)
             return
-        timings = getattr(bv, "last_timings", None)
         off = 0
-        for r in batch:
-            part = list(res[off : off + len(r.items)])
-            off += len(r.items)
-            # per-request verdict: the whole-batch all_ok is useless
-            # once requests are coalesced — recompute from the slice
-            # (matches the verifiers' own all(res) and bool(res))
-            r.ticket._resolve((all(part) and bool(part), part), timings)
+        with tracing.context_scope(_batch_ctx(batch)), \
+                tracing.span("verify.sched.split"):
+            for r in batch:
+                part = list(res[off : off + len(r.items)])
+                off += len(r.items)
+                # per-request verdict: the whole-batch all_ok is useless
+                # once requests are coalesced — recompute from the slice
+                # (matches the verifiers' own all(res) and bool(res))
+                r.ticket._resolve((all(part) and bool(part), part))
 
     # ----------------------------------------------------------- failover
 
@@ -1570,7 +1583,8 @@ class VerifyService:
         # a brief stats gap, settlement itself is unaffected
         self._track_inflight(batch, "host")
         self._hostq.put(
-            (int(batch[0].klass), next(self._hostseq), (hbv, batch))
+            (int(batch[0].klass), next(self._hostseq),
+             (hbv, batch, tracing.stamp()))
         )
 
     def _reverify_batches(self, batches: list[list[_Request]]) -> None:

@@ -147,7 +147,8 @@ def test_the_bind_span_says_which_thread_bound(warming, monkeypatch, floor, thre
         cv.global_cache().ensure(old)
         resolve_mode(new)
         commit_epochs.no_bind_running()
-        binds = [e["args"] for e in tracing.chrome_trace_events()
+        binds = [{k: v for k, v in e["args"].items() if k not in ("sid", "parent")}
+                 for e in tracing.chrome_trace_events()
                  if e["name"] == "verify.table_bind"]
     finally:
         tracing.set_enabled(was_on)

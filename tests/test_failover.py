@@ -403,7 +403,7 @@ def test_host_loop_reroutes_stale_device_payload_after_trip(svc):
     for pub, msg, sig in items:
         bv.add(pub, msg, sig)
     s._track_inflight([req], "host")
-    s._hostq.put((int(Klass.CONSENSUS), next(s._hostseq), (bv, [req])))
+    s._hostq.put((int(Klass.CONSENSUS), next(s._hostseq), (bv, [req], 0)))
     assert req.ticket.collect(WAIT) == _host_verdicts(items)
     items2 = _sigs(2, b"after")
     assert _verify(s, items2, Klass.CONSENSUS) == _host_verdicts(items2)

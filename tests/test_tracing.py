@@ -102,7 +102,7 @@ def test_span_nesting_and_chrome_schema(tmp_path):
     outer, inner, mark = by_name["outer"], by_name["inner"], by_name["mark"]
     assert outer["ph"] == "X" and "dur" in outer
     assert mark["ph"] == "i" and mark["s"] == "t" and "dur" not in mark
-    assert outer["args"] == {"height": 5}
+    assert outer["args"] == {"height": 5, "sid": outer["args"]["sid"]}
     # nesting: inner lies within outer on the same thread track
     assert inner["tid"] == outer["tid"]
     assert outer["ts"] <= inner["ts"]
@@ -147,6 +147,153 @@ def test_cross_thread_spans_drain_on_export():
     by_name = {e["name"]: e for e in evs}
     assert {"worker.span", "main.span"} <= set(by_name)
     assert by_name["worker.span"]["tid"] != by_name["main.span"]["tid"]
+
+
+# ------------------------------------------ what caused what, and waits
+
+
+def _x(name):
+    return next(e for e in tracing.chrome_trace_events()
+                if e["ph"] == "X" and e["name"] == name)
+
+
+def test_nested_spans_name_their_parent():
+    tracing.set_enabled(True)
+    tracing.reset()
+    with tracing.span("outer"):
+        with tracing.span("inner"):
+            tracing.instant("mark")
+        with tracing.span("second"):
+            pass
+    evs = {e["name"]: e for e in tracing.chrome_trace_events() if e["ph"] != "M"}
+    outer, inner, second = evs["outer"]["args"], evs["inner"]["args"], evs["second"]["args"]
+    assert "parent" not in outer
+    assert inner["parent"] == second["parent"] == outer["sid"]
+    assert len({outer["sid"], inner["sid"], second["sid"]}) == 3
+    assert evs["mark"]["args"] == {"parent": inner["sid"]}  # no id of its own
+    with tracing.span("after"):  # the thread's current span was restored
+        pass
+    assert "parent" not in _x("after").get("args", {})
+
+
+def test_a_span_that_raises_restores_its_parent():
+    tracing.set_enabled(True)
+    tracing.reset()
+    with tracing.span("outer"):
+        with pytest.raises(ValueError):
+            with tracing.span("failing"):
+                raise ValueError("x")
+        with tracing.span("next"):
+            pass
+    assert _x("next")["args"]["parent"] == _x("outer")["args"]["sid"]
+
+
+def test_carry_context_hands_the_current_span_to_another_thread():
+    """The work a span hands to another thread names it as its parent,
+    and carries the trace id where a context is installed."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    tracing.set_enabled(True)
+    tracing.reset()
+
+    def work():
+        with tracing.span("worker.span"):
+            pass
+
+    root = tracing.new_context()
+    with ThreadPoolExecutor(1) as pool:
+        with tracing.span("plain.hand"):
+            pool.submit(tracing.carry_context(work)).result()
+        with tracing.context_scope(root), tracing.span("traced.hand"):
+            pool.submit(tracing.carry_context(work)).result()
+        pool.submit(work).result()  # nothing carried: no parent
+    workers = [e for e in tracing.chrome_trace_events()
+               if e["name"] == "worker.span"]
+    assert [w["args"].get("parent") for w in workers] == [
+        _x("plain.hand")["args"]["sid"], _x("traced.hand")["args"]["sid"], None]
+    assert "trace_id" not in workers[0]["args"]
+    assert workers[1]["args"]["trace_id"] == root.trace_id
+    assert "trace_id" not in workers[2]["args"]
+    assert tracing.current_context() is None
+
+
+def test_carry_context_with_tracing_off_is_the_function_itself():
+    tracing.set_enabled(False)
+
+    def work():
+        pass
+
+    assert tracing.carry_context(work) is work
+
+
+def test_a_handoff_context_parents_the_receivers_spans():
+    """What a queued request carries: the receiver's spans under it
+    name the submitter's span; a context not made by handoff() (one off
+    the wire) names no parent."""
+    tracing.set_enabled(True)
+    tracing.reset()
+    root = tracing.new_context()
+    with tracing.context_scope(root), tracing.span("submit"):
+        ctx = tracing.handoff()
+    assert ctx.trace_id == root.trace_id and ctx == root  # same identity
+
+    def receive(c, name):
+        with tracing.context_scope(c), tracing.span(name):
+            pass
+
+    for c, name in ((ctx, "received"), (root, "from.wire")):
+        t = threading.Thread(target=receive, args=(c, name))
+        t.start()
+        t.join()
+    got = _x("received")["args"]
+    assert got["parent"] == _x("submit")["args"]["sid"]
+    assert got["trace_id"] == root.trace_id
+    assert "parent" not in _x("from.wire")["args"]
+    assert tracing.handoff() is None  # no context installed here
+
+
+def test_a_wait_is_an_arg_and_not_a_name():
+    tracing.set_enabled(True)
+    tracing.reset()
+    with tracing.span("device.wait", wait=True):
+        pass
+    with tracing.phase("host.work", "assembly"):
+        pass
+    assert _x("device.wait")["args"]["wait"] is True
+    assert "wait" not in _x("host.work")["args"]
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_interval_records_a_ring_span_from_an_earlier_stamp(
+    monkeypatch, annotations, on
+):
+    """Tracing off, interval() reads no clock and records nothing (and
+    a call site that stamped nothing passes 0); on, the span runs from
+    the stamp to now, in the ring only, under the thread's span."""
+    tracing.set_enabled(on)
+    tracing.reset()
+    clock = _CountingClock()
+    with tracing.span("holder"):
+        with monkeypatch.context() as m:
+            m.setattr(tracing, "time", clock)
+            assert (tracing.stamp() != 0) is on
+            tracing.interval("queue.wait", 250_000)
+            tracing.interval("not.stamped", 0)
+            tracing.interval("given.end", 250_000, 750_000, {"k": 1})
+    got = [e for e in tracing.chrome_trace_events() if e["ph"] == "X"]
+    if not on:
+        assert clock.reads == 0 and got == []
+        return
+    assert clock.reads == 2  # the stamp, and "now" for the first interval
+    by = {e["name"]: e for e in got}
+    assert set(by) == {"holder", "queue.wait", "given.end"}
+    # the stamp read 1 ms, "now" 4 ms
+    assert (by["queue.wait"]["ts"], by["queue.wait"]["dur"]) == (250.0, 3750.0)
+    assert (by["given.end"]["ts"], by["given.end"]["dur"]) == (250.0, 500.0)
+    assert by["given.end"]["args"]["k"] == 1
+    for name in ("queue.wait", "given.end"):
+        assert by[name]["args"]["parent"] == by["holder"]["args"]["sid"]
+    assert [a[1] for a in annotations] == ["holder", "holder"]  # not mirrored
 
 
 # ------------------------------------ the profiler's clock, one convention
@@ -271,15 +418,12 @@ def test_phase_reads_the_clock_twice_and_feeds_everyone_the_same(
     tracing.set_enabled(on)
     tracing.reset()
     clock = _CountingClock()
-    timings = {}
     with monkeypatch.context() as m:  # the export below reads the real clock
         m.setattr(tracing, "time", clock)
-        with tracing.phase(
-            "verify.slab_fill", "assembly", timings, "assembly_ms"
-        ):
+        with tracing.phase("verify.slab_fill", "assembly") as fill:
             pass
     assert clock.reads == 2
-    assert timings == {"assembly_ms": 3.0}
+    assert fill.ns == 3_000_000  # the duration stays on the phase
     hist = metrics.hub().verify_phase_seconds
     k = hist._label_key({"phase": "assembly"})
     assert hist._totals[k] == 1 and hist._sums[k] == pytest.approx(0.003)
@@ -299,22 +443,21 @@ def test_phase_that_raises_closes_its_span_and_feeds_no_duration(monkeypatch):
     monkeypatch.setattr(metrics, "_HUB", metrics.Hub())
     tracing.set_enabled(True)
     tracing.reset()
-    timings = {}
     with pytest.raises(RuntimeError):
-        with tracing.phase("verify.device_wait", "device_wait", timings,
-                           "device_wait_ms"):
+        with tracing.phase("verify.device_wait", "device_wait", wait=True):
             raise RuntimeError("lost device")
-    assert timings == {}
     assert metrics.hub().verify_phase_seconds._totals == {}
-    assert [e["name"] for e in tracing.chrome_trace_events()
-            if e["ph"] == "X"] == ["verify.device_wait"]
+    spans = [e for e in tracing.chrome_trace_events() if e["ph"] == "X"]
+    assert [e["name"] for e in spans] == ["verify.device_wait"]
+    assert spans[0]["args"]["wait"] is True
 
 
 @pytest.mark.parametrize("on", [True, False])
 def test_verify_commit_leaves_the_commit_spans_nested_as_written(on):
-    """commit.assemble, commit.verify, commit.judge: in that order, one
-    after the other on the caller's thread, and the verifier's spans of
-    that thread inside commit.verify; none with tracing off."""
+    """commit.prepare, commit.assemble, commit.verify, commit.judge: in
+    that order, one after the other on the caller's thread, and the
+    verifier's spans of that thread inside commit.verify; none with
+    tracing off."""
     from test_types import _keys, _signed_commit, _valset
 
     import cometbft_tpu.types as T
@@ -331,11 +474,13 @@ def test_verify_commit_leaves_the_commit_spans_nested_as_written(on):
         assert spans == []
         return
     assert [e["name"] for e in mine] == [
-        "commit.assemble", "commit.verify", "commit.judge"]
+        "commit.prepare", "commit.assemble", "commit.verify", "commit.judge"]
     assert len({e["tid"] for e in mine}) == 1
     for a, b in zip(mine, mine[1:]):
         assert a["ts"] + a["dur"] <= b["ts"] + 1e-6
-    verify = mine[1]
+    verify = mine[2]
+    assert verify["args"]["wait"] is True
+    assert not any("wait" in e.get("args", {}) for e in mine if e is not verify)
     for e in spans:
         if e["tid"] == verify["tid"] and e["name"].startswith("verify."):
             assert verify["ts"] <= e["ts"]
@@ -343,9 +488,9 @@ def test_verify_commit_leaves_the_commit_spans_nested_as_written(on):
 
 
 def test_pending_light_verification_leaves_the_commit_spans(monkeypatch):
-    """The pipelined path: commit.assemble and commit.verify (the
-    submit) at submit time, commit.verify (the collect) and commit.judge
-    at collect time."""
+    """The pipelined path: commit.prepare, commit.assemble and
+    commit.verify (the submit) at submit time, commit.verify (the
+    collect) and commit.judge at collect time."""
     from test_types import _keys, _signed_commit, _valset
 
     from cometbft_tpu.types import validation
@@ -377,7 +522,8 @@ def test_pending_light_verification_leaves_the_commit_spans(monkeypatch):
     pending.collect()
     assert [e["name"] for e in tracing.chrome_trace_events()
             if e["ph"] == "X"] == [
-        "commit.assemble", "commit.verify", "commit.verify", "commit.judge"]
+        "commit.prepare", "commit.assemble", "commit.verify", "commit.verify",
+        "commit.judge"]
 
 
 @pytest.mark.parametrize("lane", ["uncached", "comb"])
@@ -401,6 +547,69 @@ def test_host_route_is_counted_by_lane(monkeypatch, lane):
         metrics.hub().registry.expose_text())
     assert [e["name"] for e in tracing.chrome_trace_events()
             if e["ph"] == "X"] == ["verify.host_route"]
+
+
+class _ProfilerAnnotation(_FakeAnnotation):
+    """A profiler that records: each annotation's start on a clock of
+    its own, offset from perf_counter_ns by a constant."""
+
+    OFFSET = 123_456_789_000
+    starts: list = []
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        import time as _time
+
+        _ProfilerAnnotation.starts.append(
+            (_time.perf_counter_ns() - self.OFFSET, self.name))
+        return self
+
+
+def test_the_anchor_maps_a_ring_event_onto_the_profilers_clock(monkeypatch):
+    """While the profiler records, the first span to end writes one
+    clock anchor (a ring event and an annotation) and no other within a
+    second; its pair places each ring span within microseconds of the
+    start the profiler gave that span's annotation."""
+    import sys
+
+    sys.path.insert(0, REPO)
+    from benchmarks.readers import ring_idle_by_span as reader
+
+    _ProfilerAnnotation.starts = []
+    monkeypatch.setattr(tracing, "_ANNOTATION", _ProfilerAnnotation)
+    monkeypatch.setattr(tracing, "_PROFILING", _ProfilerAnnotation.is_enabled)
+    tracing.set_enabled(True)
+    tracing.reset()
+    for i in range(5):
+        with tracing.span(f"step.{i}"):
+            pass
+    ring = [e for e in tracing.chrome_trace_events() if e["ph"] == "X"]
+    anchors = [e for e in ring if e["name"] == tracing.ANCHOR]
+    assert len(anchors) == 1 and anchors[0]["dur"] == 0
+    args = anchors[0]["args"]
+    assert args["perf_ns"] == anchors[0]["ts"] * 1e3 and args["wall_ns"] > 0
+    profile = [(s, s, name) for s, name in _ProfilerAnnotation.starts]
+    pairs = reader.anchor_pairs(ring, profile)
+    assert len(pairs) == 1
+    got = {name: s for s, _, name in profile}
+    for e in ring:
+        if e["name"].startswith("step."):
+            mapped = reader.to_profile(pairs, e["ts"] * 1e3)
+            # what is left is how long entering an annotation took
+            assert abs(mapped - got[e["name"]]) < 50_000
+
+
+def test_no_anchor_while_the_profiler_is_off(monkeypatch, annotations):
+    monkeypatch.setattr(tracing, "_PROFILING", lambda: False)
+    tracing.set_enabled(True)
+    tracing.reset()
+    with tracing.span("x"):
+        pass
+    assert [e["name"] for e in tracing.chrome_trace_events()
+            if e["ph"] == "X"] == ["x"]
 
 
 # --------------------------------------------------------- flight recorder
@@ -519,3 +728,129 @@ def test_trace_verify_pipeline_script_smoke(tmp_path, monkeypatch):
         e["ph"] == "X" and e["name"] == "verify.device_wait"
         for e in doc["traceEvents"]
     ), "the device-wait phase must appear as a complete span"
+
+
+# ------------------------------------------- one request, end to end
+
+
+def test_one_request_names_every_stretch_of_its_path(monkeypatch):
+    """A verify_commit through the verify service and the comb staging
+    path (tables and program stood in, as in test_comb_resident): one
+    trace id from the caller's preparation to its wake-up, every span
+    with a parent, and each hand-off named by the span or interval
+    that covers it."""
+    import numpy as np
+    from test_comb_resident import _stand_in_program
+    from test_types import _keys, _signed_commit, _valset
+
+    import cometbft_tpu.types as T
+    from cometbft_tpu.models import comb_verifier as cv
+    from cometbft_tpu.models import verifier
+    from cometbft_tpu.verifysvc.service import reset_global_service
+
+    def build(a):
+        a = np.asarray(a)
+        return a[None, :, 1].astype(np.int32), np.ones((a.shape[0],), bool)
+
+    monkeypatch.setattr(cv, "_build_tables", build)
+    monkeypatch.setattr(cv, "_GLOBAL_CACHE", cv.ValsetCombCache())
+    monkeypatch.setattr(cv, "_device_verify", _stand_in_program)
+    monkeypatch.setattr(verifier, "_COMB_PROGRAMS", {})
+    keys = _keys(32)  # the comb floor and the device batch floor
+    vals = _valset(keys)
+    bid, commit = _signed_commit(keys, vals)
+    reset_global_service()
+    tracing.set_enabled(True)
+    tracing.reset()
+    root = tracing.new_context()
+    try:
+        with tracing.context_scope(root), tracing.span("test.request"):
+            T.verify_commit("test-chain", vals, bid, 5, commit)
+    finally:
+        reset_global_service()
+    ring = [e for e in tracing.chrome_trace_events() if e["ph"] == "X"]
+    mine = [e for e in ring if e["args"].get("trace_id") == root.trace_id]
+    by = {}
+    for e in mine:
+        assert e["name"] not in by, f"{e['name']} twice in one request"
+        by[e["name"]] = e
+    path = [
+        "commit.prepare", "verify.table_bind", "commit.assemble",
+        "commit.verify", "verify.svc.submit", "verify.svc.queued",
+        "verify.sched.dispatch", "verify.sched.add", "verify.staging.queued",
+        "verify.slab_fill", "verify.h2d_dispatch", "verify.sched.collect",
+        "verify.staging_wait", "verify.device_wait", "verify.blame_unpack",
+        "verify.sched.split", "verify.svc.wake", "commit.judge",
+    ]
+    assert set(path) <= set(by), sorted(set(path) - set(by))
+    sids = {e["args"]["sid"]: e for e in ring}
+    for name in path:  # every span and interval names a recorded parent
+        assert by[name]["args"]["parent"] in sids, name
+
+    def parent(name):
+        return sids[by[name]["args"]["parent"]]["name"]
+
+    assert parent("commit.prepare") == "test.request"
+    assert parent("verify.table_bind") == "commit.prepare"
+    assert parent("verify.svc.submit") == "commit.verify"
+    for name in ("verify.svc.queued", "verify.sched.dispatch",
+                 "verify.sched.collect", "verify.sched.split"):
+        assert parent(name) == "verify.svc.submit", name  # handed over
+    for name in ("verify.sched.add", "verify.staging.queued",
+                 "verify.slab_fill", "verify.h2d_dispatch"):
+        assert parent(name) == "verify.sched.dispatch", name
+    assert parent("verify.device_wait") == "verify.sched.collect"
+    assert parent("verify.svc.wake") == "commit.verify"
+    waits = {e["name"] for e in mine if e["args"].get("wait")}
+    assert waits == {"commit.verify", "verify.sched.collect",
+                     "verify.staging_wait", "verify.device_wait"}
+
+    def start(name):
+        return by[name]["ts"]
+
+    def end(name):
+        return by[name]["ts"] + by[name]["dur"]
+
+    slack = 1e-3  # the export's microseconds are floats
+    assert end("commit.prepare") <= start("commit.assemble") + slack
+    assert start("verify.svc.submit") <= start("verify.svc.queued")
+    assert end("verify.svc.queued") <= start("verify.sched.dispatch") + slack
+    assert start("verify.sched.dispatch") <= start("verify.sched.add")
+    assert end("verify.sched.add") <= end("verify.sched.dispatch") + slack
+    assert end("verify.staging.queued") <= start("verify.slab_fill") + slack
+    assert start("verify.sched.split") <= start("verify.svc.wake")
+    assert start("verify.svc.wake") <= end("verify.sched.split") + slack
+    assert end("verify.svc.wake") <= end("commit.verify") + slack
+    assert by["verify.svc.wake"]["tid"] == by["commit.verify"]["tid"]
+
+
+def test_the_host_workers_queue_is_an_interval():
+    """A batch the scheduler hands to the host worker (here a set under
+    the device floor, answered on the host): the wait in the worker's
+    queue is the interval verify.host.queued, under the request's trace
+    and parent, ending where verify.sched.hostwork starts."""
+    from test_types import _keys, _signed_commit, _valset
+
+    import cometbft_tpu.types as T
+    from cometbft_tpu.verifysvc.service import reset_global_service
+
+    keys = _keys(4)
+    vals = _valset(keys)
+    bid, commit = _signed_commit(keys, vals)
+    reset_global_service()
+    tracing.set_enabled(True)
+    tracing.reset()
+    root = tracing.new_context()
+    try:
+        with tracing.context_scope(root):
+            T.verify_commit("test-chain", vals, bid, 5, commit)
+    finally:
+        reset_global_service()
+    by = {e["name"]: e for e in tracing.chrome_trace_events()
+          if e["ph"] == "X" and e["args"].get("trace_id") == root.trace_id}
+    queued, work = by["verify.host.queued"], by["verify.sched.hostwork"]
+    assert queued["tid"] == work["tid"]
+    assert queued["ts"] + queued["dur"] <= work["ts"] + 1e-3
+    assert queued["ts"] >= by["verify.sched.dispatch"]["ts"]
+    submit = by["verify.svc.submit"]["args"]["sid"]
+    assert queued["args"]["parent"] == work["args"]["parent"] == submit
