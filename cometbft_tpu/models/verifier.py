@@ -21,6 +21,8 @@ scheduler constructs these verifiers per dispatched batch
 from __future__ import annotations
 
 import threading
+from itertools import repeat
+from operator import getitem, itemgetter
 from typing import Protocol
 
 import numpy as np
@@ -121,6 +123,33 @@ def host_route(items, lane: str) -> tuple[bool, list[bool]]:
         return cpu.verify()
 
 
+def assemble_uncached(items):
+    """The uncached program's inputs for ``items`` (pub, msg, sig), by
+    columns: ((a, r, s, blocks, active), lengths), the batch padded to
+    its power-of-two bucket, and ``lengths`` the number of distinct
+    message lengths.  Equal byte for byte to the per-row loop kept as the
+    oracle in tests/test_uncached_assemble_columns.py.
+
+    Pad rows repeat row 0, so padded lanes do real-but-ignored work.
+    Every array is one join of a column of ``bytes``, with no numpy copy
+    that would release the interpreter lock (why that matters:
+    ops/sha2.pad_sha512_columns, which builds R || A || M the same way)."""
+    from ..ops import sha2
+
+    n = len(items)
+    pad = _next_bucket(n) - n
+    pubs, msgs, sigs = (list(map(itemgetter(k), items)) for k in range(3))
+    for col in (pubs, msgs, sigs):
+        col += col[:1] * pad
+    rs = list(map(getitem, sigs, repeat(slice(0, 32))))
+    a, r, s = (
+        np.frombuffer(bytearray().join(col), dtype=np.uint8).reshape(-1, 32)
+        for col in (pubs, rs, map(getitem, sigs, repeat(slice(32, 64))))
+    )
+    blocks, active, lengths = sha2.pad_sha512_columns((rs, pubs), msgs)
+    return (a, r, s, blocks, active), lengths
+
+
 class TpuEd25519BatchVerifier:
     """Batched ZIP-215 verification on the default JAX device.
 
@@ -192,30 +221,17 @@ class TpuEd25519BatchVerifier:
         out, n = payload
         with tracing.phase("verify.device_wait", "device_wait", wait=True):
             ok = np.asarray(out)[:n]  # blocks until the device result lands
-        res = [bool(x) for x in ok]
+        res = ok.tolist()
         return all(res), res
 
     def _submit_device(self, n: int):
         import jax.numpy as jnp
-        from ..ops import sha2
 
-        with tracing.phase("verify.uncached_assemble", "assembly"):
-            bucket = _next_bucket(n)
-            a = np.zeros((bucket, 32), dtype=np.uint8)
-            r = np.zeros((bucket, 32), dtype=np.uint8)
-            s = np.zeros((bucket, 32), dtype=np.uint8)
-            hashed = []
-            for i, (pub, msg, sig) in enumerate(self._items):
-                a[i] = np.frombuffer(pub, dtype=np.uint8)
-                r[i] = np.frombuffer(sig[:32], dtype=np.uint8)
-                s[i] = np.frombuffer(sig[32:], dtype=np.uint8)
-                hashed.append(sig[:32] + pub + msg)
-            # Pad rows repeat row 0 so padded lanes do real-but-ignored work.
-            for i in range(n, bucket):
-                a[i], r[i], s[i] = a[0], r[0], s[0]
-                hashed.append(hashed[0])
-            blocks, active = sha2.pad_messages_sha512(hashed)
-        arrays = (a, r, s, blocks, active)
+        labels = {} if tracing.enabled() else None
+        with tracing.phase("verify.uncached_assemble", "assembly", labels):
+            arrays, lengths = assemble_uncached(self._items)
+            if labels is not None:
+                labels.update(rows=n, bucket=len(arrays[0]), lengths=lengths)
         fn = self._program(arrays)  # a new bucket shape compiles here
         # device dispatch is asynchronous: the returned array is a future
         with tracing.phase("verify.h2d_dispatch", "h2d_dispatch"):

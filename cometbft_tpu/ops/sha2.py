@@ -24,6 +24,8 @@ Reference workloads served by these kernels:
 
 from __future__ import annotations
 
+from itertools import chain
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -393,23 +395,46 @@ def pad_messages_sha512(msgs: list[bytes], max_len: int | None = None):
     (0x80, zeros, 128-bit big-endian bit length at the end of the row's own
     final block); active is (n,) int32 per-row live block counts.
     """
+    buf, active, _ = pad_sha512_columns((), msgs, max_len)
+    return buf, active
+
+
+def pad_sha512_columns(heads, msgs, max_len: int | None = None):
+    """Host, by columns: row i hashes ``heads[0][i] || heads[1][i] || ...
+    || msgs[i]``, where each column of ``heads`` holds ``bytes`` of one
+    width.  Returns (buf, active, lengths): buf and active as
+    :func:`pad_messages_sha512` gives them for the concatenated rows,
+    byte for byte, and ``lengths`` the number of distinct message
+    lengths.
+
+    A row's padding depends on its length alone, so each length gets one
+    tail (0x80, zeros, bit length, the unused blocks) and the buffer is
+    one join of the cells and tails, row after row.  Joins and C-level
+    iterators hold the interpreter lock throughout, where numpy releases
+    it in every large copy: while another thread runs Python (the
+    background table bind), each release waits about a switch interval
+    (5 ms) to get the lock back, and a dozen numpy writes cost more than
+    a Python loop over the rows."""
     n = len(msgs)
-    longest = max((len(m) for m in msgs), default=0)
+    h = sum(len(col[0]) for col in heads) if n else 0
+    lens = list(map(len, msgs))
+    groups = set(lens)
+    longest = h + max(lens, default=0)
     if max_len is not None:
         longest = max(longest, max_len)
     nblocks = max(1, (longest + 17 + 127) // 128)
-    buf = np.zeros((n, nblocks * 128), dtype=np.uint8)
-    active = np.zeros(n, dtype=np.int32)
-    for i, m in enumerate(msgs):
-        ln = len(m)
-        nb = (ln + 17 + 127) // 128
-        active[i] = nb
-        buf[i, :ln] = np.frombuffer(m, dtype=np.uint8)
-        buf[i, ln] = 0x80
-        buf[i, nb * 128 - 16 : nb * 128] = np.frombuffer(
-            (ln * 8).to_bytes(16, "big"), dtype=np.uint8
-        )
-    return buf.reshape(n, nblocks, 128), active
+    tails, nbs = {}, {}
+    for ml in groups:
+        ln = h + ml
+        nb = nbs[ml] = (ln + 17 + 127) // 128
+        tails[ml] = (b"\x80" + bytes(nb * 128 - 17 - ln)
+                     + (ln * 8).to_bytes(16, "big")
+                     + bytes((nblocks - nb) * 128))
+    rows = zip(*heads, msgs, map(tails.__getitem__, lens))
+    buf = np.frombuffer(bytearray().join(chain.from_iterable(rows)),
+                        dtype=np.uint8)
+    active = np.fromiter(map(nbs.__getitem__, lens), dtype=np.int32, count=n)
+    return buf.reshape(n, nblocks, 128), active, len(groups)
 
 
 def pad_messages_sha256(msgs: list[bytes], max_len: int | None = None):
