@@ -20,6 +20,7 @@ from benchmarks import harness, light_chain, spec  # noqa: E402
 from benchmarks import reference_light as ref  # noqa: E402
 from benchmarks.drivers import light_walk  # noqa: E402
 from benchmarks.readers import span_median_within  # noqa: E402
+from tests.benchmarks.cell_appended import BENCHES, bench_for  # noqa: E402
 
 CELL = "light-175-skip"
 LIGHT_METRICS = {
@@ -59,8 +60,12 @@ def run_small(cell, seconds=0.5, seed=(1 << 31) + 29):
         cell, seed, seconds, False, time.monotonic(), jax.devices())
 
 
-def test_the_cell_resolves_to_its_own_files():
-    bench = spec.load_benchmark()
+@pytest.fixture(params=BENCHES)
+def bench(request):
+    return bench_for(request.param)
+
+
+def test_the_cell_resolves_to_its_own_files(bench):
     cell = spec.resolve(CELL, bench)
     assert cell.driver is light_walk and cell.chips == 1
     assert cell.config["name"] == "light-175-skipping"
@@ -79,12 +84,14 @@ def test_the_cell_resolves_to_its_own_files():
     assert "validation_host_ms" not in names  # several verifies a request
     for m in cell.per_layer:
         if m["name"] in LIGHT_METRICS:
-            assert m["workloads"] == [CELL] and m["layer"] == "light client"
+            assert m["layer"] == "light client"
             assert m["reader"] == "span_median_within"
     # no cell that was there reads the new metrics
     assert not LIGHT_METRICS & {
         m["name"] for m in spec.resolve("commit-175-serial", bench).per_layer}
-    for entry in bench["configs"][-1:] + bench["workloads"][-1:]:
+    config = next(c for c in bench["configs"] if c["name"] == cell.config["name"])
+    workload = next(w for w in bench["workloads"] if w["name"] == CELL)
+    for entry in (config, workload):
         assert len(entry["why"]) <= 200 and len(entry.get("source", "")) <= 200
 
 

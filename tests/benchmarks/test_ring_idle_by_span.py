@@ -217,3 +217,29 @@ def test_metric_file_names_its_reader_and_spans_the_program_records(name):
         return
     assert how["args"]["spans"] == spans
     assert set(spans) <= _program_names()
+
+
+SERIAL_CELLS = ["commit-175-serial", "commit-10k-serial", "commit-175-moving-set",
+                "commit-10k-churn"]
+
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_the_metric_is_listed_for_the_cells_whose_requests_it_splits(name):
+    """Found by name in BENCHMARK.json, and on the list of every cell it
+    reads: the light client's fetches and store in the walk alone, the
+    rest in every cell."""
+    bench = spec.load_benchmark()
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    # the span's own length, or the chip's idle gaps from the profile that
+    # the ring's spans split
+    source = "program_span" if NEW[name][0] == "span_median" else "device_trace"
+    assert (entry["unit"], entry["better"], entry["moves"], entry["source"]) == (
+        "ms", "lower", "verdict_p50_ms", source)
+    layer = "commit validation" if name == "commit_prepare_ms" else "device"
+    assert entry["layer"] == layer
+    cells = ["light-175-skip"] + ([] if name == "idle_light_io_ms" else SERIAL_CELLS)
+    assert set(cells) <= set(entry["workloads"])
+    for cell_name in cells:
+        m = next(m for m in spec.resolve(cell_name, bench).per_layer
+                 if m["name"] == name)
+        assert m["reader"] == NEW[name][0]
