@@ -325,7 +325,7 @@ KERNELS: tuple[Kernel, ...] = (
     # are declared so none can drift unfingerprinted.
     Kernel(
         name="secp256k1_verify_batch",
-        fn="cometbft_tpu.ops.secp256k1:verify_batch",
+        fn="cometbft_tpu.ops.secp256k1:secp_verify_batch",
         args=(
             i32(N, 22), i32(N, 22), boolean(N),  # pubkey x, y, decode-ok
             i32(N, 22), i32(N, 22), i32(N, 22),  # e, r, s (raw 256-bit)
@@ -335,12 +335,12 @@ KERNELS: tuple[Kernel, ...] = (
         ),
         out=(boolean(N),),
         static_kwargs=(("glv", True), ("recover", False)),
-        max_eqns=28_000,  # measured 21,248
+        max_eqns=28_000,  # measured 16,871
         arg_ranges=(DIGITS, DIGITS, None, DIGITS, DIGITS, DIGITS, None, FLAG, None, None, DIGITS),
     ),
     Kernel(
         name="secp256k1_verify_batch_recover",
-        fn="cometbft_tpu.ops.secp256k1:verify_batch",
+        fn="cometbft_tpu.ops.secp256k1:secp_verify_batch",
         args=(
             i32(N, 22), i32(N, 22), boolean(N),
             i32(N, 22), i32(N, 22), i32(N, 22),
@@ -349,12 +349,12 @@ KERNELS: tuple[Kernel, ...] = (
         ),
         out=(boolean(N),),
         static_kwargs=(("glv", True), ("recover", True)),
-        max_eqns=29_500,  # measured 22,694
+        max_eqns=29_500,  # measured 18,666
         arg_ranges=(DIGITS, DIGITS, None, DIGITS, DIGITS, DIGITS, None, FLAG, None, None, DIGITS),
     ),
     Kernel(
         name="secp256k1_verify_batch_noglv",
-        fn="cometbft_tpu.ops.secp256k1:verify_batch",
+        fn="cometbft_tpu.ops.secp256k1:secp_verify_batch",
         args=(
             i32(N, 22), i32(N, 22), boolean(N),
             i32(N, 22), i32(N, 22), i32(N, 22),
@@ -363,12 +363,12 @@ KERNELS: tuple[Kernel, ...] = (
         ),
         out=(boolean(N),),
         static_kwargs=(("glv", False), ("recover", False)),
-        max_eqns=18_000,  # measured 13,688 (the pre-GLV program, unchanged)
+        max_eqns=18_000,  # measured 14,626
         arg_ranges=(DIGITS, DIGITS, None, DIGITS, DIGITS, DIGITS, None, FLAG, None, None, DIGITS),
     ),
     Kernel(
         name="secp256k1_verify_batch_noglv_recover",
-        fn="cometbft_tpu.ops.secp256k1:verify_batch",
+        fn="cometbft_tpu.ops.secp256k1:secp_verify_batch",
         args=(
             i32(N, 22), i32(N, 22), boolean(N),
             i32(N, 22), i32(N, 22), i32(N, 22),
@@ -377,7 +377,7 @@ KERNELS: tuple[Kernel, ...] = (
         ),
         out=(boolean(N),),
         static_kwargs=(("glv", False), ("recover", True)),
-        max_eqns=20_000,  # measured 15,134
+        max_eqns=20_000,  # measured 16,421
         arg_ranges=(DIGITS, DIGITS, None, DIGITS, DIGITS, DIGITS, None, FLAG, None, None, DIGITS),
     ),
     # the fused hash->verify program: padded message bytes in, verdicts
@@ -387,7 +387,7 @@ KERNELS: tuple[Kernel, ...] = (
     # (COMETBFT_TPU_SECP_HASH_MAX_LEN=119: 2 SHA blocks, 1 Keccak block).
     Kernel(
         name="secp256k1_hash_verify",
-        fn="cometbft_tpu.ops.secp256k1:hash_verify_batch",
+        fn="cometbft_tpu.ops.secp256k1:secp_hash_verify_batch",
         args=(
             u8(N, 2, 64), i32(N),  # SHA-256-padded blocks + active
             u8(N, 1, 136), i32(N),  # Keccak-padded blocks + active
@@ -398,12 +398,12 @@ KERNELS: tuple[Kernel, ...] = (
         ),
         out=(boolean(N),),
         static_kwargs=(("glv", True), ("recover", False)),
-        max_eqns=29_000,  # measured 22,111
+        max_eqns=29_000,  # measured 17,605
         arg_ranges=(None, (0, 2), None, FLAG, DIGITS, DIGITS, None, DIGITS, DIGITS, None, FLAG, None, None, DIGITS),
     ),
     Kernel(
         name="secp256k1_hash_verify_recover",
-        fn="cometbft_tpu.ops.secp256k1:hash_verify_batch",
+        fn="cometbft_tpu.ops.secp256k1:secp_hash_verify_batch",
         args=(
             u8(N, 2, 64), i32(N),
             u8(N, 1, 136), i32(N),
@@ -414,7 +414,7 @@ KERNELS: tuple[Kernel, ...] = (
         ),
         out=(boolean(N),),
         static_kwargs=(("glv", True), ("recover", True)),
-        max_eqns=30_500,  # measured 23,557
+        max_eqns=30_500,  # measured 19,388
         arg_ranges=(None, (0, 2), None, FLAG, DIGITS, DIGITS, None, DIGITS, DIGITS, None, FLAG, None, None, DIGITS),
     ),
     # ---- ops/keccak.py — batched Keccak-256 (the Ethereum 0x01-padded
@@ -519,8 +519,14 @@ JIT_SITES: dict[str, str] = {
     "cometbft_tpu/ops/bls381.py::validate_aggregate_g1": (
         "bls381_validate_aggregate_g1"
     ),
-    "cometbft_tpu/ops/secp256k1.py::verify_batch": "secp256k1_verify_batch",
-    "cometbft_tpu/ops/secp256k1.py::hash_verify_batch": "secp256k1_hash_verify",
+    # models/secp_verifier.py jits the ops/secp256k1 programs (its
+    # program cache, compiled ahead of the first call)
+    "cometbft_tpu/models/secp_verifier.py::secp_verify_batch": (
+        "secp256k1_verify_batch"
+    ),
+    "cometbft_tpu/models/secp_verifier.py::secp_hash_verify_batch": (
+        "secp256k1_hash_verify"
+    ),
     "cometbft_tpu/ops/keccak.py::keccak256_blocks": "keccak256_blocks",
     # models/verifier.py jits ops/ed25519.verify_batch (the uncached path)
     "cometbft_tpu/models/verifier.py::verify_batch": "ed25519_verify_batch",
@@ -578,14 +584,6 @@ COLLECT_BOUNDARIES: dict[str, str] = {
     ),
     "cometbft_tpu/ops/field.py::from_limbs": (
         "host-side limb decoder used by tests and host bridges"
-    ),
-    "cometbft_tpu/ops/secp256k1.py::verify_batch_device": (
-        "the secp ECDSA bridge: one blocking fetch of the per-row "
-        "verdict bits"
-    ),
-    "cometbft_tpu/ops/secp256k1.py::hash_verify_batch_device": (
-        "the fused hash->verify bridge: one blocking fetch of the "
-        "per-row verdict bits"
     ),
     "cometbft_tpu/ops/keccak.py::keccak256_device": (
         "the batched Keccak-256 bridge: one blocking fetch of the "

@@ -82,7 +82,7 @@ def verify_address_signature(addr: bytes, msg: bytes, sig: bytes) -> bool:
     20-byte sender address.  Same gauntlet as PubKey.verify_signature
     (high-S rejected up front, every recover failure judges False) —
     this is the host oracle the device ecrecover lane is bit-identical
-    to (ops/secp256k1.verify_batch with recover rows)."""
+    to (ops/secp256k1.secp_verify_batch with recover rows)."""
     if len(addr) != ADDRESS_SIZE or len(sig) != SIGNATURE_SIZE:
         return False
     s = int.from_bytes(sig[32:64], "big")

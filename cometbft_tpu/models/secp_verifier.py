@@ -21,7 +21,7 @@ the failover/remote fallbacks inherit, same shape as models/bls_verifier):
    ecrecover rows skip decode, their "pubkey" is the target address),
    the signature has the right length for the key's wire format, and
    the message hash (SHA-256 / Keccak-256) is computed — ON DEVICE,
-   fused into the verify dispatch (ops/secp256k1.hash_verify_batch),
+   fused into the verify dispatch (ops/secp256k1.secp_hash_verify_batch),
    when the batch clears ``COMETBFT_TPU_SECP_HASH_DEVICE_MIN`` and
    every message fits ``COMETBFT_TPU_SECP_HASH_MAX_LEN``; the host
    hash loop otherwise (the hashing-residency seam,
@@ -31,7 +31,7 @@ the failover/remote fallbacks inherit, same shape as models/bls_verifier):
    quad-scalar walk by default, the plain Shamir witness under
    ``COMETBFT_TPU_SECP_GLV=0`` — and the x(R') mod n == r /
    Ecrecover-parity / recovered-address verdict — on device
-   (ops/secp256k1.verify_batch) when the batch clears
+   (ops/secp256k1.secp_verify_batch) when the batch clears
    ``COMETBFT_TPU_SECP_DEVICE_MIN``, on host (the crypto modules'
    own ``verify_signature``) otherwise.  The kernel is constructed to
    be bit-identical to the host lane in every edge
@@ -46,12 +46,21 @@ jax — the PR-8 failover / PR-13 breaker fallback path);
 ``TpuSecpBatchVerifier`` routes the batch through the ops/secp256k1
 kernel.  Both are DATA PLANE only: production consumers reach them
 through the verify service.
+
+The device programs are compiled ahead of their first call, one per
+(bucket, hash route, glv, recover), through models/verifier.program_for:
+a batch that waits for its program's compile (minutes at a new bucket)
+says so to the verify service, which stops the batch's collect and
+failover clocks for that long.  :func:`warm` compiles and runs the
+program a validator set's commits will take, for a node's set-up.
 """
 
 from __future__ import annotations
 
 import hashlib
 import threading
+
+import numpy as np
 
 from ..crypto import secp256k1 as host_secp
 from ..crypto import secp256k1eth as host_eth
@@ -144,23 +153,73 @@ def _bucket(n: int) -> int:
     return b
 
 
-def _verify_items(items, use_device: bool) -> tuple[bool, list[bool]]:
-    """The ONE verdict procedure both verifier classes run;
-    ``use_device`` only moves the batch's field/group arithmetic."""
-    n = len(items)
-    if n == 0:
-        return (False, [])
-    if not use_device or n < _device_min():
-        res = [_host_verify_one(p, m, s) for (p, m, s) in items]
-        return (all(res) and bool(res), res)
+# compiled device programs, one per (hash route, glv, recover, input
+# shapes): the bucket is the shapes' leading axis
+_PROGRAMS: dict[tuple, object] = {}
+_PROGRAMS_MTX = threading.Lock()
 
-    import time as _time
 
-    import numpy as np
+def _program(args: tuple, hash_dev: bool, glv: bool, recover: bool,
+             on_compile=None):
+    """The program for these inputs, compiled ahead of its first call
+    (models/verifier.program_for: ``on_compile`` is told when a batch
+    waits for its compile) and counted in
+    ``cometbft_verify_secp_program_cache_total{result=hit|compile}``.
+    The jitted functions' names are the XLA modules': a profile shows
+    ``jit_secp_hash_verify_batch`` or ``jit_secp_verify_batch``."""
+    import jax
 
     from ..ops import secp256k1 as dev
+    from .verifier import program_for
 
-    t0 = _time.perf_counter()
+    compiled = []
+
+    def lower():
+        compiled.append(True)
+        fn = (
+            jax.jit(dev.secp_hash_verify_batch,
+                    static_argnames=("glv", "recover"))
+            if hash_dev else
+            jax.jit(dev.secp_verify_batch, static_argnames=("glv", "recover"))
+        )
+        return fn.lower(
+            *(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args),
+            glv=glv, recover=recover,
+        )
+
+    key = (hash_dev, glv, recover,
+           tuple((x.shape, np.dtype(x.dtype).str) for x in args))
+    prog = program_for(_PROGRAMS, _PROGRAMS_MTX, key, lower, on_compile)
+    _mhub().verify_secp_program_cache.inc(
+        result="compile" if compiled else "hit")
+    return prog
+
+
+def _hash_on_device(items) -> bool:
+    """Whether a device batch of ``items`` hashes its messages on the
+    device (fused into the verify program) or on the host: wide enough
+    to amortise it (COMETBFT_TPU_SECP_HASH_DEVICE_MIN) and every message
+    within the padded block shape the program compiles for
+    (COMETBFT_TPU_SECP_HASH_MAX_LEN)."""
+    hmin = envknobs.get_int(envknobs.SECP_HASH_DEVICE_MIN)
+    hmax = envknobs.get_int(envknobs.SECP_HASH_MAX_LEN)
+    return (
+        hmin > 0
+        and len(items) >= hmin
+        and all(len(msg) <= hmax for (_, msg, _) in items)
+    )
+
+
+def _assemble(items, hash_dev: bool) -> tuple[tuple, bool]:
+    """The program's inputs for ``items``, padded to the bucket: the
+    pubkeys decoded (cached), the wire-shape gates, r and s as limbs,
+    and either the padded message blocks (device hash) or the digests'
+    limbs (host hash).  Rows that fail a host gate stay valid=False.
+    Returns (inputs, recover): whether any row is an ecrecover row,
+    which the program is traced for."""
+    from ..ops import secp256k1 as dev
+
+    n = len(items)
     b = _bucket(n)
     qx = np.zeros((b, dev.NLIMBS), dtype=np.int32)
     qy = np.zeros((b, dev.NLIMBS), dtype=np.int32)
@@ -172,17 +231,6 @@ def _verify_items(items, use_device: bool) -> tuple[bool, list[bool]]:
     v = np.zeros((b,), dtype=np.int32)
     is_rec = np.zeros((b,), dtype=bool)
     addr = np.zeros((b, ECR_PUB), dtype=np.uint8)
-
-    # hashing residency: fuse SHA-256/Keccak-256 into the device
-    # dispatch when the batch is wide enough to amortize it and every
-    # message fits the padded block shape the program compiled for
-    hmin = envknobs.get_int(envknobs.SECP_HASH_DEVICE_MIN)
-    hmax = envknobs.get_int(envknobs.SECP_HASH_MAX_LEN)
-    hash_dev = (
-        hmin > 0
-        and n >= hmin
-        and all(len(msg) <= hmax for (_, msg, _) in items)
-    )
     msgs: list[bytes] = [b""] * b
 
     qxs, qys, es, rs, ss, rows = [], [], [], [], [], []
@@ -225,41 +273,91 @@ def _verify_items(items, use_device: bool) -> tuple[bool, list[bool]]:
         s[rows] = dev.ints_to_limbs_np(ss)
         if not hash_dev:
             e[rows] = dev.ints_to_limbs_np(es)
-    glv = envknobs.get_bool(envknobs.SECP_GLV)
-    m = _mhub()
-    assembly_s = _time.perf_counter() - t0
-    m.verify_phase_seconds.observe(assembly_s, phase="secp_assembly")
-    t1 = _time.perf_counter()
-    with tracing.span(
-        "verify.secp_batch",
-        {"sigs": n, "where": "device", "hash": "device" if hash_dev else "host"}
-        if tracing.enabled() else None,
-    ):
-        if hash_dev:
-            from ..ops import keccak as kops
-            from ..ops import sha2 as sops
+    keys = (qx, qy, valid)
+    sig_cols = (r, s, is_eth, v, is_rec, addr, dev.g_table())
+    recover = bool(is_rec.any())
+    if not hash_dev:
+        return keys + (e,) + sig_cols, recover
+    from ..ops import keccak as kops
+    from ..ops import sha2 as sops
 
-            sha_blocks, sha_active = sops.pad_messages_sha256(
-                msgs, max_len=hmax
-            )
-            kec_blocks, kec_active = kops.pad_messages_keccak(
-                msgs, max_len=hmax
-            )
-            ok = dev.hash_verify_batch_device(
-                sha_blocks, sha_active, kec_blocks, kec_active,
-                qx, qy, valid, r, s, is_eth, v,
-                is_rec=is_rec, addr=addr, glv=glv,
-            )
+    hmax = envknobs.get_int(envknobs.SECP_HASH_MAX_LEN)
+    sha_blocks, sha_active = sops.pad_messages_sha256(msgs, max_len=hmax)
+    kec_blocks, kec_active = kops.pad_messages_keccak(msgs, max_len=hmax)
+    blocks = (sha_blocks, sha_active, kec_blocks, kec_active)
+    return blocks + keys + sig_cols, recover
+
+
+def _verify_items(items, use_device: bool,
+                  on_compile=None) -> tuple[bool, list[bool]]:
+    """The ONE verdict procedure both verifier classes run;
+    ``use_device`` only moves the batch's field/group arithmetic.  A
+    device verifier's batch under COMETBFT_TPU_SECP_DEVICE_MIN is
+    verified on the host, counted and spanned as
+    models/verifier.host_route counts the ed25519 lanes'."""
+    n = len(items)
+    if n == 0:
+        return (False, [])
+    hub = _mhub()
+    if not use_device or n < _device_min():
+        if not use_device:
+            res = [_host_verify_one(*it) for it in items]
         else:
-            ok = dev.verify_batch_device(
-                qx, qy, valid, e, r, s, is_eth, v,
-                is_rec=is_rec, addr=addr, glv=glv,
-            )
-    m.verify_phase_seconds.observe(
-        _time.perf_counter() - t1, phase="secp_device"
+            hub.verify_host_route.inc(lane="secp", reason="below_batch_min")
+            with tracing.span("verify.host_route"):
+                res = [_host_verify_one(*it) for it in items]
+        hub.verify_secp_rows.inc(n, where="host", hash="host")
+        return (all(res) and bool(res), res)
+
+    hash_dev = _hash_on_device(items)
+    hash_where = "device" if hash_dev else "host"
+    labels = (
+        {"rows": n, "bucket": _bucket(n), "hash": hash_where}
+        if tracing.enabled() else None
     )
-    res = [bool(x) for x in ok[:n]]
+    with tracing.phase("verify.secp_assemble", "secp_assembly", labels):
+        args, recover = _assemble(items, hash_dev)
+    glv = envknobs.get_bool(envknobs.SECP_GLV)
+    prog = _program(args, hash_dev, glv, recover, on_compile)
+    labels = (
+        {"sigs": n, "bucket": _bucket(n), "where": "device",
+         "hash": hash_where}
+        if tracing.enabled() else None
+    )
+    with tracing.phase("verify.secp_batch", "secp_device", labels):
+        out = prog(*args)  # device dispatch is asynchronous
+        with tracing.phase("verify.device_wait", "device_wait", wait=True):
+            ok = np.asarray(out)[:n]
+    hub.verify_secp_rows.inc(n, where="device", hash=hash_where)
+    res = ok.tolist()
     return (all(res) and bool(res), res)
+
+
+# a compressed key of the right length that decodes to no curve point
+# (x = 0: 7 is no square mod p): warm()'s rows are judged False
+_WARM_PUB = b"\x02" + bytes(32)
+
+
+def warm(rows: int, msg_len: int, on_compile=None):
+    """Compile, and run once, the program that a device batch of
+    ``rows`` rows whose longest message is ``msg_len`` bytes takes: its
+    bucket and its hash route are chosen as a real batch's are.  For a
+    node's set-up at the first sight of a secp256k1 validator set (175
+    validators sign about 110 bytes of precommit each), so that the
+    first commit does not wait for a compile of minutes; the compile is
+    announced to ``on_compile`` as a batch's is.  It verifies nothing,
+    so it leaves no span and counts no row.  Returns (bucket, hash
+    route), or None where such a batch is verified on the host and no
+    program is needed."""
+    if rows < _device_min():
+        return None
+    items = [(_WARM_PUB, bytes(msg_len), bytes(COSMOS_SIG))] * rows
+    hash_dev = _hash_on_device(items)
+    args, recover = _assemble(items, hash_dev)
+    glv = envknobs.get_bool(envknobs.SECP_GLV)
+    prog = _program(args, hash_dev, glv, recover, on_compile)
+    np.asarray(prog(*args))  # the first run loads the program
+    return _bucket(rows), "device" if hash_dev else "host"
 
 
 def _check_item(pub: bytes, msg: bytes, sig: bytes) -> None:
@@ -293,7 +391,7 @@ class CpuSecpBatchVerifier:
 class TpuSecpBatchVerifier:
     """Device-assisted ECDSA verification: the whole range-check /
     batch-inversion / Shamir pipeline in one fused kernel dispatch
-    (ops/secp256k1.verify_batch) above COMETBFT_TPU_SECP_DEVICE_MIN
+    (ops/secp256k1.secp_verify_batch) above COMETBFT_TPU_SECP_DEVICE_MIN
     rows, the host loop below it.
 
     ``_entry = None`` routes submit() through the verify service's
@@ -305,6 +403,9 @@ class TpuSecpBatchVerifier:
 
     _entry = None
     _fallback = None
+    # told when this batch's program starts and stops compiling
+    # (program_for); the verify service sets it per dispatched batch
+    on_compile = None
 
     def __init__(self) -> None:
         self._items: list[tuple[bytes, bytes, bytes]] = []
@@ -317,7 +418,8 @@ class TpuSecpBatchVerifier:
         self._items.append((pub_key, msg, sig))
 
     def submit(self):
-        return ("sync", _verify_items(self._items, use_device=True))
+        return ("sync", _verify_items(
+            self._items, use_device=True, on_compile=self.on_compile))
 
     def collect(self, ticket) -> tuple[bool, list[bool]]:
         return ticket[1]

@@ -6,7 +6,7 @@ At firehose ingest rates the per-tx host hash loop in
 models/secp_verifier is the wall the FPGA verification-engine study
 (PAPERS.md arXiv:2112.02229) pipelines away; this kernel moves the
 Keccak-256 half of the secp lane's message hashing onto the device so
-the fused hash->verify dispatch (ops/secp256k1.hash_verify_batch) never
+the fused hash->verify dispatch (ops/secp256k1.secp_hash_verify_batch) never
 touches the host between payload bytes and verdict.
 
 Layout: the 5x5x64-bit Keccak state rides as TWO (..., 25) uint32
@@ -150,7 +150,7 @@ def keccak256_blocks(blocks, active_blocks=None):
     static; the sha2.sha256_blocks contract).
 
     Manifest kernel ``keccak256_blocks`` (jitted via
-    ops/secp256k1.hash_verify_batch and keccak256_device).
+    ops/secp256k1.secp_hash_verify_batch and keccak256_device).
     """
     nblocks = blocks.shape[-2]
     hi = jnp.zeros(blocks.shape[:-2] + (25,), dtype=jnp.uint32)

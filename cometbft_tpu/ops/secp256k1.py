@@ -10,13 +10,15 @@ not close enough to a power of two for the ops/field.py carry-fold, so
 field elements are 22 signed 12-bit limbs in int32 (batch axis
 leading, limbs minor), R = 2^264, and every op returns canonical limbs
 in [0, m).  The 44-limb product is one outer-product + one constant
-anti-diagonal matmul; the reduction is a fori_loop (O(1) jaxpr in the
-limb count).  int32 bounds: conv sums <= 22*4095^2 ~ 3.7e8, reduction
-adds <= the same again — peak < 7.4e8 < 2^31.  The interval
-interpreter (analysis/rangecheck.py) proves the tight version of that
-estimate: peak |intermediate| = 716,255,216 across all five secp
-kernels (1.58 bits of int32 headroom; certificate entries
-``secp256k1_*`` in analysis/range_fingerprints.json).
+anti-diagonal matmul; the Montgomery reduction is whole-width (two
+matmuls by constant Toeplitz matrices), and carries move by parallel
+rounds and one word-wide addition, never a loop over the limbs.  int32
+bounds: conv sums <= 22*4095^2 ~ 3.7e8, the reduction adds <= the same
+again — peak < 7.4e8 < 2^31.  The interval interpreter
+(analysis/rangecheck.py) proves the tight version of that estimate:
+peak |intermediate| = 716,084,460 across all six secp kernels (1.58
+bits of int32 headroom; certificate entries ``secp256k1_*`` in
+analysis/range_fingerprints.json).
 
 The ECDSA batch (one fused program per bucket shape):
 
@@ -71,7 +73,7 @@ The ECDSA batch (one fused program per bucket shape):
   address — bit-identical to crypto/secp256k1eth.recover_pubkey +
   address() in every edge (non-residue r, infinity, high-s, v > 1).
 
-``hash_verify_batch`` fuses the message hashing in front of all of the
+``secp_hash_verify_batch`` fuses the message hashing in front of all of the
 above: cosmos rows through ops/sha2.sha256_blocks, eth/ecrecover rows
 through ops/keccak.keccak256_blocks, digests multiplexed per row — one
 device program from padded payload bytes to verdict bits, so firehose
@@ -88,8 +90,9 @@ from __future__ import annotations
 
 import threading
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..crypto import secp256k1 as host_secp
@@ -114,6 +117,33 @@ def _int_to_limbs(x: int, n: int = NLIMBS) -> np.ndarray:
     return out
 
 
+def _toeplitz(limbs: np.ndarray, width: int) -> np.ndarray:
+    """(22, width) int32 with row i the limbs shifted up by i: a limb
+    vector times it is the product's first ``width`` conv limbs."""
+    out = np.zeros((NLIMBS, width), dtype=np.int32)
+    for i in range(NLIMBS):
+        n = min(NLIMBS, width - i)
+        out[i, i : i + n] = limbs[:n]
+    return out
+
+
+# 2^264 as limbs of 4096 at limb 0 and 4095 above it (and the carry out
+# of the top limb): added to a difference, it lifts every limb to >= 0
+_ZERO_BIAS = np.full(NLIMBS, MASK, dtype=np.int32)
+_ZERO_BIAS[0] = RADIX
+_UNIT = _int_to_limbs(1)
+
+
+def _exponent_windows(e: int) -> np.ndarray:
+    """A host exponent as its 4-bit windows, most significant first:
+    the digits :func:`_mont_pow` walks."""
+    n = (e.bit_length() + 3) // 4
+    out = np.zeros(n, dtype=np.int32)
+    for i in range(n):
+        out[n - 1 - i] = (e >> (4 * i)) & 15
+    return out
+
+
 class _Mod:
     """Host-side constant bundle for one odd modulus m < 2^264: the limb
     decompositions and Montgomery constants the device ops close over."""
@@ -121,16 +151,19 @@ class _Mod:
     def __init__(self, m: int):
         self.m = m
         self.limbs = _int_to_limbs(m)
-        self.limbs23 = _int_to_limbs(m, NLIMBS + 1)
-        self.prime = (-pow(m, -1, RADIX)) % RADIX  # -m^-1 mod 2^12
+        self.neg = _int_to_limbs(R_MONT - m)  # 2^264 - m: the compare
+        # Toeplitz matrices of the whole-width reduction: x @ toep is the
+        # 44-limb product x*m, x @ toep_neg_inv the low 22 limbs of
+        # x * (-m^-1 mod 2^264)
+        self.toep = _toeplitz(self.limbs, 2 * NLIMBS)
+        self.toep_neg_inv = _toeplitz(
+            _int_to_limbs((-pow(m, -1, R_MONT)) % R_MONT), NLIMBS)
         self.r2 = _int_to_limbs(R_MONT * R_MONT % m)  # to-Montgomery mul
         self.one_plain = _int_to_limbs(1)  # from-Montgomery mul
         self.one_mont = _int_to_limbs(R_MONT % m)
-        # m - 2 bits MSB-first: the Fermat inversion ladder of the ONE
+        # m - 2 by 4-bit windows: the Fermat inversion chain of the ONE
         # total-product inverse in the batch-inversion trick
-        self.inv_bits = np.array(
-            [b == "1" for b in bin(m - 2)[2:]], dtype=bool
-        )
+        self.inv_windows = _exponent_windows(m - 2)
 
     def to_mont(self, x: int) -> int:
         return x * R_MONT % self.m
@@ -272,68 +305,85 @@ for _i in range(NLIMBS):
 
 
 # ------------------------------------------------------------- primitives
-# Identical staging to ops/bls381 (the proven idiom), parameterized by
-# the modulus bundle: lax.scan carries keep the jaxpr O(1) in the limb
-# count, the Montgomery reduction is a fori_loop of dynamic slices.
 # Representation: canonical digits everywhere — every op returns limbs
 # in [0, 2^12) with value in [0, m), so limb-wise equality IS value
 # equality and window extraction reads digits directly.
 #
-# Compile-cost note: like the bls381 kernels, the rolled Montgomery
-# graphs are expensive to compile cold on the CPU backend (one bucket
-# shape ~2 min); the persistent XLA compile cache
-# (utils/compilecache, on by default in tests and bench — the
-# same mitigation the ed25519 verify kernel already relies on) makes
-# every later process a cache hit, and the power-of-two bucketing
-# keeps the shape set small.
+# No op loops over the limbs: each is a fixed chain of whole-vector
+# steps (a few rounds of parallel carries, then one int32 addition that
+# resolves the last ripple of carries for all 22 limbs at once), so a
+# device runs a field op as a dozen or so fused kernels, where a loop of
+# one step a limb ran hundreds of small ones (PERF.md §6).
+#
+# Compile-cost note: a bucket shape compiles cold in tens of seconds;
+# the persistent XLA compile cache (utils/compilecache, on by default in
+# tests and bench) makes every later process a cache hit, and the
+# power-of-two bucketing keeps the shape set small.
+
+# a limb vector times _UP[w] is the vector moved one limb up (limb 0
+# becomes 0, the top limb drops out), times _POW2 the word whose bit i
+# is limb i (for limbs of 0 or 1).  Matmuls rather than slices: the
+# compiler fuses a slice into each reader, and a chain of field ops
+# would then recompute every earlier op once for each of its readers.
+_UP = {w: np.eye(w, k=1, dtype=np.int32) for w in (NLIMBS, 2 * NLIMBS)}
+_POW2 = (1 << np.arange(NLIMBS, dtype=np.int32)).astype(np.int32)
+_LIMB_BITS = np.arange(NLIMBS, dtype=np.int32)
 
 
-def _carry23(a):
-    """Carry chain into 23 canonical-width limbs (signed input limbs;
-    any value in (-2^264, 2^265) fits)."""
-    aT = jnp.moveaxis(a, -1, 0)  # (L, ...)
-
-    def step(c, limb):
-        v = limb + c
-        return v >> BITS, v & MASK
-
-    c, outT = lax.scan(step, jnp.zeros_like(aT[0]), aT)
-    out = jnp.moveaxis(outT, 0, -1)
-    if a.shape[-1] < NLIMBS + 1:
-        out = jnp.concatenate([out, c[..., None]], axis=-1)
-    return out
+def _carry_save(v, rounds: int):
+    """``rounds`` rounds of parallel carries over non-negative limbs:
+    each limb keeps its low 12 bits and hands the rest one limb up, and
+    what leaves the top limb is dropped (the value changes by a multiple
+    of 2^(12 * width)).  One round takes limbs below 2^13 to at most
+    4096, two take limbs below 2^24 there and three limbs below 2^31."""
+    up = jnp.asarray(_UP[v.shape[-1]])
+    for _ in range(rounds):
+        v = (v & MASK) + (v >> BITS) @ up
+    return v
 
 
-def _cond_sub_m(a23, mod: _Mod):
-    """One round: subtract m if a >= m (borrow-chain compare+select)."""
-    aT = jnp.moveaxis(a23, -1, 0)
-    ml = jnp.asarray(mod.limbs23)
-
-    def step(borrow, inp):
-        limb, m_i = inp
-        v = limb - m_i - borrow
-        b = (v < 0).astype(v.dtype)
-        return b, v + b * RADIX
-
-    borrow, dT = lax.scan(step, jnp.zeros_like(aT[0]), (aT, ml))
-    d = jnp.moveaxis(dT, 0, -1)
-    ge = borrow == 0  # no final borrow -> a >= m
-    return jnp.where(ge[..., None], d, a23)
+def _ripple(v):
+    """22 limbs in [0, 4096] -> (canonical digits of the value mod
+    2^264, the carry out of the top limb).  A limb generates a carry
+    when it is 4096 and passes one on when it is 4095, which is binary
+    addition: with a = generate | propagate and b = generate as 22-bit
+    words, a + b carries into bit i exactly where the limbs carry into
+    limb i, and bit i of the sum is that carry xor propagate."""
+    gen = v >> BITS
+    prop = (v == MASK).astype(v.dtype)
+    total = (2 * gen + prop) @ jnp.asarray(_POW2)  # a + b
+    carry_in = ((total[..., None] >> _LIMB_BITS) & 1) ^ prop
+    return (v + carry_in) & MASK, total >> NLIMBS
 
 
-def _normalize2m(a, mod: _Mod):
-    """Limb vector with value in (-m, 2m) -> canonical [0, m)."""
-    return _cond_sub_m(_carry23(a), mod)[..., :NLIMBS]
+def _carry(v, rounds: int):
+    """Non-negative limbs (bounded as :func:`_carry_save` says) ->
+    canonical digits of the value mod 2^264."""
+    return _ripple(_carry_save(v, rounds))[0]
+
+
+def _cond_sub_m(a, mod: _Mod):
+    """Canonical digits of a value below 2m -> canonical [0, m): a + (2^264
+    - m) carries out of the top limb, in its one round of parallel
+    carries or in the ripple after it, exactly when a >= m, and its
+    digits are then those of a - m."""
+    v = a + jnp.asarray(mod.neg)
+    top = lax.index_in_dim(v, NLIMBS - 1, axis=-1, keepdims=False) >> BITS
+    d, ripple_top = _ripple(_carry_save(v, 1))
+    return jnp.where((top + ripple_top > 0)[..., None], d, a)
 
 
 def add(a, b, mod: _Mod):
-    return _normalize2m(a + b, mod)
+    return _cond_sub_m(_carry(a + b, 1), mod)
 
 
 def sub(a, b, mod: _Mod):
-    """a - b (canonical inputs): a + m - b lands in (0, 2m); the signed
-    carry chain absorbs the negative intermediate limbs."""
-    return _normalize2m(a - b + jnp.asarray(mod.limbs), mod)
+    """a - b (canonical inputs): a + m - b lands in (0, 2m).  The limbs
+    of a - b + m may be negative; adding ``_ZERO_BIAS`` (4096 to
+    limb 0, 4095 to the others, 2^264 in all) makes each non-negative,
+    and the value mod 2^264 is unchanged."""
+    v = a - b + jnp.asarray(mod.limbs + _ZERO_BIAS)
+    return _cond_sub_m(_carry(v, 2), mod)
 
 
 def mul(a, b, mod: _Mod):
@@ -341,33 +391,25 @@ def mul(a, b, mod: _Mod):
     be any canonical-DIGIT vectors as long as a*b < R*m (both < m, or
     one < m and the other < R — the raw-input to-Montgomery case).
 
-    int32 bounds: conv limbs <= 22*4095^2 ~ 3.7e8; the reduction adds
-    <= the same again (limb j is touched by <= 22 of the 22 q*m adds)
-    — peak < 7.4e8 < 2^31; forwarded carries are < 2^18 on top."""
+    Whole-width reduction: with T = a*b, Q = (T mod R) * (-m^-1) mod R
+    makes T + Q*m a multiple of R, and (T + Q*m) / R < 2m.  T's limbs
+    are one outer product and a constant anti-diagonal matmul; Q and
+    Q*m are matmuls by constant Toeplitz matrices of -m^-1 and m.
+
+    int32 bounds: conv limbs <= 22*4095^2 ~ 3.7e8, T + Q*m's <= twice
+    that — peak < 7.4e8 < 2^31."""
     outer = (a[..., :, None] * b[..., None, :]).reshape(
         a.shape[:-1] + (NLIMBS * NLIMBS,)
     )
     t = outer @ jnp.asarray(_DIAG)  # (..., 44) conv limbs
-    pl = jnp.asarray(mod.limbs)
-    pprime = mod.prime
-
-    # word-wise reduction: clear limb i by adding q*m at weight i.
-    def body(i, t):
-        ti = lax.dynamic_index_in_dim(t, i, axis=-1, keepdims=False)
-        c = ti >> BITS
-        low = ti & MASK
-        q = (low * pprime) & MASK
-        seg = lax.dynamic_slice_in_dim(t, i, NLIMBS, axis=-1)
-        seg = seg + q[..., None] * pl
-        t = lax.dynamic_update_slice_in_dim(t, seg, i, axis=-1)
-        nxt = lax.dynamic_index_in_dim(t, i + 1, axis=-1, keepdims=False)
-        # limb i is (c<<12 + low + q*m0); low + q*m0 ≡ 0 mod 2^12 —
-        # forward the whole /2^12 quotient, the final slice drops limb i
-        nxt = nxt + c + ((low + q * pl[0]) >> BITS)
-        return lax.dynamic_update_index_in_dim(t, nxt, i + 1, axis=-1)
-
-    t = lax.fori_loop(0, NLIMBS, body, t)
-    return _normalize2m(t[..., NLIMBS:], mod)
+    t_lo = _carry_save(t[..., :NLIMBS], 3)  # T mod R, limbs <= 4096
+    q = _carry(t_lo @ jnp.asarray(mod.toep_neg_inv), 3)
+    u = _carry_save(t + q @ jnp.asarray(mod.toep), 3)
+    # the low half is now a multiple of R below 2R: it is R, and carries
+    # one into the high half, exactly when one of its limbs is nonzero
+    lo_carry = jnp.any(u[..., :NLIMBS] != 0, axis=-1).astype(u.dtype)
+    hi = u[..., NLIMBS:] + lo_carry[..., None] * jnp.asarray(_UNIT)
+    return _cond_sub_m(_carry(hi, 1), mod)
 
 
 def sqr(a, mod: _Mod):
@@ -407,31 +449,42 @@ def _lt_const(a, climbs) -> jnp.ndarray:
 def _add_const(a, climbs):
     """(..., 22) + host constant, carried back to canonical digits (the
     sum must stay < 2^264; used for r + n < 2^257)."""
-    return _carry23(a + jnp.asarray(climbs))[..., :NLIMBS]
+    return _carry(a + jnp.asarray(climbs), 1)
 
 
 # ------------------------------------------------ Montgomery batch inverse
 
 
-def _mont_pow(x, bits, mod: _Mod):
+def _mont_pow(x, windows, mod: _Mod):
     """x^E in the Montgomery domain for a fixed host exponent given as
-    its MSB-first bit vector: lax.scan keeps the jaxpr one
-    square+conditional-multiply body regardless of the bit count.  Used
-    for the batch-inversion Fermat chain (E = m - 2) and the ecrecover
-    square-root chain (E = (p+1)/4)."""
+    its 4-bit windows, most significant first (:func:`_exponent_windows`):
+    a table of x^0..x^15, then per window four squarings and one
+    multiplication by the window's entry.  Rolled scans keep the jaxpr
+    one multiplication body for the table and one window body regardless
+    of the exponent's length.  Used for the batch-inversion Fermat chain
+    (E = m - 2) and the ecrecover square-root chain (E = (p+1)/4)."""
     one = jnp.broadcast_to(jnp.asarray(mod.one_mont), x.shape)
 
-    def step(acc, bit):
-        acc = sqr(acc, mod)
-        return jnp.where(bit, mul(acc, x, mod), acc), None
+    def power(p, _):
+        p = mul(p, x, mod)
+        return p, p
 
-    acc, _ = lax.scan(step, one, jnp.asarray(bits))
+    _, pows = lax.scan(power, one, None, length=15)  # x^1..x^15
+    table = jnp.concatenate([one[None], pows], axis=0)
+
+    def step(acc, w):
+        acc, _ = lax.scan(
+            lambda a, _: (sqr(a, mod), None), acc, None, length=4)
+        entry = lax.dynamic_index_in_dim(table, w, axis=0, keepdims=False)
+        return mul(acc, entry, mod), None
+
+    acc, _ = lax.scan(step, one, jnp.asarray(windows))
     return acc
 
 
 def _mont_pow_inv(x, mod: _Mod):
     """x^(m-2) — the single Fermat chain of the batch-inversion trick."""
-    return _mont_pow(x, mod.inv_bits, mod)
+    return _mont_pow(x, mod.inv_windows, mod)
 
 
 def _shifted(x, k: int, fill):
@@ -582,8 +635,6 @@ def g_table():
     if _G_TABLE_DEV is None:
         with _G_TABLE_MTX:
             if _G_TABLE_DEV is None:
-                import jax
-
                 _G_TABLE_DEV = jax.device_put(_G_TABLE_NP)
     return _G_TABLE_DEV
 
@@ -720,6 +771,21 @@ def _neg_y(Y, flag):
 # ------------------------------------------------- the two walk variants
 
 
+def _doublings(acc, k: int):
+    """``k`` doublings of the accumulator, rolled: one doubling body."""
+    acc, _ = lax.scan(lambda p, _: (pt_double(*p), None), acc, None,
+                      length=k)
+    return acc
+
+
+def _add_all(acc, points):
+    """The accumulator plus each of ``points`` in turn, rolled: one add
+    body however many points a window adds."""
+    stacked = tuple(jnp.stack(c) for c in zip(*points))
+    acc, _ = lax.scan(lambda p, q: (pt_add(*p, *q), None), acc, stacked)
+    return acc
+
+
 def _walk_shamir(u1, u2, qtab, gtab):
     """The non-GLV bit-exactness witness: 66 shared windows, per window
     4 doublings (rolled scan) + one G-table add + one Q-table add."""
@@ -728,20 +794,16 @@ def _walk_shamir(u1, u2, qtab, gtab):
     one_m = jnp.broadcast_to(jnp.asarray(FP.one_mont), u1.shape)
 
     def step(i, acc):
-        # 4 doublings as a rolled scan: one doubling body in the jaxpr
-        # instead of four (compile cost, not semantics)
-        (X, Y, Z), _ = lax.scan(
-            lambda p, _: (pt_double(*p), None), acc, None, length=4
-        )
-        gX, gY, gZ = _lookup_g(
-            gtab, lax.dynamic_index_in_dim(u1w, i, axis=0, keepdims=False)
-        )
-        X, Y, Z = pt_add(X, Y, Z, gX, gY, gZ)
-        qX, qY, qZ = _lookup_q(
-            qtab, lax.dynamic_index_in_dim(u2w, i, axis=0, keepdims=False)
-        )
-        X, Y, Z = pt_add(X, Y, Z, qX, qY, qZ)
-        return (X, Y, Z)
+        # 4 doublings, then the window's two adds, as rolled scans: one
+        # doubling body and one add body in the jaxpr (compile cost,
+        # not semantics)
+        acc = _doublings(acc, 4)
+        return _add_all(acc, [
+            _lookup_g(gtab, lax.dynamic_index_in_dim(
+                u1w, i, axis=0, keepdims=False)),
+            _lookup_q(qtab, lax.dynamic_index_in_dim(
+                u2w, i, axis=0, keepdims=False)),
+        ])
 
     inf = (one_m, one_m, jnp.zeros_like(u1))
     return lax.fori_loop(0, NWINDOWS, step, inf)
@@ -772,9 +834,7 @@ def _walk_glv(u1, u2, qtab, gtab):
     one_m = jnp.broadcast_to(jnp.asarray(FP.one_mont), u1.shape)
 
     def step(i, acc):
-        (X, Y, Z), _ = lax.scan(
-            lambda p, _: (pt_double(*p), None), acc, None, length=4
-        )
+        points = []
         for tab, w, neg, look in (
             (gtab, wg, k1n, _lookup_g),
             (pg_tab, wpg, k2n, _lookup_g),
@@ -784,8 +844,8 @@ def _walk_glv(u1, u2, qtab, gtab):
             aX, aY, aZ = look(
                 tab, lax.dynamic_index_in_dim(w, i, axis=0, keepdims=False)
             )
-            X, Y, Z = pt_add(X, Y, Z, aX, _neg_y(aY, neg), aZ)
-        return (X, Y, Z)
+            points.append((aX, _neg_y(aY, neg), aZ))
+        return _add_all(_doublings(acc, 4), points)
 
     inf = (one_m, one_m, jnp.zeros_like(u1))
     return lax.fori_loop(0, NWINDOWS_GLV, step, inf)
@@ -793,8 +853,8 @@ def _walk_glv(u1, u2, qtab, gtab):
 
 # ------------------------------------------- ecrecover / hashing helpers
 
-# (p+1)/4 MSB-first: the Fermat square-root chain of the R-lift
-_SQRT_BITS = np.array([b == "1" for b in bin((P + 1) // 4)[2:]], dtype=bool)
+# (p+1)/4: the Fermat square-root chain of the R-lift
+_SQRT_WINDOWS = _exponent_windows((P + 1) // 4)
 
 # canonical 12-bit limbs (LE) <-> 32 big-endian bytes, as static gathers:
 # BE byte j is LE byte k = 31-j, which spans limbs q = 2k//3 and q+1 at
@@ -858,7 +918,7 @@ def _address_from_affine(x_aff, y_aff):
 # ----------------------------------------------------------- verification
 
 
-def verify_batch(
+def secp_verify_batch(
     qx, qy, q_valid, e, r, s, is_eth, v, is_rec, addr, gtab,
     *, glv=True, recover=False,
 ):
@@ -894,12 +954,37 @@ def verify_batch(
 
     Returns (B,) bool, bit-identical to the host verifiers.
 
+    The ``jax.named_scope`` names are the phases a profile is read by
+    (docs/observability.md): ``secp_prep`` (on-curve, range and low-s
+    checks, the scalars and their batch inversion), ``secp_walk`` (the
+    Q window table and the GLV or Shamir walk) and ``secp_final`` (the
+    affine normalisation and the verdict).  The XLA module is named
+    after the function, ``jit_secp_verify_batch``: a prefix that no
+    reader of the ed25519 programs matches.
+
     Manifest kernels ``secp256k1_verify_batch[_recover][ _noglv]``
     (analysis/kernel_manifest): eqn-budgeted and fingerprint-pinned per
-    (glv, recover) variant; the jit site is the bridge's module-cached
-    ``jax.jit(verify_batch, static_argnames=...)`` in JIT_SITES.
+    (glv, recover) variant; jitted by models/secp_verifier's program
+    cache.
     """
-    # ---- validation (device half): on-curve + scalar ranges + low-s
+    with jax.named_scope("secp_prep"):
+        row_pre, q_ok, Px_m, Py_m, u1, u2 = _prep(
+            qx, qy, q_valid, e, r, s, is_eth, v, is_rec, recover)
+    with jax.named_scope("secp_walk"):
+        # u1*G + u2*P with P the pubkey (or the lifted R on ecrecover
+        # rows); invalid rows enter as infinity
+        one_m = jnp.broadcast_to(jnp.asarray(FP.one_mont), qx.shape)
+        Pz = select(q_ok, one_m, jnp.zeros_like(qx))
+        qtab = _build_q_table(Px_m, Py_m, Pz)
+        walk = _walk_glv if glv else _walk_shamir
+        X, Y, Z = walk(u1, u2, qtab, gtab)
+    with jax.named_scope("secp_final"):
+        return row_pre & _final(X, Y, Z, r, is_eth, v, is_rec, addr, recover)
+
+
+def _prep(qx, qy, q_valid, e, r, s, is_eth, v, is_rec, recover):
+    """The device half of validation (on-curve, scalar ranges, low-s)
+    and the walk's scalars: (row_pre, q_ok, Px_m, Py_m, u1, u2)."""
     qx_m = to_mont(qx, FP)
     qy_m = to_mont(qy, FP)
     n_l = FN.limbs
@@ -916,7 +1001,7 @@ def verify_batch(
         # rejects r >= n before lifting, as r_ok does here)
         rx_m = to_mont(r, FP)
         y2 = add(mul(sqr(rx_m, FP), rx_m, FP), jnp.asarray(_B7_M), FP)
-        y_m = _mont_pow(y2, _SQRT_BITS, FP)
+        y_m = _mont_pow(y2, _SQRT_WINDOWS, FP)
         lift_ok = jnp.all(sqr(y_m, FP) == y2, axis=-1)  # y2 was a QR
         y_plain = from_mont(y_m, FP)
         flip = (y_plain[:, 0] & 1) != v
@@ -955,17 +1040,12 @@ def verify_batch(
         u2_src_m = to_mont(r, FN)
     u1 = from_mont(u1_m, FN)
     u2 = from_mont(mul(u2_src_m, w_m, FN), FN)
+    return row_pre, q_ok, Px_m, Py_m, u1, u2
 
-    # ---- the double-scalar walk: u1*G + u2*P with P the pubkey (or
-    # the lifted R on ecrecover rows); invalid rows enter as infinity
-    one_m = jnp.broadcast_to(jnp.asarray(FP.one_mont), qx.shape)
-    Pz = select(q_ok, one_m, jnp.zeros_like(qx))
-    qtab = _build_q_table(Px_m, Py_m, Pz)
-    if glv:
-        X, Y, Z = _walk_glv(u1, u2, qtab, gtab)
-    else:
-        X, Y, Z = _walk_shamir(u1, u2, qtab, gtab)
 
+def _final(X, Y, Z, r, is_eth, v, is_rec, addr, recover):
+    """The walked point's verdict, per row (row_pre not yet applied)."""
+    n_l = FN.limbs
     # ---- affine normalization, z^-1 amortized across the batch (the
     # second shared inversion; Z = 0 rows sanitized exactly like s = 0)
     z_nonzero = ~is_zero(Z)
@@ -990,10 +1070,10 @@ def verify_batch(
         )
     else:
         verdict = jnp.where(is_eth, eth_ok, cosmos_ok)
-    return row_pre & z_nonzero & verdict
+    return z_nonzero & verdict
 
 
-def hash_verify_batch(
+def secp_hash_verify_batch(
     sha_blocks, sha_active, kec_blocks, kec_active,
     qx, qy, q_valid, r, s, is_eth, v, is_rec, addr, gtab,
     *, glv=True, recover=False,
@@ -1007,35 +1087,32 @@ def hash_verify_batch(
         row's message SHA-256-padded (ops/sha2.pad_messages_sha256)
     kec_blocks / kec_active : (B, nb', 136) uint8 + (B,) int32 — the
         SAME messages Keccak-padded (ops/keccak.pad_messages_keccak)
-    remaining args/kwargs   : exactly :func:`verify_batch` minus ``e``
+    remaining args/kwargs   : exactly :func:`secp_verify_batch` minus ``e``
 
     Both digests are computed for every row (branch-free batch; the
     loser is masked per row), then multiplexed: Keccak-256 for
     eth/ecrecover rows, SHA-256 for cosmos rows — matching the host
     hash choice bit for bit.
 
-    Manifest kernels ``secp256k1_hash_verify[_recover]``; jit site is
-    the module-cached bridge below.
+    The digests run under the scope ``secp_hash``; the XLA module is
+    ``jit_secp_hash_verify_batch``.  Manifest kernels
+    ``secp256k1_hash_verify[_recover]``, jitted by models/secp_verifier.
     """
     from . import keccak as _keccak
     from . import sha2 as _sha2
 
-    sha_d = _sha2.sha256_blocks(sha_blocks, sha_active)
-    kec_d = _keccak.keccak256_blocks(kec_blocks, kec_active)
-    dig = jnp.where((is_eth | is_rec)[..., None], kec_d, sha_d)
-    e = _digest_to_limbs(dig)
-    return verify_batch(
+    with jax.named_scope("secp_hash"):
+        sha_d = _sha2.sha256_blocks(sha_blocks, sha_active)
+        kec_d = _keccak.keccak256_blocks(kec_blocks, kec_active)
+        dig = jnp.where((is_eth | is_rec)[..., None], kec_d, sha_d)
+        e = _digest_to_limbs(dig)
+    return secp_verify_batch(
         qx, qy, q_valid, e, r, s, is_eth, v, is_rec, addr, gtab,
         glv=glv, recover=recover,
     )
 
 
 # ------------------------------------------------------------ host bridge
-
-
-_VERIFY_JIT = None
-_HASH_VERIFY_JIT = None
-_JIT_MTX = threading.Lock()
 
 
 def ints_to_limbs_np(vals) -> np.ndarray:
@@ -1067,90 +1144,3 @@ def from_limbs(a) -> np.ndarray:
             val = (val << BITS) + int(row[k])
         out[i] = val
     return out.reshape(a.shape[:-1])
-
-
-def _rec_defaults(b: int, is_rec, addr):
-    if is_rec is None:
-        is_rec = np.zeros((b,), dtype=bool)
-    if addr is None:
-        addr = np.zeros((b, 20), dtype=np.uint8)
-    return is_rec, addr
-
-
-def verify_batch_device(
-    qx, qy, q_valid, e, r, s, is_eth, v,
-    is_rec=None, addr=None, glv=True,
-) -> np.ndarray:
-    """One device dispatch of the batched ECDSA kernel over pre-packed
-    host arrays; the blocking result fetch is this bridge's declared
-    collect point (analysis/kernel_manifest.COLLECT_BOUNDARIES).
-
-    The ``recover`` trace flag is derived here: batches without
-    ecrecover rows ride the cheaper program (no sqrt chain, no address
-    Keccak)."""
-    import jax
-
-    global _VERIFY_JIT
-    if _VERIFY_JIT is None:
-        with _JIT_MTX:
-            if _VERIFY_JIT is None:
-                _VERIFY_JIT = jax.jit(
-                    verify_batch, static_argnames=("glv", "recover")
-                )
-    is_rec, addr = _rec_defaults(qx.shape[0], is_rec, addr)
-    dev_args = (
-        jnp.asarray(qx),
-        jnp.asarray(qy),
-        jnp.asarray(q_valid),
-        jnp.asarray(e),
-        jnp.asarray(r),
-        jnp.asarray(s),
-        jnp.asarray(is_eth),
-        jnp.asarray(v),
-        jnp.asarray(is_rec),
-        jnp.asarray(addr),
-        g_table(),
-    )
-    ok = _VERIFY_JIT(
-        *dev_args, glv=bool(glv), recover=bool(np.any(is_rec))
-    )
-    return np.asarray(ok)
-
-
-def hash_verify_batch_device(
-    sha_blocks, sha_active, kec_blocks, kec_active,
-    qx, qy, q_valid, r, s, is_eth, v,
-    is_rec=None, addr=None, glv=True,
-) -> np.ndarray:
-    """The fused hash->verify dispatch (device-resident hashing); same
-    collect-point contract as :func:`verify_batch_device`."""
-    import jax
-
-    global _HASH_VERIFY_JIT
-    if _HASH_VERIFY_JIT is None:
-        with _JIT_MTX:
-            if _HASH_VERIFY_JIT is None:
-                _HASH_VERIFY_JIT = jax.jit(
-                    hash_verify_batch, static_argnames=("glv", "recover")
-                )
-    is_rec, addr = _rec_defaults(qx.shape[0], is_rec, addr)
-    dev_args = (
-        jnp.asarray(sha_blocks),
-        jnp.asarray(sha_active),
-        jnp.asarray(kec_blocks),
-        jnp.asarray(kec_active),
-        jnp.asarray(qx),
-        jnp.asarray(qy),
-        jnp.asarray(q_valid),
-        jnp.asarray(r),
-        jnp.asarray(s),
-        jnp.asarray(is_eth),
-        jnp.asarray(v),
-        jnp.asarray(is_rec),
-        jnp.asarray(addr),
-        g_table(),
-    )
-    ok = _HASH_VERIFY_JIT(
-        *dev_args, glv=bool(glv), recover=bool(np.any(is_rec))
-    )
-    return np.asarray(ok)

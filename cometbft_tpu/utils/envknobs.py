@@ -120,7 +120,7 @@ BLS_PUBKEY_CACHE = _declare(
 SECP_DEVICE_MIN = _declare(
     "COMETBFT_TPU_SECP_DEVICE_MIN", "int", 8,
     "Minimum batch width at/above which secp256k1 ECDSA batches run on "
-    "the accelerator (ops/secp256k1.verify_batch: Shamir double-scalar "
+    "the accelerator (ops/secp256k1.secp_verify_batch: Shamir double-scalar "
     "kernels + Montgomery batch inversion); below it the per-row host "
     "verify wins over dispatch overhead.  The verdict is bit-identical "
     "either way (models/secp_verifier).",
@@ -136,7 +136,7 @@ SECP_GLV = _declare(
     "COMETBFT_TPU_SECP_GLV", "bool", True,
     "`0` selects the plain 66-window Shamir double-scalar walk (the "
     "bit-exactness witness path) instead of the GLV endomorphism "
-    "quad-scalar walk over 33 windows in ops/secp256k1.verify_batch.  "
+    "quad-scalar walk over 33 windows in ops/secp256k1.secp_verify_batch.  "
     "The verdict is bit-identical either way (tests/test_secp_glv.py "
     "pins it); GLV roughly halves the shared doubling chain that "
     "dominates the kernel.",
@@ -145,7 +145,7 @@ SECP_HASH_DEVICE_MIN = _declare(
     "COMETBFT_TPU_SECP_HASH_DEVICE_MIN", "int", 64,
     "Minimum secp batch width at/above which message hashing (SHA-256 "
     "for cosmos rows, Keccak-256 for eth/ecrecover rows) fuses into "
-    "the device dispatch (ops/secp256k1.hash_verify_batch) instead of "
+    "the device dispatch (ops/secp256k1.secp_hash_verify_batch) instead of "
     "running as a per-row host loop; 0 disables the fused path.  Only "
     "batches whose every message fits COMETBFT_TPU_SECP_HASH_MAX_LEN "
     "take it — the verdict is bit-identical either way.",

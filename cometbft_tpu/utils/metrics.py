@@ -400,8 +400,9 @@ class Hub:
         self.verify_host_route = r.counter(
             "verify_host_route_total",
             "Batches a device verifier answered on the host (label "
-            "lane=uncached|comb, reason=below_batch_min: narrower than "
-            "COMETBFT_TPU_DEVICE_BATCH_MIN)",
+            "lane=uncached|comb|secp, reason=below_batch_min: narrower "
+            "than COMETBFT_TPU_DEVICE_BATCH_MIN, for secp "
+            "COMETBFT_TPU_SECP_DEVICE_MIN)",
         )
         self.commit_assemble_rows = r.counter(
             "commit_assemble_rows_total",
@@ -485,6 +486,19 @@ class Hub:
             "lane (label result=hit|miss); CheckTx ingest repeats "
             "senders, so the firehose soak asserts the hit rate from "
             "this counter instead of inferring it",
+        )
+        self.verify_secp_program_cache = r.counter(
+            "verify_secp_program_cache_total",
+            "Device batches of the MODE_SECP lane by how their program "
+            "was found (label result=hit|compile: compile = the batch "
+            "compiled its bucket's program first, announced to the "
+            "verify service; models/secp_verifier)",
+        )
+        self.verify_secp_rows = r.counter(
+            "verify_secp_rows_total",
+            "Rows the MODE_SECP lane verified, by where the arithmetic "
+            "ran and where the message hash was taken (labels "
+            "where=device|host, hash=device|host)",
         )
         # ---- verify service scheduler (verifysvc/service.py)
         self.verify_svc_queue_depth = r.gauge(

@@ -606,6 +606,12 @@ _PHASES = {
         "comb_lookup_a", "comb_lookup_b", "tree_reduce", "final_check",
         "pack_result",
     },
+    # the secp256k1 programs' own names (a prefix of their own, so that
+    # no reader of the ed25519 phases counts them)
+    "secp256k1_verify_batch": {"secp_prep", "secp_walk", "secp_final"},
+    "secp256k1_hash_verify": {
+        "secp_hash", "secp_prep", "secp_walk", "secp_final",
+    },
 }
 
 

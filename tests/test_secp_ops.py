@@ -4,8 +4,10 @@ over adversarial corpora, plus the batch-inversion poison test.
 The whole corpus rides in ONE padded bucket -> one compiled program
 (warm via the persistent XLA compile cache tests/.jax_cache, the same
 mitigation the ed25519/comb kernels rely on), so the fast tier pays a
-dispatch, not a compile, per run.  Field-level differentials and the
-multi-bucket sweep are heavier and live in the slow tier.
+dispatch, not a compile, per run.  Each field op is held to Python
+integers on operands that make long carry chains; the batch inversion's
+differential and the multi-bucket sweep are heavier and live in the
+slow tier.
 """
 
 import hashlib
@@ -217,6 +219,61 @@ def test_field_and_inverse_differential():
         )(la)))
         for i in range(n):
             assert mod.from_mont(int(inv[i])) == pow(a[i], mod.m - 2, mod.m), i
+
+
+def _edge_values(m: int, top: int) -> list[int]:
+    """Operands below ``top`` that make long carry chains: 0, 1, the
+    values next to m and to powers of two (runs of 4095 limbs), and
+    random ones."""
+    vals = [0, 1, 2, m - 1, m - 2, (m - 1) // 2, top - 1, 4095, 4096]
+    vals += [(1 << k) - d for k in (12, 13, 132, 255, 256, 263)
+             for d in (0, 1)]
+    vals += [int.from_bytes(rng.bytes(33), "big") % top for _ in range(48)]
+    return [v % top for v in vals]
+
+
+_R = 1 << 264  # the Montgomery radix
+
+# op: (the device op on limb batches, the exact result in integers)
+_FIELD_OPS = {
+    "add": (lambda d, mod, x, y: d.add(x, y, mod),
+            lambda m, x, y: (x + y) % m),
+    "sub": (lambda d, mod, x, y: d.sub(x, y, mod),
+            lambda m, x, y: (x - y) % m),
+    "mul": (lambda d, mod, x, y: d.mul(x, y, mod),
+            lambda m, x, y: x * y * pow(_R, -1, m) % m),
+    "add_n": (lambda d, mod, x, y: d._add_const(x, d.FN.limbs),
+              lambda m, x, y: x + host.N),
+    # x^(m-2) of the element whose Montgomery form is x
+    "invert": (lambda d, mod, x, y: d._mont_pow_inv(x, mod),
+               lambda m, x, y: pow(x * pow(_R, -1, m), m - 2, m) * _R % m),
+}
+
+
+@pytest.mark.parametrize("op", ["add", "sub", "mul", "mul_raw", "add_n", "invert"])
+@pytest.mark.parametrize("modulus", ["p", "n"])
+def test_field_ops_match_big_integers(modulus, op):
+    """Each field op against Python integers on operands that make long
+    carry chains: the parallel carries and the whole-width reduction
+    give the canonical digits of the exact result.  ``mul_raw`` is the
+    to-Montgomery case, the second operand any value below 2^264."""
+    import jax
+
+    from cometbft_tpu.ops import secp256k1 as dev
+
+    mod = dev.FP if modulus == "p" else dev.FN
+    m = mod.m
+    a = _edge_values(m, m)
+    b = _edge_values(m, _R if op == "mul_raw" else m)[::-1]
+    device_op, exact = _FIELD_OPS["mul" if op == "mul_raw" else op]
+    limbs = np.asarray(jax.jit(lambda x, y: device_op(dev, mod, x, y))(
+        dev.ints_to_limbs_np(a), dev.ints_to_limbs_np(b)))
+    assert limbs.min() >= 0 and limbs.max() < 4096  # canonical digits
+    got = [int(g) for g in dev.from_limbs(limbs)]
+    for i, (x, y) in enumerate(zip(a, b)):
+        if op == "invert" and x == 0:
+            continue  # zero has no inverse: callers sanitise it first
+        assert got[i] == exact(m, x, y), (i, x, y)
 
 
 def test_host_packer_roundtrip():

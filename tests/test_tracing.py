@@ -590,7 +590,9 @@ def test_the_anchor_maps_a_ring_event_onto_the_profilers_clock(monkeypatch):
     anchors = [e for e in ring if e["name"] == tracing.ANCHOR]
     assert len(anchors) == 1 and anchors[0]["dur"] == 0
     args = anchors[0]["args"]
-    assert args["perf_ns"] == anchors[0]["ts"] * 1e3 and args["wall_ns"] > 0
+    # the export writes ts as perf_ns / 1e3 (microseconds); the product
+    # back need not round-trip to the integer, so the check divides
+    assert anchors[0]["ts"] == args["perf_ns"] / 1e3 and args["wall_ns"] > 0
     profile = [(s, s, name) for s, name in _ProfilerAnnotation.starts]
     pairs = reader.anchor_pairs(ring, profile)
     assert len(pairs) == 1
